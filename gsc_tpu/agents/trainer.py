@@ -1087,6 +1087,20 @@ class Trainer:
                 curr.fold_td(np.asarray(sig["td_abs_sum"]),
                              np.asarray(sig["td_count"]))
 
+        learn_row = {}
+
+        def _on_episode(i, ret, succ, metrics):
+            """Harness ``on_episode`` callback: keep the learn burst's
+            losses for this episode's row (the serial path logs the same
+            two through ``_log``) — values the drain already synced."""
+            learn_row.clear()
+            if isinstance(metrics, dict):
+                learn_row.update(
+                    {k: float(metrics[k])
+                     for k in ("critic_loss", "actor_loss") if k in metrics})
+            if curr is not None:
+                _curriculum_hook(i, ret, succ, metrics)
+
         start = time.time()
         try:
             # the scheduler may swap topologies mid-run, so drive the
@@ -1145,9 +1159,11 @@ class Trainer:
                     # the sharded dispatch jits its own copy, that capture
                     # trace would read as a spurious chunk_step retrace in
                     # the sentinel stream: pause the monitor for exactly
-                    # that case (meshless captures share the dispatch's
-                    # trace cache, so they stay un-paused and count once,
-                    # same reasoning as bench.py's --perf path).
+                    # that case.  (Meshless the capture stays un-paused:
+                    # on jax 0.9.0 it shows as one more chunk_step trace
+                    # inside episode 0 — before the steady state any
+                    # retrace check looks at — and its backend compile
+                    # seeds the persistent cache the dispatch then hits.)
                     mon = self.obs.compile_monitor
                     paused = plan is not None and mon is not None
                     if paused:
@@ -1189,25 +1205,18 @@ class Trainer:
                             # show — the machine-read half of the
                             # tp-vs-sharded interconnect claim.  One
                             # extra AOT compile at startup (--no-perf
-                            # skips it); under the multi-device CPU
-                            # cache wart the lowering must run with the
-                            # persistent cache disabled, same guard as
-                            # the dispatch compiles.  The sharded jit
-                            # takes statics positionally (in_shardings
-                            # rejects kwargs).
-                            from ..parallel.partition import \
-                                no_persistent_compile_cache
+                            # skips it).  The sharded jit takes statics
+                            # positionally (in_shardings rejects kwargs).
                             s_fn = pddpg.sharded_lowerable("chunk_step",
                                                            state)
-                            with no_persistent_compile_cache(plan.mesh):
-                                self._capture_costs({
-                                    "chunk_step_sharded": (
-                                        s_fn,
-                                        (state, buffers, es_s, obs_s,
-                                         topo, traffic,
-                                         np.int32(ep * steps_per_ep),
-                                         chunk, True), {}),
-                                })
+                            self._capture_costs({
+                                "chunk_step_sharded": (
+                                    s_fn,
+                                    (state, buffers, es_s, obs_s,
+                                     topo, traffic,
+                                     np.int32(ep * steps_per_ep),
+                                     chunk, True), {}),
+                            })
                     except Exception as e:  # noqa: BLE001 - never fatal
                         log.warning("cost-ledger capture skipped on the "
                                     "replica path: %s", e)
@@ -1230,9 +1239,7 @@ class Trainer:
                     step_offset=ep * steps_per_ep, hub=hub, timer=timer,
                     topo_names=(mix_plan.names if mix_plan is not None
                                 else None),
-                    learn_names=seg_names,
-                    on_episode=(_curriculum_hook if curr is not None
-                                else None))
+                    learn_names=seg_names, on_episode=_on_episode)
                 if curr is not None:
                     # next episode's family weights, from THIS episode's
                     # drained TD segments (the hook above updated the
@@ -1265,8 +1272,8 @@ class Trainer:
                        * num_replicas / (time.time() - start))
                 row = {"episodic_return": rets[0],
                        "mean_succ_ratio": succ[0],
-                       "final_succ_ratio": final[0], "episode": ep,
-                       "sps": sps}
+                       "final_succ_ratio": final[0], **learn_row,
+                       "episode": ep, "sps": sps}
                 self.history.append(row)
                 self.rewards_writer.write(rets[0])
                 if self.tb:

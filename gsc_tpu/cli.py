@@ -28,39 +28,11 @@ def cli():
     """gsc-tpu: TPU-native service coordination framework."""
 
 
-def _apply_jax_cache(flag_value):
-    """Wire the persistent jax compilation cache into this process:
-    ``--jax-cache-dir`` wins, else ``GSC_JAX_CACHE_DIR``; unset leaves the
-    jax default (off) alone.  Returns the effective directory (or None)
-    so run_start obs meta can record what actually applied.  The test
-    suite has set this via conftest.py since PR 2 — production entry
-    points get the same compile-skipping here."""
-    d = flag_value or os.environ.get("GSC_JAX_CACHE_DIR")
-    if not d:
-        return None
-    d = os.path.abspath(d)
-    try:
-        jax.config.update("jax_compilation_cache_dir", d)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception as e:   # backend declines (e.g. unsupported platform)
-        click.echo(f"[jax-cache] not applied ({e})", err=True)
-        return None
-    return d
-
-
 # (temperature, floor) — the ONE definition behind the two
 # --curriculum-* click defaults AND the flags-without-factory guard in
 # train(): a tuned default must keep both in lockstep, or every
 # non-factory run would trip the guard
 _CURRICULUM_DEFAULTS = (1.0, 0.25)
-
-_JAX_CACHE_HELP = (
-    "persistent jax compilation cache directory (XLA executables are "
-    "reused across processes — repeat runs skip identical compiles).  "
-    "Unset: the GSC_JAX_CACHE_DIR env var; neither = cache off.  The "
-    "effective dir is recorded in run_start obs meta")
-
 
 def _uniform_schedule_action(limits, node_mask):
     """Flat [A] uniform dummy schedule over real nodes (the coordsim
@@ -249,17 +221,19 @@ def _build(agent_config, simulator_config, service, scheduler, seed,
                    "data-parallel path with on-device per-episode traffic "
                    "sampling; 1: the reference's single-env loop)")
 @click.option("--chunk", default=50, show_default=True,
-              help="rollout steps per device call with --replicas > 1 "
-                   "(long single-call scans exceed TPU per-call limits)")
+              help="rollout steps per device call with --replicas > 1; "
+                   "must divide episode_steps.  Shorter calls hand control "
+                   "back to the host more often; a whole 200-step episode "
+                   "in one call also runs on the v5e")
 @click.option("--mesh", default=None,
               help="pjit device mesh 'DPxMP' (e.g. 8x1, 4x2) for "
                    "--replicas > 1: env replicas/replay/traffic shard "
                    "over the dp*mp device grid and the learner state "
                    "follows --partition-rules.  Replica count must be "
                    "divisible by dp*mp.  The backend must HAVE dp*mp "
-                   "devices (for a CPU dry run preset XLA_FLAGS=--xla_"
-                   "force_host_platform_device_count=N — train never "
-                   "silently re-platforms).  Checkpoints are always "
+                   "devices (for a CPU dry run preset JAX_PLATFORMS=cpu "
+                   "XLA_FLAGS=--xla_force_host_platform_device_count=N "
+                   "— nothing re-platforms a run).  Checkpoints are always "
                    "host-gathered, so a "
                    "--resume may use a DIFFERENT mesh shape than the run "
                    "that wrote them (elastic resume).  Unset: today's "
@@ -329,9 +303,10 @@ def _build(agent_config, simulator_config, service, scheduler, seed,
               help="simulator substep engine override: xla (default; the "
                    "hand-fused one-hot pipeline) or pallas (the substep "
                    "megakernel, ONE kernel invocation per substep — "
-                   "bit-exact vs xla, CPU/interpret-only until its "
-                   "Mosaic port).  Unset = the simulator yaml's "
-                   "'substep_impl' key (default xla)")
+                   "bit-exact vs xla, CPU backend only: TPU Pallas "
+                   "cannot lower it and the engine refuses it there).  "
+                   "Unset = the simulator yaml's 'substep_impl' key "
+                   "(default xla)")
 @click.option("--unroll", type=int, default=None,
               help="substep-scan unroll factor override "
                    "(SimConfig.scan_unroll; trades compile time for less "
@@ -505,7 +480,6 @@ def _build(agent_config, simulator_config, service, scheduler, seed,
                    "no family's sampling probability can fall below "
                    "floor/K, so every family stays alive (forgetting "
                    "stays visible)")
-@click.option("--jax-cache-dir", default=None, help=_JAX_CACHE_HELP)
 @click.option("--verbose/--quiet", default=True)
 def train(agent_config, simulator_config, service, scheduler, episodes, seed,
           result_dir, experiment_id, max_nodes, max_edges, tensorboard,
@@ -518,8 +492,7 @@ def train(agent_config, simulator_config, service, scheduler, episodes, seed,
           check_invariants, fault_plan, rollback, ckpt_interval,
           ckpt_retain, hot_swap_dir, publish_interval, async_mode,
           async_actors, max_staleness, publish_bursts, learn_ratio,
-          curriculum_temperature, curriculum_floor, jax_cache_dir,
-          verbose):
+          curriculum_temperature, curriculum_floor, verbose):
     """Train DDPG, checkpoint, then one greedy test episode
     (main.py:16-76).  With --runs N, trains N seeds and selects the best
     (src/rlsp/agents/main.py:89-113 semantics).  With --replicas B, each
@@ -537,7 +510,9 @@ def train(agent_config, simulator_config, service, scheduler, episodes, seed,
         setup_result_dir,
     )
 
-    jax_cache_dir = _apply_jax_cache(jax_cache_dir)
+    from .runtime import (device_summary, enable_compile_cache,
+                          tree_platforms)
+    jax_cache_dir = enable_compile_cache()
     if resume and runs != 1:
         raise click.BadParameter("--resume only supports --runs 1")
     if metrics_port < 0:
@@ -595,19 +570,11 @@ def train(agent_config, simulator_config, service, scheduler, episodes, seed,
                 f"--replicas ({replicas}) must be divisible by the mesh "
                 f"device count ({dp_ * mp_} = {dp_}x{mp_}) for an even "
                 "replica sharding")
-        # same contract as bench.py and make_train_mesh's docstring:
-        # production entry points check device counts BEFORE building the
-        # mesh — otherwise make_train_mesh's virtual-CPU fallback would
-        # silently re-platform a TPU training run onto dp*mp virtual CPU
-        # devices (the dry-run path must be an explicit choice)
-        have = len(jax.devices())
-        if have < dp_ * mp_:
-            raise click.UsageError(
-                f"--mesh {mesh} needs {dp_ * mp_} devices, backend has "
-                f"{have}.  For a CPU dry run start the process with "
-                f"JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_"
-                f"device_count={dp_ * mp_}")
-        plan = ShardingPlan.from_spec(mesh, rules=partition_rules)
+        try:
+            # make_train_mesh raises when the backend is short of devices
+            plan = ShardingPlan.from_spec(mesh, rules=partition_rules)
+        except ValueError as e:
+            raise click.UsageError(f"--mesh {mesh}: {e}")
         if async_mode:
             # dp-sharded replay needs a dp axis — refuse tp-only grids
             # here with the flag's name, not from inside the run loop
@@ -945,7 +912,13 @@ def train(agent_config, simulator_config, service, scheduler, episodes, seed,
             obs.close(status="ok")
         result.metrics = test
         result.write()
-        outputs[rdir] = {"result_dir": rdir, "checkpoint": ckpt, **test}
+        outputs[rdir] = {"result_dir": rdir, "checkpoint": ckpt, **test,
+                         "device": device_summary(),
+                         # where the trained learner state lives when the
+                         # loop ends ("host" = gathered numpy, the --mesh
+                         # layout) — a device run that fell back to the
+                         # CPU shows here
+                         "state_platforms": tree_platforms(state)}
     best = select_best_agent(run_dirs) if runs > 1 else run_dirs[0]
     click.echo(json.dumps({**outputs[best], "runs": runs,
                            "all_result_dirs": run_dirs}))
@@ -970,10 +943,9 @@ def train(agent_config, simulator_config, service, scheduler, episodes, seed,
                    "agent yaml for pre-meta checkpoints) so the greedy "
                    "episodes evaluate under the compute dtype the "
                    "checkpoint was trained with")
-@click.option("--jax-cache-dir", default=None, help=_JAX_CACHE_HELP)
 def infer(agent_config, simulator_config, service, scheduler, checkpoint,
           episodes, seed, max_nodes, max_edges, resource_functions_path,
-          precision, jax_cache_dir):
+          precision):
     """Restore a checkpoint and run greedy test episodes
     (inference.py:17-40).  The JSON output splits compile+warmup wall
     (``compile_warmup_s``: everything up to the first completed control
@@ -985,7 +957,8 @@ def infer(agent_config, simulator_config, service, scheduler, checkpoint,
 
     import numpy as _np
 
-    _apply_jax_cache(jax_cache_dir)
+    from .runtime import enable_compile_cache
+    enable_compile_cache()
     if precision is None:
         precision = read_checkpoint_meta(checkpoint).get("precision")
     env, driver, agent = _build(agent_config, simulator_config, service,
@@ -1134,14 +1107,13 @@ def infer(agent_config, simulator_config, service, scheduler, checkpoint,
                    "'25' or '25,8:60'.  Off by default (deadline-miss "
                    "ratio, pad waste and arrival rate are tracked "
                    "regardless).  Requires --obs")
-@click.option("--jax-cache-dir", default=None, help=_JAX_CACHE_HELP)
 def serve(agent_config, simulator_config, service, scheduler, checkpoint,
           requests, concurrency, buckets, deadline_ms, continuous,
           workers, brownout_burn, hot_swap_dir, swap_poll_s, fire_swaps,
           artifact_cache, pool_steps, stats_interval, request_timeout,
           seed, max_nodes, max_edges, resource_functions_path, result_dir,
           obs_enabled, obs_dir, obs_series_window, perf_enabled,
-          metrics_port, trace_sample, slo_p99_ms, jax_cache_dir):
+          metrics_port, trace_sample, slo_p99_ms):
     """Serve coordination decisions from an AOT-compiled greedy policy.
 
     With CHECKPOINT: restores the actor, ahead-of-time compiles the
@@ -1212,7 +1184,8 @@ def serve(agent_config, simulator_config, service, scheduler, checkpoint,
             slo_objectives = parse_slo_spec(slo_p99_ms)
         except ValueError as e:
             raise click.BadParameter(f"--slo-p99-ms {slo_p99_ms!r}: {e}")
-    jax_cache_dir = _apply_jax_cache(jax_cache_dir)
+    from .runtime import device_summary, enable_compile_cache
+    jax_cache_dir = enable_compile_cache()
 
     precision = None
     if checkpoint:
@@ -1396,7 +1369,9 @@ def serve(agent_config, simulator_config, service, scheduler, checkpoint,
             fire_thread.start()
 
         # closed-loop load: each client thread submits its share
-        # sequentially, so at most --concurrency requests are in flight
+        # sequentially, so at most --concurrency requests are in flight.
+        # A failed request is collected so the JSON can name it — and
+        # then fails the command (non-zero exit below)
         errors = []
         shares = [requests // concurrency + (1 if i < requests % concurrency
                                              else 0)
@@ -1407,8 +1382,9 @@ def serve(agent_config, simulator_config, service, scheduler, checkpoint,
                 ob_h = pool[(tid + j * concurrency) % len(pool)]
                 try:
                     frontend.submit(ob_h).result(request_timeout)
-                except Exception as e:  # noqa: BLE001 - surfaced in JSON
-                    errors.append(f"client{tid}/{j}: {e}")
+                except Exception as e:  # noqa: BLE001 - fails the command
+                    errors.append(f"client{tid}/{j}: "
+                                  f"{type(e).__name__}: {e}")
 
         t0 = _time.perf_counter()
         threads = [threading.Thread(target=client, args=(i, n),
@@ -1485,12 +1461,15 @@ def serve(agent_config, simulator_config, service, scheduler, checkpoint,
         raise
     if obs_rec is not None:
         obs_rec.close(status="ok")
+    completed = requests - len(errors)
     click.echo(json.dumps({
-        "tier": server.tier, "requests": requests,
+        "tier": server.tier, "requests": requests, "completed": completed,
         "workers": workers, "mode": mode,
         "errors": len(errors), "error_detail": errors[:5],
+        "device": device_summary(),
         "wall_s": round(wall, 3),
-        "rps": round(requests / wall, 3) if wall > 0 else 0.0,
+        # completed requests only: a failed request is not throughput
+        "rps": round(completed / wall, 3) if wall > 0 else 0.0,
         "p50_ms": round(lat.get("p50", 0.0), 3),
         "p99_ms": round(lat.get("p99", 0.0), 3),
         "buckets": per_bucket,
@@ -1503,6 +1482,10 @@ def serve(agent_config, simulator_config, service, scheduler, checkpoint,
         "artifact_cache": cache_dir if checkpoint else None,
         "jax_cache_dir": jax_cache_dir,
         "result_dir": rdir}))
+    if errors:
+        raise click.ClickException(
+            f"{len(errors)} of {requests} requests failed (first: "
+            f"{errors[0]})")
 
 
 @cli.command()

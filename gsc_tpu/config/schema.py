@@ -279,8 +279,9 @@ class SimConfig:
     # hand-fused one-hot XLA pipeline (default, the reference-parity
     # workhorse); "pallas" = the substep MEGAKERNEL — the whole
     # admission/release chain as ONE pallas_call per substep
-    # (gsc_tpu/ops/pallas_substep.py; interpret-mode on CPU, bit-exact vs
-    # "xla" by construction and by the `pytest -m megakernel` suite).
+    # (gsc_tpu/ops/pallas_substep.py; CPU backend only — SimEngine
+    # refuses it elsewhere; bit-exact vs "xla" by construction and by the
+    # `pytest -m megakernel` suite).
     # Per-flow control (controller="per_flow") stays on the XLA path.
     substep_impl: str = "xla"
 

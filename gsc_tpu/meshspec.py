@@ -2,12 +2,11 @@
 
 The ``"DPxMP"`` mesh grammar and the named-rulebook vocabulary are
 spoken by surfaces on BOTH sides of the jax boundary: the CLI and
-``parallel/partition.py`` import jax anyway, but ``bench.py``'s
-orchestrator must stay jax-free (a parent process that imports jax
-claims the TPU alongside its measurement workers).  PR 8 left the regex
-copied into bench.py twice for exactly that reason; this module is the
-one shared definition both sides import — ``import gsc_tpu.meshspec``
-executes only the package docstring, never a jax import.
+``parallel/partition.py`` import jax anyway, but ``bench.py``'s argument
+parsing and the jax-free ``tools/dryrun_multihost.py`` launcher validate
+a spec before any backend exists.  This module is the one shared
+definition both sides import — ``import gsc_tpu.meshspec`` executes only
+the package docstring, never a jax import.
 
 Canonical spellings, enforced here so cross-artifact grouping never
 splits one value into two strings:

@@ -26,18 +26,22 @@ the serve latency histograms, so the ledger adds ZERO host syncs to the
 dispatch path (the ``no_host_sync`` sentinel contract: everything here
 happens before the episode loop or after it, never inside a dispatch).
 
-Combining the two yields per-dispatch achieved FLOP/s, MFU against a
-per-backend peak envelope, and the roofline position (arithmetic
-intensity vs the ridge point, attainable-roof multiple).  The whole
+Combining the two yields per-dispatch achieved FLOP/s and bytes/s and —
+on a device :data:`DEVICE_PEAKS` knows — MFU against that chip's
+published peaks and the roofline position (arithmetic intensity vs the
+ridge point, attainable-roof multiple).  A device that is not in the
+table gets NO ``mfu``/``bw_util``/``roofline`` fields and the document
+says so (``peaks: null`` plus ``peaks_note``): a CPU run, or a chip whose
+datasheet nobody entered, never borrows another part's peaks.  The whole
 ledger serializes as a schema-versioned ``perf.json`` next to
-``metrics.json`` (``RunObserver.close`` writes it), each capture also
-emitting one structured ``compile_cost`` event into events.jsonl.
+``metrics.json`` (``RunObserver.close`` writes it), naming ``backend``,
+``device_kind`` and ``device_count``; each capture also emits one
+structured ``compile_cost`` event into events.jsonl.
 
-CPU-backend caveat: XLA's CPU cost model still reports flops/bytes, but
-the peak envelope is an order-of-magnitude placeholder — MFU numbers on
-CPU are for run-over-run comparison (tools/bench_diff.py tolerance
-bands), not absolute utilization claims.  Rows record the backend so a
-reader can never mistake one for the other.
+The FLOPs and bytes are XLA's cost model for the compiled program, not
+the operations the algorithm needs, and the wall is host wall around the
+drain — so ``mfu`` here is a utilization of the cost model's work, to be
+read next to a device trace, not instead of one.
 """
 from __future__ import annotations
 
@@ -54,17 +58,25 @@ log = logging.getLogger("gsc_tpu.obs.perf")
 # (tools/obs_report.py, tools/bench_diff.py) key on it
 PERF_SCHEMA_VERSION = 1
 
-# peak envelopes per backend platform for MFU/roofline.  TPU row is the
-# v4 datasheet (275 TFLOP/s bf16 MXU, 1.2 TB/s HBM); GPU a generic A100
-# class; CPU an honest single-core order-of-magnitude placeholder (this
-# box) — see the module docstring's caveat.  Override per-run with
-# ``CostLedger(peak_flops=..., peak_bytes_per_s=...)`` when the hardware
-# is known more precisely.
-PEAK_ENVELOPES = {
-    "tpu": {"flops_per_s": 275e12, "bytes_per_s": 1.2e12},
-    "gpu": {"flops_per_s": 312e12, "bytes_per_s": 2.0e12},
-    "cpu": {"flops_per_s": 5e10, "bytes_per_s": 2e10},
+# THE peaks table: published per-chip peaks keyed by the string
+# ``jax.devices()[0].device_kind`` prints.  One row per part somebody has
+# actually run this repo on, each with its source; a device that is not
+# here gets no MFU/roofline (``device_peaks`` returns None) — never a
+# default.  tools/profile_substep.py reads the same table.
+DEVICE_PEAKS = {
+    # one TPU v5e chip reports itself as "TPU v5 lite" (chip run, PR 21)
+    "TPU v5 lite": {
+        "flops_per_s": 197e12,      # bf16 MXU
+        "bytes_per_s": 819e9,       # HBM2e
+        "source": "Google Cloud documentation, 'TPU v5e' system "
+                  "architecture: 197 TFLOP/s bf16, 819 GB/s HBM per chip",
+    },
 }
+
+
+def device_peaks(device_kind: str) -> Optional[Dict]:
+    """The :data:`DEVICE_PEAKS` row for ``device_kind``, or None."""
+    return DEVICE_PEAKS.get(device_kind)
 
 # ops worth a per-entry histogram next to the fusion count: `while` is
 # the serial-scatter tell on CPU, `dot` the MXU share, scatter/gather
@@ -104,12 +116,8 @@ def resolve_lowerable(owner, name: str):
 
 
 def _cost_dict(compiled) -> Dict[str, float]:
-    """Flatten ``compiled.cost_analysis()`` (dict, or list-of-dict on
-    older jaxlibs) to one ``{metric: value}`` dict."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost or {})
+    """``compiled.cost_analysis()`` as a plain ``{metric: value}`` dict."""
+    return dict(compiled.cost_analysis() or {})
 
 
 class CostLedger:
@@ -121,31 +129,34 @@ class CostLedger:
     raised — a missing cost model must not fail a training run.
     """
 
-    def __init__(self, hub=None, backend: Optional[str] = None,
-                 peak_flops: Optional[float] = None,
-                 peak_bytes_per_s: Optional[float] = None):
+    def __init__(self, hub=None, device_kind: Optional[str] = None):
         self.hub = hub
-        self._backend = backend          # resolved lazily (needs jax)
-        self._peak_flops = peak_flops
-        self._peak_bw = peak_bytes_per_s
+        # ``device_kind`` overrides what JAX reports (tests pin a table
+        # row without owning the part); None = ask the backend, lazily
+        self._device_kind = device_kind
+        self._device: Optional[Dict] = None
         self._entries: Dict[str, Dict] = {}
         self._timings: Dict[str, Dict[str, float]] = {}
         self._phases: Dict[str, Dict[str, float]] = {}
 
-    # ------------------------------------------------------------- backend
-    def backend(self) -> str:
-        if self._backend is None:
-            try:
-                import jax
-                self._backend = jax.default_backend()
-            except Exception:
-                self._backend = "unknown"
-        return self._backend
+    # -------------------------------------------------------------- device
+    def device(self) -> Dict:
+        """``{"platform", "kind", "count"}`` of the backend (resolved
+        once, lazily — constructing a ledger never touches jax)."""
+        if self._device is None:
+            from ..runtime import device_summary
+            self._device = device_summary()
+            if self._device_kind is not None:
+                self._device = {**self._device, "kind": self._device_kind}
+        return self._device
 
-    def peaks(self) -> Dict[str, float]:
-        env = PEAK_ENVELOPES.get(self.backend(), PEAK_ENVELOPES["cpu"])
-        return {"flops_per_s": self._peak_flops or env["flops_per_s"],
-                "bytes_per_s": self._peak_bw or env["bytes_per_s"]}
+    def backend(self) -> str:
+        return self.device()["platform"]
+
+    def peaks(self) -> Optional[Dict]:
+        """The peaks-table row for this device, or None when the device
+        is not in :data:`DEVICE_PEAKS`."""
+        return device_peaks(self.device()["kind"])
 
     # ------------------------------------------------------------- capture
     def has(self, name: str) -> bool:
@@ -250,11 +261,16 @@ class CostLedger:
             if entry.get("available") and entry.get("flops") and mean_s > 0:
                 achieved = entry["flops"] / mean_s
                 out["achieved_flops_per_s"] = round(achieved, 1)
-                out["mfu"] = round(achieved / peaks["flops_per_s"], 6)
                 bytes_a = entry.get("bytes_accessed") or 0.0
                 if bytes_a:
                     bw = bytes_a / mean_s
                     out["achieved_bytes_per_s"] = round(bw, 1)
+                if peaks is None:
+                    # unknown device: achieved rates only — no MFU, no
+                    # roofline, never another part's peaks
+                    return out
+                out["mfu"] = round(achieved / peaks["flops_per_s"], 6)
+                if bytes_a:
                     out["bw_util"] = round(bw / peaks["bytes_per_s"], 6)
                     intensity = entry["flops"] / bytes_a
                     ridge = peaks["flops_per_s"] / peaks["bytes_per_s"]
@@ -282,11 +298,19 @@ class CostLedger:
 
     def summary(self) -> Dict:
         """The full schema-versioned perf document."""
+        dev = self.device()
+        peaks = self.peaks()
         return {
             "schema_version": PERF_SCHEMA_VERSION,
             "ts": round(time.time(), 3),
-            "backend": self.backend(),
-            "peaks": self.peaks(),
+            "backend": dev["platform"],
+            "device_kind": dev["kind"],
+            "device_count": dev["count"],
+            "peaks": peaks,
+            **({} if peaks is not None else {"peaks_note": (
+                f"device_kind {dev['kind']!r} is not in obs.perf."
+                "DEVICE_PEAKS: entries carry achieved rates only, no "
+                "mfu / bw_util / roofline")}),
             "run": (self.hub.base_tags.get("run")
                     if self.hub is not None else None),
             "entries": {name: self._derived(e, self._timings.get(name))

@@ -32,19 +32,18 @@ against the XLA engine on the reference-parity scenarios):
   exact under any order and use scatter-adds.
 - the grouping SORT stays ``argsort`` over unique integer keys — exact.
 
-Execution model: ``interpret=None`` auto-selects interpret mode on the
-CPU backend exactly like ``pallas_gat`` (tests, 1-core CI, virtual
-meshes); there the kernel body inlines into the XLA program as ONE
+Execution model: this kernel has a CPU role only.  On the CPU backend
+``interpret=None`` inlines the kernel body into the XLA program as ONE
 straight-line block — measurably FEWER fusions than the hand-fused
 engine (the fusion-budget test in ``tests/test_megakernel.py`` asserts
-pallas < xla on the compiled flagship interval).  On a TPU backend the
-call attempts native Mosaic lowering; the ``argsort`` grouping and the
-dynamic gathers are not yet expressible there (TPU Pallas has no sort
-primitive), so the compiled-TPU port — a bitonic compare-exchange
-network over the flow axis, one-hot MXU contractions for the few
-order-sensitive segment sums, scalar refs in SMEM — is the documented
-next step for a chip window; until then chip runs keep
-``substep_impl="xla"``.
+pallas < xla on the compiled flagship interval).  On a TPU backend
+native Mosaic lowering was tried on the chip (PR 21, TPU v5 lite,
+jax 0.9.0) and refused: ``Unimplemented primitive in Pallas TPU lowering
+for KernelType.TC: dynamic_slice`` — the ``argsort`` grouping and the
+dynamic gathers are not expressible there.  ``SimEngine`` therefore
+rejects ``substep_impl="pallas"`` at build time on any non-CPU backend;
+chip runs use ``substep_impl="xla"``.  Whether this twin survives is
+ROADMAP Queue 3 item 3.
 """
 from __future__ import annotations
 
@@ -545,9 +544,11 @@ def substep_megakernel(state: SimState, topo, traffic, cap_now: jnp.ndarray,
       inlined as plain XLA — bit-identical to interpret mode (the Pallas
       interpreter executes exactly these jnp ops) but without the
       ref-discharge copies, so the compiled flagship interval lands
-      BELOW the hand-fused XLA engine's fusion count (measured 185 vs
-      191; the fusion-budget test pins it) and runs ~25% faster per
-      interval on CPU.  Other backends take the native ``pallas_call``.
+      BELOW the hand-fused XLA engine's fusion count (270 vs 273 on
+      jaxlib 0.9.0; the fusion-budget test pins it) and runs ~25% faster per
+      interval on CPU.  Other backends take the native ``pallas_call``,
+      which TPU Pallas refuses (module docstring) — the engine never
+      builds this impl there.
     - ``interpret=True``: force a REAL interpret-mode ``pallas_call``
       (the parity suite uses this to pin kernel == inlined body).
     - ``interpret=False``: force native lowering.

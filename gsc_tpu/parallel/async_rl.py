@@ -53,10 +53,7 @@ jits, then the sharded donated ingest — AOT-lowered so its partitioned
 HLO can be mined and asserted collective-free (row-aligned ring/block/
 cursor shardings make the scatter one independent per-shard donated
 write; a block lands on the mesh once, in its final shard, and never
-moves again).  The whole run executes under ONE
-``no_persistent_compile_cache`` guard (the multi-device-CPU cache wart,
-see partition.py), which also makes the per-dispatch inner guards
-inert — no actor thread ever toggles global jax config.  Learn-bursts
+moves again).  Learn-bursts
 dispatch through the same plan-bound binding the sync path uses (tp
 rulebooks compose unchanged), and publishes gather params to host ONCE
 so the actor watchers and the serving fleet's hot-swap read the same
@@ -102,8 +99,7 @@ from ..resilience.faults import FaultInjected
 from ..resilience.guard import RollbackGuard, all_finite, poison_tree
 from ..resilience.retry import (RetryPolicy, TransientDispatchError,
                                 call_with_retry)
-from .partition import (actor_shard_assignment, no_persistent_compile_cache,
-                        ring_shard_rows)
+from .partition import actor_shard_assignment, ring_shard_rows
 
 log = logging.getLogger("gsc_tpu.parallel.async_rl")
 
@@ -153,7 +149,6 @@ def make_replay_ingest(num_replicas: int, capacity: int, sharding=None):
 
         return replay_ingest
 
-    from jax.experimental.shard_map import shard_map
     mesh, spec = sharding.mesh, sharding.spec
 
     def _local_fold(buffers: ReplayBuffer, block: Any) -> ReplayBuffer:
@@ -162,12 +157,11 @@ def make_replay_ingest(num_replicas: int, capacity: int, sharding=None):
         return _fold(buffers, block,
                      jnp.arange(buffers.pos.shape[0])[:, None])
 
-    # check_rep off: every output is fully row-partitioned (nothing
-    # replicated to validate) and this jax version's replication checker
-    # rejects benign .at[].set patterns
-    sharded_fold = shard_map(_local_fold, mesh=mesh,
-                             in_specs=(spec, spec), out_specs=spec,
-                             check_rep=False)
+    # check_vma off: every output is fully row-partitioned — there is
+    # nothing replicated for the varying-axes checker to validate
+    sharded_fold = jax.shard_map(_local_fold, mesh=mesh,
+                                 in_specs=(spec, spec), out_specs=spec,
+                                 check_vma=False)
 
     @partial(jax.jit, donate_argnums=(0,),
              in_shardings=(sharding, sharding), out_shardings=sharding)
@@ -514,9 +508,8 @@ def run_async(pddpg, scenario_fn: Callable, state, buffers,
     only thread that owns the carries, so a save can never race a
     rebind.
 
-    With a plan-carrying ``pddpg`` (``--async --mesh``) the whole run
-    executes under ONE ``no_persistent_compile_cache`` guard and a
-    prewarm builds every jit before the first actor thread starts: the
+    With a plan-carrying ``pddpg`` (``--async --mesh``) a prewarm
+    builds every jit before the first actor thread starts: the
     plan-bound dispatch, then the dp-sharded donated ingest (AOT-lowered
     and asserted collective-free).  The ring is placed into
     ``plan.ring_sharding`` residency here, so callers may hand a
@@ -548,23 +541,6 @@ def run_async(pddpg, scenario_fn: Callable, state, buffers,
     plan = getattr(pddpg, "plan", None)
     if plan is not None:
         plan.assert_async_capable()
-        # ONE guard for the whole run (prewarm compiles, actor-thread
-        # dispatches, learner ingests/bursts): inside it the per-dispatch
-        # guards in dp.py read an unset cache dir and become inert, so no
-        # actor thread ever touches global jax config (the guard itself
-        # is not thread-safe — holding it once here is what makes the
-        # multi-device-CPU cache wart safe under threads)
-        with no_persistent_compile_cache(plan.mesh):
-            return _run_async_impl(
-                pddpg, scenario_fn, state, buffers, episodes,
-                episode_steps, chunk, seed, cfg, publisher=publisher,
-                hub=hub, timer=timer, on_episode=on_episode,
-                on_burst=on_burst, should_stop=should_stop,
-                start_episode=start_episode,
-                checkpoint_every=checkpoint_every,
-                checkpoint_fn=checkpoint_fn, fault_plan=fault_plan,
-                rollback=rollback, on_recovery=on_recovery,
-                retry_policy=retry_policy)
     return _run_async_impl(
         pddpg, scenario_fn, state, buffers, episodes, episode_steps,
         chunk, seed, cfg, publisher=publisher, hub=hub, timer=timer,

@@ -276,15 +276,6 @@ class ParallelDDPG:
             # consumed buffers alive
             return jax.device_put(tree, data)
 
-        # every dispatch (where a compile, or a recompile after cache
-        # eviction, can happen) runs under the multi-device-CPU guard:
-        # deserializing num_partitions>1 CPU executables from the
-        # persistent compilation cache heap-corrupts or silently
-        # miscomputes on this jax version (see partition.py) — the
-        # in-memory executable is unaffected, so steady-state calls pay
-        # two config reads and nothing else
-        from .partition import no_persistent_compile_cache
-
         def built(name, state):
             # double-checked build: the lazy first-dispatch fill must not
             # race a second thread into a duplicate trace
@@ -299,8 +290,7 @@ class ParallelDDPG:
         def chunk_step(state, buffers, env_states, obs, topo, traffic,
                        episode_start_step, num_steps=None, learn=False):
             fn = built("chunk_step", state)
-            with no_persistent_compile_cache(plan.mesh), \
-                    self.dispatch_lock:
+            with self.dispatch_lock:
                 out = fn(state_in(state), put_data(buffers),
                          put_data(env_states), put_data(obs),
                          put_once(topo, topo_sh), put_once(traffic, data),
@@ -311,8 +301,7 @@ class ParallelDDPG:
         def rollout_episodes(state, buffers, env_states, obs, topo,
                              traffic, episode_start_step, num_steps=None):
             fn = built("rollout_episodes", state)
-            with no_persistent_compile_cache(plan.mesh), \
-                    self.dispatch_lock:
+            with self.dispatch_lock:
                 out = fn(state_in(state), put_data(buffers),
                          put_data(env_states), put_data(obs),
                          put_once(topo, topo_sh), put_once(traffic, data),
@@ -322,8 +311,7 @@ class ParallelDDPG:
 
         def learn_burst(state, buffers):
             fn = built("learn_burst", state)
-            with no_persistent_compile_cache(plan.mesh), \
-                    self.dispatch_lock:
+            with self.dispatch_lock:
                 out = fn(state_in(state), put_data(buffers))
             return (state_out(out[0]),) + out[1:]
 
@@ -342,10 +330,7 @@ class ParallelDDPG:
         from ``state`` if the lazy binding has not happened yet; ``None``
         without a plan.  Callers lower it AOT (``obs.perf.CostLedger``)
         to mine the PARTITIONED program's HLO — fusions and collective
-        ops — which the unsharded class jit cannot show.  Lowering a
-        multi-device CPU program must run under
-        ``partition.no_persistent_compile_cache`` (same wart as the
-        dispatch compiles)."""
+        ops — which the unsharded class jit cannot show."""
         if self.plan is None:
             return None
         if name not in self._sharded_fns:

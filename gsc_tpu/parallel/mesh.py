@@ -22,15 +22,14 @@ def force_virtual_cpu(n_devices: int) -> None:
     """Select an ``n_devices``-device virtual CPU platform — BEFORE any
     backend touch.
 
-    The dry-run/CI entry point: call this before the first
+    The dry-run/CI entry point, and the ONLY code in the package that
+    changes the platform: call it explicitly, before the first
     ``jax.devices()``/``jit`` of the process.  It sets
     ``xla_force_host_platform_device_count`` and switches
-    ``jax_platforms`` to cpu via ``jax.config.update`` — the one order of
+    ``jax_platforms`` to cpu via ``jax.config.update`` — an order of
     operations that never initializes the default (possibly TPU) backend,
-    whose init can hang indefinitely when the shared chip is wedged by an
-    earlier faulted run (tests/conftest.py uses the same pattern).  If a
-    CPU backend predating the flag is already live, falls back to
-    ``clear_backends`` surgery."""
+    so a dry run never claims a chip.  If a CPU backend predating the
+    flag is already live, falls back to ``clear_backends`` surgery."""
     import os
     flags = os.environ.get("XLA_FLAGS", "")
     flags = " ".join(f for f in flags.split()
@@ -88,20 +87,29 @@ def make_hybrid_mesh(outer_axis: str = "dcn", axis: str = "dp") -> Mesh:
     return Mesh(grid, (outer_axis, axis))
 
 
+def require_devices(n_devices: int, what: str):
+    """The backend's devices, or a ``ValueError`` naming how many ``what``
+    needs and what the backend has.  Mesh builders never change the
+    platform: a run that asked for a chip mesh must not become a CPU run.
+    Dry runs select the virtual CPU platform explicitly
+    (:func:`force_virtual_cpu`, or ``JAX_PLATFORMS=cpu`` with
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` before start)."""
+    devs = jax.devices()
+    if len(devs) < n_devices:
+        raise ValueError(
+            f"{what} needs {n_devices} devices and the "
+            f"{devs[0].platform!r} backend has {len(devs)}.  For a CPU dry "
+            "run start the process with JAX_PLATFORMS=cpu XLA_FLAGS="
+            f"--xla_force_host_platform_device_count={n_devices}")
+    return devs
+
+
 def make_mesh(n_devices: Optional[int] = None, axis: str = "dp") -> Mesh:
     """1-D mesh over the first ``n_devices`` devices (default: all).
-
-    If fewer devices exist than requested, falls back to a virtual CPU
-    platform with ``n_devices`` host devices (the dry-run path for
-    validating multi-chip shardings without hardware).  Note this probes
-    the current backend first; dry-run entry points that must never touch
-    the TPU should call ``force_virtual_cpu`` beforehand."""
-    devs = jax.devices()
-    if n_devices is not None and len(devs) < n_devices:
-        force_virtual_cpu(n_devices)
-        devs = jax.devices()
-    if n_devices is not None:
-        devs = devs[:n_devices]
+    Raises ``ValueError`` when the backend has fewer
+    (:func:`require_devices`)."""
+    devs = jax.devices() if n_devices is None else \
+        require_devices(n_devices, f"make_mesh({n_devices})")[:n_devices]
     return Mesh(np.asarray(devs), (axis,))
 
 

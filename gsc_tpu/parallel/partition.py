@@ -38,7 +38,6 @@ from __future__ import annotations
 
 import logging
 import re
-from contextlib import contextmanager
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -47,7 +46,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..meshspec import (PARTITION_RULEBOOKS, parse_mesh_shape,
                         validate_partition_rules)
-from .mesh import force_virtual_cpu
+from .mesh import require_devices
 
 log = logging.getLogger("gsc_tpu.parallel.partition")
 
@@ -130,25 +129,18 @@ assert tuple(NAMED_RULEBOOKS) == PARTITION_RULEBOOKS
 
 
 # ------------------------------------------------------------- mesh shapes
-# the "DPxMP" grammar lives jax-free in gsc_tpu.meshspec (bench.py's
-# orchestrator shares it without importing jax); parse_mesh_shape is
-# imported above and re-exported so every historic import site keeps
+# the "DPxMP" grammar lives jax-free in gsc_tpu.meshspec; parse_mesh_shape
+# is imported above and re-exported so every historic import site keeps
 # working.
 
 
 def make_train_mesh(dp: int, mp: int = 1,
                     axes: Tuple[str, str] = TRAIN_AXES) -> Mesh:
-    """2-D ``(dp, mp)`` mesh over the first ``dp*mp`` devices.
-
-    Like :func:`..mesh.make_mesh`, falls back to a virtual CPU platform
-    when fewer devices exist (the dry-run/CI path) — production entry
-    points that must never silently leave the accelerator check device
-    counts BEFORE calling (bench.py does)."""
+    """2-D ``(dp, mp)`` mesh over the first ``dp*mp`` devices.  Like
+    :func:`..mesh.make_mesh`, raises ``ValueError`` when the backend has
+    fewer — it never changes the platform."""
     n = dp * mp
-    devs = jax.devices()
-    if len(devs) < n:
-        force_virtual_cpu(n)
-        devs = jax.devices()
+    devs = require_devices(n, f"a {dp}x{mp} mesh")
     grid = np.asarray(devs[:n]).reshape(dp, mp)
     return Mesh(grid, axes)
 
@@ -249,54 +241,6 @@ def spec_summary(specs) -> Dict[str, int]:
         key = str(spec)
         counts[key] = counts.get(key, 0) + 1
     return dict(sorted(counts.items()))
-
-
-@contextmanager
-def no_persistent_compile_cache(mesh: Mesh):
-    """Disable the persistent XLA compilation cache while compiling (or
-    re-compiling after eviction) a MULTI-DEVICE CPU program.
-
-    Measured on this box (jax 0.4.37): deserializing a num_partitions>1
-    CPU executable from the persistent cache is broken — a cache hit
-    either aborts with glibc heap corruption (``free(): invalid next
-    size`` / ``double free`` / SIGSEGV) or, worse, runs and silently
-    computes garbage (a 2x4 carving leg returned a DIFFERENT digest on
-    every cached run where every fresh compile returns the same correct
-    bytes).  Fresh compiles of the same programs are correct and
-    carving-invariant.  The suite's historic multi-device test programs
-    never tripped this because they compile under the 1 s
-    ``persistent_cache_min_compile_time_secs`` floor and are never
-    written; the sharded ``chunk_step`` compiles in seconds and is.
-
-    Merely flipping ``jax_compilation_cache_dir`` is NOT enough: the
-    cache object and the per-backend "is the cache used" verdict are
-    both LATCHED at first use (``compilation_cache._initialize_cache``
-    / ``is_cache_used``), so a live cache keeps serving reads whatever
-    the config says.  The guard therefore calls
-    ``compilation_cache.reset_cache()`` with the dir unset — the next
-    compile re-initializes to "disabled" — and resets again on exit so
-    the restored dir re-latches lazily.  Single-device programs and
-    TPU/GPU backends round-trip fine, so the guard activates ONLY for a
-    >1-device CPU mesh with a cache dir configured — everything else
-    keeps its cache semantics untouched."""
-    try:
-        active = (len(mesh.devices.flat) > 1
-                  and next(iter(mesh.devices.flat)).platform == "cpu"
-                  and jax.config.jax_compilation_cache_dir)
-    except Exception:
-        active = False
-    if not active:
-        yield
-        return
-    from jax._src import compilation_cache as _cc
-    old = jax.config.jax_compilation_cache_dir
-    try:
-        jax.config.update("jax_compilation_cache_dir", None)
-        _cc.reset_cache()
-        yield
-    finally:
-        jax.config.update("jax_compilation_cache_dir", old)
-        _cc.reset_cache()
 
 
 # -------------------------------------------------------- shard/gather fns

@@ -10,7 +10,7 @@ makes the stack *survive* it, and proves each path with injected faults:
   into the fused episode programs + the trainer's last-good rollback
   snapshot.
 - :mod:`~gsc_tpu.resilience.retry` — bounded exponential backoff around
-  episode dispatch for transient ``XlaRuntimeError``-like failures.
+  episode dispatch for transient runtime-error-like failures.
 - :mod:`~gsc_tpu.resilience.preempt` — SIGTERM/SIGINT ->
   snapshot-and-exit-cleanly.
 - :mod:`~gsc_tpu.resilience.ckpt` — checksummed periodic checkpoints with

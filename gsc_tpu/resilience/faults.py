@@ -19,7 +19,7 @@ site                 effect when the keyed episode is reached
                      the watchdog, whose escalation interrupts/restarts the
                      prefetcher (the sleep aborts early on prefetcher stop)
 ``dispatch_transient`` episode dispatch raises a transient
-                     ``XlaRuntimeError``-like failure once; the retry layer
+                     runtime-error-like failure once; the retry layer
                      backs off and re-dispatches
 ``nan_grads``        the learner state entering the keyed episode is
                      poisoned with NaN (the effect of a NaN gradient

@@ -24,9 +24,11 @@ Either way the flushed batch runs in the smallest configured bucket that
 fits it, padded by repeating the last real request (see
 ``ObsTemplate.stack_pad``); answers are sliced back per request.
 
-Each request's answer is bit-identical regardless of batch-mates: the
-bucketed policy is a ``vmap`` over the request axis, so rows never
-interact (test-asserted padding-invariance).  Latency accounting flows
+Within one bucket each request's answer is bit-identical regardless of
+batch-mates: the bucketed policy is a ``vmap`` over the request axis, so
+rows never interact (test-asserted padding-invariance).  Across buckets
+the executables are compiled separately and agree to f32 rounding, not
+bit for bit.  Latency accounting flows
 through the shared :class:`~gsc_tpu.obs.MetricsHub`:
 
 - ``serve_latency_ms`` histogram (overall and tagged per bucket),

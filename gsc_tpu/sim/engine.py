@@ -31,7 +31,7 @@ flowsimulator.py:72-128):
     node capacity admission through per-SF resource functions
     (base_processor.py:24-35, 51-101), startup-delay wait, delayed load
     release after the flow duration
- 7. departures and drops with the reference's 4-reason taxonomy
+ 7. departures and drops with the reference's 4-reason classification
     (metrics.py:144-164; a drop with TTL<=0 is always recorded as TTL)
 
 Known, documented divergences from the event-driven reference:
@@ -237,6 +237,13 @@ class SimEngine:
         max_hold = (self.H - 1) * self.dt
         if cfg.run_duration > max_hold:
             raise ValueError("release_horizon must cover at least one run_duration")
+        if cfg.substep_impl == "pallas" and jax.default_backend() != "cpu":
+            # tried on the chip (PR 21, TPU v5 lite, jax 0.9.0): Mosaic
+            # refuses the kernel body at its first dynamic gather
+            raise ValueError(
+                "substep_impl='pallas' runs on the CPU backend only — "
+                "Pallas cannot lower its sort and dynamic gathers for "
+                f"{jax.default_backend()!r}; use substep_impl='xla'")
         # static deterministic-processing-delay flag, shared by both
         # substep impls (the pallas path draws its noise OUTSIDE the
         # kernel with the same key, so the rng stream is impl-invariant)

@@ -2,7 +2,7 @@
 
 The reference's own tests only cover the interface contract
 (src/tests/test_simulatorInterface.py); these go further and pin the
-simulator's *semantics* — per-flow timelines, drop taxonomy, WRR splits —
+simulator's *semantics* — per-flow timelines, drop classification, WRR splits —
 on scenarios small enough to verify by hand against the reference's rules
 (coordsim/simulation/flowsimulator.py:72-128 and its components).
 """

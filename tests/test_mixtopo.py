@@ -15,7 +15,7 @@ The PR-9 contract: one device batch carries MANY networks.  Tests cover
   bucket/stack memoization, mix-grammar errors;
 - mid-episode capacity faults: link/node rows zero at the planned
   interval inside the scanned episode, and a dead link actually drops
-  flows with the LINK_CAP taxonomy.
+  flows with the LINK_CAP classification.
 """
 import dataclasses
 
@@ -284,7 +284,7 @@ def test_fault_plan_zeroes_capacity_tables():
                           TopologyBucket(8, 8))
 
 
-def test_link_fault_drops_flows_with_linkcap_taxonomy():
+def test_link_fault_drops_flows_with_linkcap_reason():
     """A dead link (interval 0 on line3's only ingress-adjacent edge)
     starves the network: flows drop as LINK_CAP inside the scanned
     episode, while the no-fault control processes traffic."""

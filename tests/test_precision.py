@@ -104,9 +104,10 @@ def test_f32_fused_step_bit_identical_to_two_call_path():
 # --------------------------------------------- pallas-bf16 vs dense-bf16
 @pytest.mark.parametrize("mean_aggr", [True, False])
 def test_pallas_bf16_dense_bf16_parity(mean_aggr):
-    """Interpret-mode BIT parity: the bf16 kernel and the bf16 branch of
-    attention_dense run the same op sequence (bf16 pairwise features and
-    MXU operands, f32 logits/softmax, one rounding at the output)."""
+    """Interpret-mode BIT parity: the bf16 kernel rounds to bf16 exactly
+    where the bf16 branch of attention_dense does (pairwise features,
+    LeakyReLU product, attention weights, output) with f32
+    logits/softmax/accumulators in between."""
     _, ei, em, nm = random_graph(jax.random.PRNGKey(0), batch=(5,))
     adj = dense_adj(ei, em, nm)
     k1, k2, k3, k4 = jax.random.split(jax.random.PRNGKey(3), 4)
@@ -116,8 +117,7 @@ def test_pallas_bf16_dense_bf16_parity(mean_aggr):
     att = jax.random.normal(k3, (F,))
     bias = jax.random.normal(k4, (F,))
     dense = attention_dense(xl, xr, att, bias, adj, mean_aggr)
-    # tile_b=None → the dtype-sized default tile (16 for bf16, so the
-    # batch of 5 exercises the padded single-tile path)
+    # tile_b=None → tiles sized from the shapes (pallas_gat.tile_shape)
     fused = gatv2_pallas(xl, xr, att, bias, adj, mean_aggr,
                          tile_b=None, interpret=True)
     assert dense.dtype == fused.dtype == jnp.bfloat16

@@ -40,7 +40,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _TOOLS = os.path.join(REPO, "tools")
 if _TOOLS not in sys.path:
     sys.path.insert(0, _TOOLS)
-from reward_curve import no_tpu_env  # noqa: E402  (single env-sanitizer)
 
 pytestmark = pytest.mark.skipif(
     not os.path.isdir(REFERENCE),
@@ -190,7 +189,7 @@ def test_engine_matches_reference(name):
 def test_oracle_numbers_are_current(name):
     """Re-run the reference itself and verify the frozen constants."""
     want = ORACLE[name]
-    env = no_tpu_env()  # skip TPU registration: no jax
+    env = dict(os.environ)   # the reference run is jax-free
     r = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools", "run_reference.py"),
          "--mode", "interface", "--network", want["network"],
@@ -260,7 +259,7 @@ def test_perflow_engine_matches_reference():
 def test_perflow_oracle_numbers_are_current():
     """Re-run the reference FlowController itself and verify the frozen
     constants."""
-    env = no_tpu_env()
+    env = dict(os.environ)
     r = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools", "run_reference.py"),
          "--mode", "perflow", "--network", PERFLOW["network"],
@@ -285,7 +284,7 @@ def test_reward_curve_matches_reference():
     constant reward offset through the /15 diameter term); shape must
     match to r > 0.99.  tools/reward_curve.py is the measurement; 25
     steps keeps CI cost at half the 50-step exhibit."""
-    env = no_tpu_env()
+    env = dict(os.environ)
     r = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools", "reward_curve.py"),
          "--steps", "25"],

@@ -599,10 +599,7 @@ def test_async_sigterm_resume_auto_roundtrip(tmp_path):
     args = write_tiny_configs(tmp_path)
     res = str(tmp_path / "res")
     env = dict(os.environ)
-    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=REPO,
-               JAX_COMPILATION_CACHE_DIR=os.path.join(REPO, ".jax_cache"),
-               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="1",
-               JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="-1")
+    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=REPO)
     proc = subprocess.Popen(
         [sys.executable, "-m", "gsc_tpu.cli", "train", *args,
          "--episodes", "500", "--replicas", "2", "--async",

@@ -136,8 +136,14 @@ def test_batcher_buckets_and_deadline(served, tmp_path):
         for o in outs[1:]:
             np.testing.assert_array_equal(o, ref)
         assert hub.get_counter("serve_batches_total", bucket=4) == 1
-        # lone request: the deadline (not a batch-mate) flushes it
-        np.testing.assert_array_equal(srv.submit_sync(obs, timeout=60), ref)
+        # lone request: the deadline (not a batch-mate) flushes it — in
+        # the 1-bucket, a DIFFERENT compiled executable.  Bit-equality is
+        # the contract within one bucket (above, and
+        # test_batch_mate_and_padding_invariance); across buckets XLA is
+        # free to tile its reductions per batch size, so answers agree to
+        # f32 rounding only (one ulp, 6e-8, on jaxlib 0.9.0)
+        np.testing.assert_allclose(srv.submit_sync(obs, timeout=60), ref,
+                                   rtol=1e-6, atol=1e-6)
         assert hub.get_counter("serve_batches_total", bucket=1) == 1
         assert hub.get_counter("serve_requests_total") == 5
         lat = hub.histogram_summary("serve_latency_ms")

@@ -114,8 +114,9 @@ def test_meshspec_is_the_one_grammar():
     with pytest.raises(ValueError, match="unknown rulebook"):
         validate_partition_rules("zerO")
     # jax-free by contract: no import statement in the module (or the
-    # package __init__ it pulls in) may touch jax — bench.py's
-    # orchestrator depends on it
+    # package __init__ it pulls in) may touch jax — argument parsing
+    # (bench.py) and the jax-free dryrun launcher validate mesh specs
+    # before any backend exists
     import ast
     import importlib
 

@@ -307,7 +307,9 @@ def _perf_row(d: Dict) -> Dict:
             if _num(col.get(k)) is not None:
                 metrics[f"{name}_collective_{k}"] = float(col[k])
     return {"kind": "perf_ledger", "status": "ok", "metrics": metrics,
-            "context": {"backend": d.get("backend"), "run": d.get("run"),
+            "context": {"backend": d.get("backend"),
+                        "device_kind": d.get("device_kind"),
+                        "run": d.get("run"),
                         "ledger_schema": d.get("schema_version")}}
 
 

@@ -1,5 +1,11 @@
 """Chaos smoke: tiny fault-injected train runs must self-heal to rc=0.
 
+CPU-only: this tool starts JAX child processes (and, for the smokes,
+runs JAX in the parent first), and a chip belongs to one process at a
+time — it refuses to start unless ``JAX_PLATFORMS=cpu``
+(``gsc_tpu.runtime.require_cpu_env``).  Nothing it prints is a device
+number.
+
 The CI-stage proof that the resilience subsystem's recovery paths actually
 execute, in two legs:
 
@@ -64,18 +70,12 @@ ASYNC_EXPECTED = {("actor", "restart"), ("replay", "quarantine"),
 
 
 def _configure_jax():
-    import jax
+    """CPU-only tool (its parent starts JAX children): refuse any other
+    platform, then apply the repo's compile-cache rule."""
+    from gsc_tpu.runtime import enable_compile_cache, require_cpu_env
 
-    jax.config.update("jax_platforms", "cpu")
-    try:   # the repo-shared persistent compile cache keeps this stage fast
-        jax.config.update(
-            "jax_compilation_cache_dir",
-            os.path.join(os.path.dirname(os.path.dirname(
-                os.path.abspath(__file__))), ".jax_cache"))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:
-        pass
+    require_cpu_env("tools/chaos_smoke.py")
+    enable_compile_cache()
 
 
 def write_tiny_configs(cfg: str):
@@ -112,13 +112,11 @@ def write_tiny_configs(cfg: str):
 
 
 def _cli_env() -> dict:
-    """Fresh-subprocess environment: CPU jax + the repo-shared persistent
-    compile cache (the subprocess's compiles are disk hits)."""
+    """Fresh-subprocess environment: CPU jax (the CLI applies the repo's
+    compile-cache rule itself, so the subprocess's compiles are disk
+    hits)."""
     env = dict(os.environ)
-    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=REPO,
-               JAX_COMPILATION_CACHE_DIR=os.path.join(REPO, ".jax_cache"),
-               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="1",
-               JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="-1")
+    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=REPO)
     return env
 
 

@@ -237,6 +237,7 @@ def perf_summary(perf: Optional[Dict]) -> Optional[Dict]:
     return {
         "schema_version": perf.get("schema_version"),
         "backend": perf.get("backend"),
+        "device_kind": perf.get("device_kind"),
         "peaks": perf.get("peaks"),
         "entries": rows,
         # device-vs-host split: dispatch wall is time handing work to the
@@ -1202,8 +1203,9 @@ def _synthetic_perf(path: str):
     """A cost-ledger document with the gsc_tpu.obs.perf schema."""
     with open(path, "w") as f:
         json.dump({
-            "schema_version": 1, "ts": 1_000_000_000.0, "backend": "cpu",
-            "peaks": {"flops_per_s": 5e10, "bytes_per_s": 2e10},
+            "schema_version": 1, "ts": 1_000_000_000.0, "backend": "tpu",
+            "device_kind": "TPU v5 lite", "device_count": 1,
+            "peaks": {"flops_per_s": 197e12, "bytes_per_s": 819e9},
             "run": "selftest",
             "entries": {
                 "episode_step": {
@@ -1214,7 +1216,7 @@ def _synthetic_perf(path: str):
                     "arithmetic_intensity": 0.9848,
                     "dispatches": 5, "wall_s_total": 0.05,
                     "wall_s_mean": 0.01, "mfu": 0.0133,
-                    "roofline": {"intensity": 0.9848, "ridge": 2.5,
+                    "roofline": {"intensity": 0.9848, "ridge": 240.5,
                                  "regime": "memory_bound",
                                  "roof_multiple": 29.5}},
                 "chunk_step_sharded": {
@@ -1247,7 +1249,8 @@ def selftest() -> int:
         # perf section: ledger rows condensed, schema version surfaced,
         # the unavailable serve entry kept visible rather than dropped
         pf = summary["perf"]
-        assert pf["schema_version"] == 1 and pf["backend"] == "cpu", pf
+        assert pf["schema_version"] == 1 and pf["backend"] == "tpu" \
+            and pf["device_kind"] == "TPU v5 lite", pf
         row = pf["entries"]["episode_step"]
         assert row["fusions"] == 718 and row["mfu"] == 0.0133 \
             and row["regime"] == "memory_bound" \

@@ -36,14 +36,10 @@ sys.path.insert(0, os.path.join(REPO, "tools"))
 def _configure_jax():
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
-    try:   # the repo-shared persistent compile cache keeps this stage fast
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.join(REPO, ".jax_cache"))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:
-        pass
+    from gsc_tpu.runtime import enable_compile_cache
+
+    jax.config.update("jax_platforms", "cpu")   # a CPU smoke, explicitly
+    enable_compile_cache()
 
 
 def fail(msg: str) -> int:
@@ -80,14 +76,19 @@ def main() -> int:
     perf = json.load(open(perf_path))
     e = (perf.get("entries") or {}).get("episode_step") or {}
     for field in ("flops", "bytes_accessed", "fusions", "dispatches",
-                  "wall_s_mean", "mfu"):
+                  "wall_s_mean", "achieved_flops_per_s"):
         if not e.get(field):
             return fail(f"perf.json episode_step missing/zero {field!r}: "
                         f"{e}")
     if e["dispatches"] != 3:
         return fail(f"expected 3 dispatches, ledger has {e['dispatches']}")
+    # a CPU is not in the peaks table: no MFU, and the document says so
+    if perf.get("peaks") is not None or "mfu" in e \
+            or perf.get("device_kind") not in (perf.get("peaks_note") or ""):
+        return fail("a CPU run must carry no peaks/mfu and a peaks_note "
+                    f"naming the device: {perf.get('peaks')!r} / {e}")
     print(f"perfobs smoke: ledger ok (schema v{perf['schema_version']}, "
-          f"{e['fusions']} fusions, mfu {e['mfu']})")
+          f"{e['fusions']} fusions, no mfu on {perf['device_kind']!r})")
 
     # rotation actually happened and the report reader reassembles it
     if not os.path.exists(os.path.join(rdir, "events.jsonl.1")):

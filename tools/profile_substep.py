@@ -9,7 +9,7 @@ trace-events JSON (.gz) for the device track, and prints the top-K ops by
 total self duration plus the per-substep wall.
 
     python tools/profile_substep.py --replicas 256 --chunk 50
-    python tools/profile_substep.py --cpu --replicas 4 --chunk 5  # smoke
+    JAX_PLATFORMS=cpu python tools/profile_substep.py --replicas 4 --chunk 5  # smoke
 
 Only FRESH trace dirs are globbed (stale files double-count — r3 gotcha).
 
@@ -19,10 +19,11 @@ the chunked rollout call, reads XLA's own per-executable cost analysis
 static dot shapes), times the call, and prints sustained FLOP/s vs chip
 peak plus the arithmetic-intensity regime.  This is the VERDICT r4 item:
 "what fraction of peak does the chip sustain, and is the substep
-FLOP-bound or op-count-bound at B=256?"
+FLOP-bound or op-count-bound at B=256?"  The peaks come from the ONE
+table, ``gsc_tpu.obs.perf.DEVICE_PEAKS``, keyed by ``device_kind``; on a
+device that is not in it (any CPU) ``--mfu`` is an error, not a default.
 
     python tools/profile_substep.py --mfu --replicas 64 256 512
-    python tools/profile_substep.py --mfu --cpu --replicas 2 4 --chunk 5
 """
 from __future__ import annotations
 
@@ -37,11 +38,6 @@ import tempfile
 import time
 
 sys.path.insert(0, __file__.rsplit("/", 2)[0])
-
-
-# TPU v5e (v5 lite) single-chip peaks; overridable for other parts.
-PEAK_BF16_FLOPS = float(os.environ.get("GSC_PEAK_BF16_FLOPS", 197e12))
-PEAK_HBM_BPS = float(os.environ.get("GSC_PEAK_HBM_BPS", 819e9))
 
 
 def _build(env_steps, B, chunk):
@@ -72,14 +68,9 @@ def _build(env_steps, B, chunk):
 
 
 def _cost(compiled):
-    """Flops/bytes from XLA's executable cost analysis (version-tolerant:
-    older jaxlibs return a per-device list)."""
+    """Flops/bytes from XLA's executable cost analysis."""
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0]
-    flops = float(ca.get("flops", 0.0))
-    byts = float(ca.get("bytes accessed", 0.0))
-    return flops, byts
+    return float(ca.get("flops", 0.0)), float(ca.get("bytes accessed", 0.0))
 
 
 def mfu_sweep(args):
@@ -91,7 +82,17 @@ def mfu_sweep(args):
     import jax
 
     from gsc_tpu.analysis.hlo import count_fusions
+    from gsc_tpu.obs.perf import device_peaks
+    from gsc_tpu.runtime import device_fields
 
+    device = device_fields()
+    peaks = device_peaks(device["device_kind"])
+    if peaks is None:
+        raise SystemExit(
+            f"--mfu: device_kind {device['device_kind']!r} is not in "
+            "gsc_tpu.obs.perf.DEVICE_PEAKS — add its published peaks "
+            "with their source there; no default is applied")
+    peak_flops, peak_bps = peaks["flops_per_s"], peaks["bytes_per_s"]
     chunk = args.chunk
     rows = []
     for B in args.replicas:
@@ -109,8 +110,8 @@ def mfu_sweep(args):
         wall = (time.time() - t0) / args.calls
         # per-substep figures: one rollout call = chunk control steps, each
         # sim_cfg.run_duration/dt substeps; flops is per CALL
-        t_flops = flops / PEAK_BF16_FLOPS
-        t_bytes = byts / PEAK_HBM_BPS
+        t_flops = flops / peak_flops
+        t_bytes = byts / peak_bps
         roof = max(t_flops, t_bytes)
         if wall > 3 * roof:
             regime = "op-count-bound"
@@ -119,16 +120,14 @@ def mfu_sweep(args):
         else:
             regime = "bytes-bound"
         rows.append({
-            "backend": jax.default_backend(),  # TPU peaks are meaningless
-                                               # on the --cpu smoke path
-            "replicas": B, "chunk": chunk,
+            **device, "replicas": B, "chunk": chunk,
             "wall_per_call_s": round(wall, 4),
             "env_steps_per_sec": round(chunk * B / wall, 1),
             "gflops_per_call": round(flops / 1e9, 2),
             "gbytes_per_call": round(byts / 1e9, 3),
             "sustained_tflops": round(flops / wall / 1e12, 3),
-            "mfu_vs_bf16_peak": round(flops / wall / PEAK_BF16_FLOPS, 4),
-            "hbm_frac": round(byts / wall / PEAK_HBM_BPS, 4),
+            "mfu_vs_bf16_peak": round(flops / wall / peak_flops, 4),
+            "hbm_frac": round(byts / wall / peak_bps, 4),
             "arith_intensity": round(flops / max(byts, 1.0), 2),
             "compute_roof_s": round(t_flops, 5),
             "memory_roof_s": round(t_bytes, 5),
@@ -136,9 +135,10 @@ def mfu_sweep(args):
             "regime": regime,
         })
         print(json.dumps(rows[-1]))
-    print(json.dumps({"backend": jax.default_backend(),
-                      "peak_bf16_tflops": PEAK_BF16_FLOPS / 1e12,
-                      "peak_hbm_gbps": PEAK_HBM_BPS / 1e9,
+    print(json.dumps({**device,
+                      "peak_bf16_tflops": peak_flops / 1e12,
+                      "peak_hbm_gbps": peak_bps / 1e9,
+                      "peaks_source": peaks["source"],
                       "note": ("engine dots run f32 Precision.HIGHEST "
                                "(multi-pass bf16 on the MXU), so MXU "
                                "issue-slot occupancy is ~3-6x the raw "
@@ -152,15 +152,12 @@ def main():
     ap.add_argument("--chunk", type=int, default=50)
     ap.add_argument("--calls", type=int, default=3)
     ap.add_argument("--top", type=int, default=25)
-    ap.add_argument("--cpu", action="store_true")
     ap.add_argument("--episode-steps", type=int, default=200)
     ap.add_argument("--mfu", action="store_true",
                     help="roofline sweep over --replicas instead of a trace")
     args = ap.parse_args()
 
     import jax
-    if args.cpu:
-        jax.config.update("jax_platforms", "cpu")
 
     if args.mfu:
         mfu_sweep(args)
@@ -169,6 +166,8 @@ def main():
     if len(args.replicas) > 1:
         raise SystemExit("trace mode profiles ONE replica count; pass a "
                          "single --replicas value (or use --mfu to sweep)")
+    from gsc_tpu.runtime import device_fields
+
     B, chunk = args.replicas[0], args.chunk
     call, (state, buffers, env_states, obs) = _build(
         args.episode_steps, B, chunk)
@@ -221,7 +220,7 @@ def main():
     total = sum(agg.values())
     env_steps = args.calls * chunk * B
     print(json.dumps({
-        "backend": jax.default_backend(), "replicas": B, "chunk": chunk,
+        **device_fields(), "replicas": B, "chunk": chunk,
         "calls": args.calls, "wall_s": round(wall, 3),
         "env_steps_per_sec": round(env_steps / wall, 1),
         "trace_total_us": total,

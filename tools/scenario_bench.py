@@ -1,5 +1,11 @@
 """SCEN bench: host-regen vs on-device scenario factory at equal B.
 
+CPU-only: this tool starts JAX child processes (and, for the smokes,
+runs JAX in the parent first), and a chip belongs to one process at a
+time — it refuses to start unless ``JAX_PLATFORMS=cpu``
+(``gsc_tpu.runtime.require_cpu_env``).  Nothing it prints is a device
+number.
+
 The factory's throughput claim, measured instead of asserted: two
 fresh-subprocess legs run the SAME replica-parallel training shape
 (equal B, equal episode_steps/chunk, per-episode scenario regeneration)
@@ -51,18 +57,10 @@ LEG_TIMEOUT_S = 900
 
 
 def _configure_jax():
-    import jax
+    """main() has already refused anything but JAX_PLATFORMS=cpu."""
+    from gsc_tpu.runtime import enable_compile_cache
 
-    jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update(
-            "jax_compilation_cache_dir",
-            os.path.join(os.path.dirname(os.path.dirname(
-                os.path.abspath(__file__))), ".jax_cache"))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:
-        pass
+    enable_compile_cache()
 
 
 def worker(leg: str) -> int:
@@ -195,6 +193,8 @@ def _run_leg(leg: str) -> dict:
 
 
 def main(argv=None) -> int:
+    from gsc_tpu.runtime import require_cpu_env
+    require_cpu_env("tools/scenario_bench.py")
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--worker", default=None,
                     help="run one leg in-process (factory|host_regen)")

@@ -50,10 +50,9 @@ sys.path.insert(0, os.path.join(REPO, "tools"))
 def _env():
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    # both caches on: the artifact cache is the subject under test, the
-    # persistent XLA cache is what makes the deserialized module's backend
-    # compile skippable across processes too
-    env.setdefault("GSC_JAX_CACHE_DIR", os.path.join(REPO, ".jax_cache"))
+    # both caches on: the artifact cache is the subject under test; the
+    # persistent XLA cache (on by default in the CLI) is what makes the
+    # deserialized module's backend compile skippable across processes
     return env
 
 

@@ -45,16 +45,10 @@ EPISODES = 3
 def _configure_jax():
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
-    try:   # the repo-shared persistent compile cache keeps this stage fast
-        jax.config.update(
-            "jax_compilation_cache_dir",
-            os.path.join(os.path.dirname(os.path.dirname(
-                os.path.abspath(__file__))), ".jax_cache"))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:
-        pass
+    from gsc_tpu.runtime import enable_compile_cache
+
+    jax.config.update("jax_platforms", "cpu")   # a CPU smoke, explicitly
+    enable_compile_cache()
 
 
 def fail(msg: str) -> int:
