@@ -1,0 +1,453 @@
+"""Driver: whole-episode training windows through
+``gsc_tpu.agents.trainer.Trainer.train_parallel``, built as ``cli train
+<agent> <sim> <service> <scheduler> --replicas B --chunk C --ckpt-interval
+1`` builds it (``cli._build``, the ``RunObserver`` and ``Trainer``
+constructors with the CLI's defaults) from YAML/graphml files written
+into a temporary directory from the cell's configuration file.
+
+No copy of the episode loop lives here.  Three of the entry point's own
+parameters carry the benchmark:
+
+- ``preempt``: the harness's :class:`~benchmarks.harness.Window`, whose
+  ``triggered`` the loop reads at every episode boundary;
+- ``init_state``: the learner state made here from the seed (the resume
+  path), so that the plain reference starts from the same weights without
+  taking anything the program made;
+- ``ckpt_manager`` with ``ckpt_interval=1``: a recorder that, once, at the
+  end of the first episode, keeps a device copy of the learner state and a
+  host copy of that episode's replay rows for the output check; at every
+  call it keeps a host copy of the actor parameters alone (a megabyte, as
+  the loop's own finite check has just copied the whole learner state;
+  no device operation), so that the parameters which drove the window's
+  last episode are at hand when the window has closed.  Nothing is
+  written to disk.
+"""
+from __future__ import annotations
+
+import json
+import os
+import shutil
+import sys
+import tempfile
+import time
+import zlib
+from typing import Callable, Dict
+
+CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if CHECKOUT not in sys.path:
+    sys.path.insert(0, CHECKOUT)
+
+RESERVED = ("source", "deployment", "reduced", "published", "assumed",
+            "network", "simulator", "service", "scheduler", "max_nodes",
+            "max_edges", "matmul_precision", "factored_head_threshold")
+EPISODES_CAP = 1_000_000   # the window stops the loop, never this count
+
+
+def prepare(cell: dict) -> None:
+    """Process-level JAX set-up, before any device is touched: the
+    program's own compile-cache rule (``JAX_COMPILATION_CACHE_DIR`` where
+    set, else ``<checkout>/.jax_cache``) and the contraction precision
+    the configuration states."""
+    import jax
+    from gsc_tpu.runtime import enable_compile_cache
+
+    enable_compile_cache()
+    jax.config.update("jax_default_matmul_precision",
+                      cell["config"]["matmul_precision"])
+
+
+# ------------------------------------------------------------------ files
+def write_inputs(cfg: dict, out: str) -> Dict[str, str]:
+    """The four files ``cli train`` takes, plus the network, from the
+    configuration file (the writers are ``yaml`` as ``cli init-configs``
+    uses it and ``topology.synthetic.write_graphml``)."""
+    import yaml
+    from gsc_tpu.topology import synthetic
+
+    os.makedirs(os.path.join(out, "networks"), exist_ok=True)
+    net = cfg["network"]
+    spec = getattr(synthetic, net["generator"])(**net.get("kwargs", {}))
+    net_path = os.path.join(out, "networks", net["file"])
+    synthetic.write_graphml(spec, net_path)
+    paths = {"network": net_path}
+    docs = {
+        "agent": {k: v for k, v in cfg.items() if k not in RESERVED},
+        "simulator": cfg["simulator"],
+        "service": cfg["service"],
+        "scheduler": {"training_network_files": [net_path],
+                      "inference_network": net_path,
+                      **cfg.get("scheduler", {})},
+    }
+    for name, doc in docs.items():
+        paths[name] = os.path.join(out, f"{name}.yaml")
+        with open(paths[name], "w") as f:
+            yaml.safe_dump(doc, f)
+    paths["spec"] = spec
+    return paths
+
+
+# ---------------------------------------------------------------- weights
+def leaf_name(path) -> str:
+    """A pytree path as the checkpoint layout's slash-joined leaf name."""
+    parts = []
+    for p in path:
+        for attr in ("name", "key", "idx"):
+            if hasattr(p, attr):
+                parts.append(str(getattr(p, attr)))
+                break
+        else:
+            parts.append(str(p))
+    return "/".join(parts)
+
+
+def leaf_table(tree) -> Dict[str, object]:
+    import jax
+
+    return {leaf_name(p): l
+            for p, l in jax.tree_util.tree_flatten_with_path(tree)[0]}
+
+
+def make_weights(seed: int, shapes: Dict[str, tuple]):
+    """Every network leaf from the seed in one jitted call on the device:
+    matrices Glorot-uniform from their own shape, vectors zero (the
+    initialisers of the program's modules, keyed here by leaf name so the
+    values do not depend on the program's own key schedule)."""
+    import jax
+    import jax.numpy as jnp
+
+    def make(key):
+        out = {}
+        for name, shape in sorted(shapes.items()):
+            if len(shape) < 2:
+                out[name] = jnp.zeros(shape, jnp.float32)
+                continue
+            k = jax.random.fold_in(key, zlib.crc32(name.encode()) & 0x7FFFFFFF)
+            lim = (6.0 / (shape[0] + shape[1])) ** 0.5
+            out[name] = jax.random.uniform(k, shape, jnp.float32, -lim, lim)
+        return out
+
+    return jax.jit(make)(jax.random.PRNGKey(seed))
+
+
+def make_init_state(seed: int, state_shape):
+    """A ``DDPGState`` of the program's layout filled from the seed:
+    online and target networks from :func:`make_weights`, optimiser
+    moments and counts zero, the learner key folded from the seed.
+    Returns (state, weights by reference name, learner key)."""
+    import jax
+    import jax.numpy as jnp
+
+    flat, treedef = jax.tree_util.tree_flatten_with_path(state_shape)
+    names = [leaf_name(p) for p, _ in flat]
+    nets = {"actor_params": "actor", "target_actor_params": "actor",
+            "critic_params": "critic", "target_critic_params": "critic"}
+    shapes = {}
+    for name, (_, leaf) in zip(names, flat):
+        head, _, rest = name.partition("/")
+        if head in ("actor_params", "critic_params"):
+            shapes[f"{nets[head]}/{rest}"] = tuple(leaf.shape)
+    weights = make_weights(seed, shapes)
+    rng = jax.random.fold_in(jax.random.PRNGKey(seed), 7)
+    leaves = []
+    for name, (_, leaf) in zip(names, flat):
+        head, _, rest = name.partition("/")
+        if head in nets:
+            leaves.append(weights[f"{nets[head]}/{rest}"])
+        elif head == "rng":
+            leaves.append(rng)
+        else:
+            leaves.append(jnp.zeros(leaf.shape, leaf.dtype))
+    return jax.tree_util.tree_unflatten(treedef, leaves), weights, rng
+
+
+# --------------------------------------------------------------- recorder
+class MemorySink:
+    """Hub sink keeping the run's events in memory for the readers."""
+
+    def __init__(self):
+        self.events = []
+
+    def emit(self, record):
+        self.events.append(record)
+
+    def close(self):
+        pass
+
+
+def ring_rows(buffers, index, prefixes=("",)):
+    """Rows of the replay ring as host arrays [replicas, slots, ...], by
+    leaf name and each row in its stored shape: ``index`` picks replicas
+    and slots (two slices, or two index arrays that broadcast);
+    ``prefixes`` selects the leaves."""
+    import jax
+    import numpy as np
+
+    leaves = jax.tree_util.tree_flatten_with_path(buffers.data)[0]
+    shapes = buffers.shapes or (None,) * len(leaves)
+    rows = {}
+    for (path, leaf), shape in zip(leaves, shapes):
+        name = leaf_name(path)
+        if not name.startswith(tuple(prefixes)):
+            continue
+        part = np.asarray(leaf[index])
+        if shape is not None:
+            part = part.reshape(part.shape[:2] + tuple(shape))
+        rows[name] = part
+    return rows
+
+
+class Recorder:
+    """Stands where ``cli train`` puts its ``CheckpointManager``.  At the
+    end of episode 0 it keeps what the output check follows: a device copy
+    of the learner state and a host copy of the episode's replay rows.  At
+    every call it keeps a host copy of the actor parameters (the last two
+    calls' only; a device copy would put operations after the program's
+    last into the traced slice), and stamps its entry: the loop's finite check of the
+    learner state, which the checkpoint cadence brings, lies between the
+    episode's event and that stamp.  Nothing is written to disk."""
+
+    def __init__(self, episode_steps: int):
+        self.steps = int(episode_steps)
+        self.state = None
+        self.rows = None
+        self.seconds = 0.0
+        self.actor = {}          # save number -> host copy, last two
+        self.entered = {}        # save number -> host clock at entry
+
+    def save(self, state, buffers, episode: int, **_):
+        import jax
+        import jax.numpy as jnp
+
+        self.entered[episode] = time.time()
+        self.actor[episode] = jax.device_get(state.actor_params)
+        self.actor.pop(episode - 2, None)
+        if episode != 1 or self.state is not None:
+            return None
+        t0 = time.time()
+        self.state = jax.tree_util.tree_map(jnp.copy, state)
+        self.rows = ring_rows(buffers, (slice(None), slice(self.steps)))
+        self.seconds = time.time() - t0
+        return None
+
+
+class Tracer:
+    """Traces the last ``slice_s`` seconds of the window's second episode
+    with ``jax.profiler`` (the cell's ``trace_slice_s``: a few times the
+    learn burst, which ends the episode).  A timer thread starts the
+    trace that long before the episode's expected end — the window's
+    first, untraced, episode gives the length — and the closing boundary
+    stops it, with the device idle.
+
+    Why a slice, and why this one (measured, PR 24): the flagship runs
+    about 445 000 device operations a second in the rollout and about 5
+    million a second in the learn burst; the profiler keeps some 6.2
+    million and drops the rest, and handing them over costs about 30
+    microseconds an event with the device idle and 105 while a program
+    runs.  So neither a whole 25.6 s episode, nor a slice that has to be
+    stopped in mid-episode, nor one long enough for a whole ``chunk_step``
+    execution fits a run's 360 seconds with room."""
+
+    EPISODES = 2      # a traced run's window: one clean episode, one traced
+
+    def __init__(self, out_dir: str, slice_s: float):
+        self.dir = out_dir
+        self.slice_s = float(slice_s)
+        self.started = None
+        self.stopped = None
+        self.stop_s = None
+        self._opened = None
+        self._timer = None
+
+    def _start(self):
+        import jax
+
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(self.dir, profiler_options=opts)
+        self.started = time.time()
+
+    def __call__(self, k: int, now: float):
+        import threading
+
+        if k == 0:
+            self._opened = now
+        elif k == 1 and self._timer is None:
+            delay = max(now - self._opened - self.slice_s, 0.0)
+            self._timer = threading.Timer(delay, self._start)
+            self._timer.daemon = True
+            self._timer.start()
+        elif k >= 2:
+            self.finish()
+
+    def finish(self):
+        """Stop the trace if it runs; leave no timer and no session."""
+        import jax
+
+        if self._timer is not None:
+            self._timer.cancel()
+            self._timer.join()
+        if self.started is not None and self.stopped is None:
+            self.stopped = time.time()
+            jax.profiler.stop_trace()
+            self.stop_s = time.time() - self.stopped
+
+
+# -------------------------------------------------------------------- run
+def run(cell: dict, seed: int, seconds: float, traced: bool,
+        t_start: float, peaks: dict, log: Callable = print,
+        probe: Callable = None) -> dict:
+    """One run of the cell: set-up, the measured window, the output
+    check.  Returns the run record the metric readers take:
+
+    ``cell seed replicas chunk episode_steps warm_episodes config peaks
+    flops`` (what ran); ``t_start stamps opened closed_at window_episodes
+    hook_s`` (the window: every boundary stamp, set-up's included);
+    ``events`` (the run's hub events: ``episode`` with cumulative
+    ``phases``, ``harness_episode``, ``learn_signal``, ``compile``,
+    ``recovery``); ``memory_peak_bytes``; ``trace`` (``trace.reduce``'s
+    result, traced runs only); ``correct compared values rng_after
+    attempted failed error`` (the output check).  ``probe`` (``control.py``) is handed the
+    check's inputs afterwards; a benchmark run passes none."""
+    import jax
+    import numpy as np
+
+    from benchmarks import check, flops
+    from benchmarks.harness import Window, memory_peak_bytes
+    from gsc_tpu.agents.trainer import Trainer
+    from gsc_tpu.cli import _build
+    from gsc_tpu.obs import RunObserver
+
+    cfg, wl = cell["config"], cell["cell"]
+    replicas, chunk = int(wl["replicas"]), int(wl["chunk"])
+    steps = int(cfg["episode_steps"])
+    warm = -(-int(cfg["nb_steps_warmup_critic"]) // steps)
+    tmp = tempfile.mkdtemp(prefix="gsc-bench-")
+    tracer = Tracer(os.path.join(tmp, "trace"), wl["trace_slice_s"]) \
+        if traced else None
+    window = Window(seconds, warm, on_boundary=tracer,
+                    episodes=Tracer.EPISODES if traced else None)
+    sink = MemorySink()
+    record = {"cell": cell["name"], "seed": seed, "replicas": replicas,
+              "chunk": chunk, "episode_steps": steps,
+              "warm_episodes": warm, "t_start": t_start, "config": cfg,
+              "peaks": peaks, "trace": None,
+              "flops": flops.model_flops(cfg)}
+    obs = None
+    error = None
+    try:
+        paths = write_inputs(cfg, tmp)
+        env, driver, agent = _build(
+            paths["agent"], paths["simulator"], paths["service"],
+            paths["scheduler"], seed, int(cfg["max_nodes"]),
+            int(cfg["max_edges"]))
+        rdir = os.path.join(tmp, "run")
+        obs = RunObserver(rdir, snapshot_interval=10,
+                          watchdog_budget_s=300.0, watchdog_escalate=3,
+                          perf=True, learn=True, series_window=1024,
+                          tags={"seed": seed})
+        obs.hub.add_sink(sink)
+        obs.start(meta={"replicas": replicas, "seed": seed,
+                        "benchmark_cell": cell["name"]})
+        trainer = Trainer(env, driver, agent, seed=seed, result_dir=rdir,
+                          obs=obs)
+        topo0, traffic0 = driver.episode(0, False)
+        _, obs_shape = jax.eval_shape(env.reset, jax.random.PRNGKey(0),
+                                      topo0, traffic0)
+        state_shape = jax.eval_shape(trainer.ddpg.init,
+                                     jax.random.PRNGKey(0), obs_shape)
+        init_state, weights, rng0 = make_init_state(seed, state_shape)
+        weights_host = {k: np.asarray(v) for k, v in weights.items()}
+        rng0_host = np.asarray(rng0)
+        node_mask = np.asarray(topo0.node_mask)
+        recorder = Recorder(steps)
+        try:
+            state, buffers = trainer.train_parallel(
+                EPISODES_CAP, num_replicas=replicas, chunk=chunk,
+                verbose=False, init_state=init_state,
+                ckpt_manager=recorder, ckpt_interval=1, preempt=window)
+            jax.block_until_ready(state)
+        except Exception as e:  # the run failed: no metric, not correct
+            import traceback
+            traceback.print_exc()
+            error = f"{type(e).__name__}: {e}"
+            state = buffers = None
+        if tracer is not None:
+            tracer.finish()
+        record["memory_peak_bytes"] = memory_peak_bytes()
+        final = None
+        if state is not None:
+            final = {"size": np.asarray(buffers.size),
+                     "pos": np.asarray(buffers.pos),
+                     "capacity": int(jax.tree_util.tree_leaves(
+                         buffers.data)[0].shape[1]),
+                     "state": {k: np.asarray(v)
+                               for k, v in leaf_table(state).items()}}
+        policy = None
+        last = warm + window.episodes - 1
+        if state is not None and window.episodes and last in recorder.actor:
+            # the window's last episode: its rows are the ring's newest,
+            # and the actor that drove it was copied as it began
+            sample = check.sim_sample(seed, replicas)
+            cap = final["capacity"]
+            policy = {
+                "episode": last, "sample": sample,
+                "actor": {f"actor/{k}": np.asarray(v) for k, v in
+                          leaf_table(recorder.actor[last]).items()},
+                "rows": ring_rows(
+                    buffers,
+                    (np.asarray(sample)[:, None],
+                     (last * steps + np.arange(steps))[None, :] % cap),
+                    ("obs/", "action"))}
+        recorder.actor.clear()
+        after = None
+        if recorder.state is not None:
+            after = {k: np.asarray(v)
+                     for k, v in leaf_table(recorder.state).items()}
+        # free the program's device state before the reference runs
+        recorder.state = None
+        del state, buffers, init_state, weights, trainer
+        obs.close(status="preempted" if error is None else "error")
+        obs = None
+        record.update(
+            stamps=list(window.stamps), opened=window.opened,
+            closed_at=window.closed_at, window_episodes=window.episodes,
+            events=sink.events, recorder_s=recorder.seconds, error=error,
+            hook_s=list(window.hook_s))
+        for ev in sink.events:
+            if ev.get("event") in ("compile", "recovery"):
+                log("event", json.dumps(ev))
+        log("boundaries", json.dumps(window.stamps))
+        ended = {e["episode"]: e["ts"] for e in sink.events
+                 if e.get("event") == "episode"}
+        log("ckpt_check_s", json.dumps(
+            {k: round(recorder.entered[k + 1] - ended[k], 3)
+             for k in sorted(ended) if k + 1 in recorder.entered}))
+        if traced and tracer.started and tracer.stopped:
+            from benchmarks import trace as trace_mod
+            t0 = time.time()
+            record["trace"] = trace_mod.reduce_dir(
+                tracer.dir, tracer.started, tracer.stopped)
+            log("trace", json.dumps({
+                "slice_s": tracer.stopped - tracer.started,
+                "stop_s": tracer.stop_s, "reduce_s": time.time() - t0,
+                "events": record["trace"]["n_events"],
+                "last_loops": record["trace"]["top_level_loops"][-4:]}))
+        t_check = time.time()
+        inputs = dict(weights=weights_host, rng=rng0_host,
+                      rows=recorder.rows, after=after, final=final,
+                      node_mask=node_mask, net_spec=paths["spec"],
+                      policy=policy)
+        record.update(check.decide(record, wl.get("limits", {}), log=log,
+                                   **inputs))
+        log("check_s", round(time.time() - t_check, 2), "recorder_s",
+            round(recorder.seconds, 2), "run_s",
+            round(time.time() - t_start, 2))
+        if probe is not None:       # the control's and the faults' readings
+            record["probe"] = probe(record, **inputs)
+    finally:
+        if obs is not None:
+            obs.close(status="error")
+        shutil.rmtree(tmp, ignore_errors=True)
+    return record

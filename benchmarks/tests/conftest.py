@@ -1,0 +1,8 @@
+"""The benchmark's own tests run on the CPU (``pytest benchmarks/tests``;
+not part of ``tests/``): platform pinned before the first backend touch."""
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)))))
