@@ -83,13 +83,14 @@ def buffer_init(example: Any, capacity: int) -> ReplayBuffer:
 def buffer_add(buf: ReplayBuffer, item: Any) -> ReplayBuffer:
     """Insert one transition (buffer.py:33-54)."""
     capacity = jax.tree_util.tree_leaves(buf.data)[0].shape[0]
-    data = jax.tree_util.tree_map(
-        lambda d, x: jax.lax.dynamic_update_index_in_dim(
-            d, jnp.asarray(x).astype(d.dtype), buf.pos, 0),
-        buf.data, flatten_transition(item))
-    return ReplayBuffer(data=data, pos=(buf.pos + 1) % capacity,
-                        size=jnp.minimum(buf.size + 1, capacity),
-                        shapes=buf.shapes)
+    with jax.named_scope("replay_write"):
+        data = jax.tree_util.tree_map(
+            lambda d, x: jax.lax.dynamic_update_index_in_dim(
+                d, jnp.asarray(x).astype(d.dtype), buf.pos, 0),
+            buf.data, flatten_transition(item))
+        return ReplayBuffer(data=data, pos=(buf.pos + 1) % capacity,
+                            size=jnp.minimum(buf.size + 1, capacity),
+                            shapes=buf.shapes)
 
 
 def buffer_nbytes(buf: ReplayBuffer, local: bool = False) -> int:

@@ -236,9 +236,11 @@ class DDPG:
             env_state, obs, perm, buffer = carry
             k = jax.random.fold_in(sub, i)
             step_mask = shuffle.step_mask(obs, mask, perm)
-            action = self.choose_action(state.actor_params, obs, step_mask,
-                                        episode_start_step + i, k)
-            action = self.env.process_action(action)
+            with jax.named_scope("policy_forward"):
+                action = self.choose_action(state.actor_params, obs,
+                                            step_mask, episode_start_step + i,
+                                            k)
+                action = self.env.process_action(action)
             env_state, next_obs, reward, done, info = self.env.step(
                 env_state, topo, traffic, shuffle.env_action(action, perm))
             next_obs, next_perm = shuffle.advance(
@@ -253,8 +255,9 @@ class DDPG:
             return (env_state, next_obs, next_perm, buffer), stats
 
         T = self.agent.episode_steps if num_steps is None else num_steps
-        (env_state, obs, _, buffer), stats = jax.lax.scan(
-            step_fn, (env_state, obs, perm0, buffer), jnp.arange(T))
+        with jax.named_scope("rollout_step"):   # what no inner layer claims
+            (env_state, obs, _, buffer), stats = jax.lax.scan(
+                step_fn, (env_state, obs, perm0, buffer), jnp.arange(T))
         episode_stats = {
             "episodic_return": stats["reward"].sum(),
             "mean_succ_ratio": stats["succ_ratio"].mean(),
@@ -349,26 +352,31 @@ class DDPG:
 
     def gradient_step_on_batch(self, state: DDPGState, batch
                                ) -> Tuple[DDPGState, Dict[str, jnp.ndarray]]:
-        (critic_loss, aux), cgrad = jax.value_and_grad(
-            self._critic_loss, has_aux=True)(state.critic_params, state, batch)
-        q_vals, td = aux if self.learn_ledger is not None else (aux, None)
-        cupd, critic_opt = self.opt.update(cgrad, state.critic_opt)
-        critic_params = optax.apply_updates(state.critic_params, cupd)
+        with jax.named_scope("critic_update"):
+            (critic_loss, aux), cgrad = jax.value_and_grad(
+                self._critic_loss, has_aux=True)(state.critic_params, state,
+                                                 batch)
+            q_vals, td = aux if self.learn_ledger is not None else (aux, None)
+            cupd, critic_opt = self.opt.update(cgrad, state.critic_opt)
+            critic_params = optax.apply_updates(state.critic_params, cupd)
 
-        actor_loss, agrad = jax.value_and_grad(self._actor_loss)(
-            state.actor_params, critic_params, batch)
-        aupd, actor_opt = self.opt.update(agrad, state.actor_opt)
-        actor_params = optax.apply_updates(state.actor_params, aupd)
+        with jax.named_scope("actor_update"):
+            actor_loss, agrad = jax.value_and_grad(self._actor_loss)(
+                state.actor_params, critic_params, batch)
+            aupd, actor_opt = self.opt.update(agrad, state.actor_opt)
+            actor_params = optax.apply_updates(state.actor_params, aupd)
 
         tau = self.agent.target_model_update
         polyak = lambda t, p: jax.tree_util.tree_map(
             lambda tl, pl: tau * pl + (1 - tau) * tl, t, p)
-        state = DDPGState(
-            actor_params=actor_params, critic_params=critic_params,
-            target_actor_params=polyak(state.target_actor_params, actor_params),
-            target_critic_params=polyak(state.target_critic_params,
-                                        critic_params),
-            actor_opt=actor_opt, critic_opt=critic_opt, rng=state.rng)
+        with jax.named_scope("target_update"):
+            state = DDPGState(
+                actor_params=actor_params, critic_params=critic_params,
+                target_actor_params=polyak(state.target_actor_params,
+                                           actor_params),
+                target_critic_params=polyak(state.target_critic_params,
+                                            critic_params),
+                actor_opt=actor_opt, critic_opt=critic_opt, rng=state.rng)
         # grad norms ride along for run telemetry (events.jsonl) — computed
         # from the already-materialized grads, so the update path is
         # untouched and pipeline/serial bit-identity holds
@@ -420,7 +428,8 @@ class DDPG:
             st, acc = carry
             if constrain is not None:
                 st = constrain(st)
-            batch = sample_fn(jax.random.fold_in(sub, i))
+            with jax.named_scope("replay_sample"):
+                batch = sample_fn(jax.random.fold_in(sub, i))
             st, metrics = self.gradient_step_on_batch(st, batch)
             if self.learn_ledger is not None:
                 # TD segments ACCUMULATE across the burst (per-topology
@@ -453,7 +462,9 @@ class DDPG:
                    else self.agent.learn_steps
                    if self.agent.learn_steps is not None
                    else self.agent.episode_steps)
-        state, metrics = jax.lax.fori_loop(0, n_steps, body, (state, zero))
+        with jax.named_scope("learn_burst"):   # the `while` carries the name
+            state, metrics = jax.lax.fori_loop(0, n_steps, body,
+                                               (state, zero))
         # divergence guardrail: flag the POST-update learner state in the
         # same device program (no extra host sync — the trainer reads it
         # from the deferred metric drain and rolls back on violation)

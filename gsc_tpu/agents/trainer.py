@@ -19,6 +19,7 @@ look-ahead cannot perturb them and exact resume is preserved.
 """
 from __future__ import annotations
 
+import contextlib
 import csv
 import logging
 import os
@@ -32,7 +33,7 @@ import numpy as np
 from ..config.schema import DROP_REASONS, AgentConfig
 from ..env.driver import EpisodeDriver
 from ..env.env import ServiceCoordEnv
-from ..obs.trace import episode_span, phase_span
+from ..obs.trace import emit_episode_spans, episode_span, phase_span
 from ..resilience.faults import FaultInjected
 from ..resilience.guard import RollbackGuard, poison_tree
 from ..resilience.retry import (RetryPolicy, TransientDispatchError,
@@ -242,45 +243,59 @@ class Trainer:
         # measures time blocked on device→host metric syncs, not host-side
         # bookkeeping — and the emitted event then carries phase totals
         # that include the drain just finished
-        if self.check_invariants:
-            # promoted from utils.debug: per drained episode, the final
-            # sim state is checked host-side and violations become
-            # structured events rather than a silently-returned list.
-            # (check_invariants is a module-level import — a per-episode
-            # lazy import here cost an import-system round-trip inside
-            # the drain path, flagged by gsc-lint's hot-loop review.)
-            errs = check_invariants(sim, topo, self.env.tables.chain_len)
-            if errs:
-                log.warning("episode=%d simulator invariants violated: %s",
-                            ep, "; ".join(errs))
-                if self.obs:
-                    # routed through the sentinel event pathway (counter +
-                    # structured event), same family as `compile` events
-                    self.obs.invariant_violation(ep, errs)
-        if self.obs:
-            row = self.history[-1]
-            # topology identity on the SERIAL path too: mixed batches get
-            # per-replica names through the harness, but a single-replica
-            # run's episodes must land in the same per-topology report
-            # tables — stamp the scheduled network's name on the event
-            # and gauge its return
-            extra = self._topology_extra(ep, row["episodic_return"])
-            self.obs.episode_end(
-                episode=ep, global_step=end_step,
-                metrics={k: v for k, v in row.items()
-                         if k not in ("episode", "sps")},
-                sps=sps, phases=timer.summary(),
-                drop_reasons=dict(zip(
-                    DROP_REASONS,
-                    np.asarray(sim.metrics.drop_reasons).tolist())),
-                truncated_arrivals=trunc, replay_bytes=replay_bytes,
-                extra=extra)
-            if self.learn_obs is not None and (signal is not None
-                                               or replay is not None):
-                # drained learning signal -> learn_signal event + gauges
-                # (values synced above; nothing here waits on the device)
-                self.learn_obs.episode(ep, signal=signal, replay=replay)
+        with phase_span("episode_log", timer, hub):
+            if self.check_invariants:
+                # promoted from utils.debug: per drained episode, the final
+                # sim state is checked host-side and violations become
+                # structured events rather than a silently-returned list.
+                # (check_invariants is a module-level import — a per-episode
+                # lazy import here cost an import-system round-trip inside
+                # the drain path, flagged by gsc-lint's hot-loop review.)
+                errs = check_invariants(sim, topo, self.env.tables.chain_len)
+                if errs:
+                    log.warning("episode=%d simulator invariants violated: %s",
+                                ep, "; ".join(errs))
+                    if self.obs:
+                        # routed through the sentinel event pathway (counter +
+                        # structured event), same family as `compile` events
+                        self.obs.invariant_violation(ep, errs)
+            if self.obs:
+                row = self.history[-1]
+                # topology identity on the SERIAL path too: mixed batches get
+                # per-replica names through the harness, but a single-replica
+                # run's episodes must land in the same per-topology report
+                # tables — stamp the scheduled network's name on the event
+                # and gauge its return
+                extra = self._topology_extra(ep, row["episodic_return"])
+                self.obs.episode_end(
+                    episode=ep, global_step=end_step,
+                    metrics={k: v for k, v in row.items()
+                             if k not in ("episode", "sps")},
+                    sps=sps, phases=timer.summary(),
+                    drop_reasons=dict(zip(
+                        DROP_REASONS,
+                        np.asarray(sim.metrics.drop_reasons).tolist())),
+                    truncated_arrivals=trunc, replay_bytes=replay_bytes,
+                    extra=extra)
+                if self.learn_obs is not None and (signal is not None
+                                                   or replay is not None):
+                    # drained learning signal -> learn_signal event + gauges
+                    # (values synced above; nothing here waits on the device)
+                    self.learn_obs.episode(ep, signal=signal, replay=replay)
         return finite
+
+    @staticmethod
+    def _next_episode_span(root, timer, hub, ep: int, preempt) -> bool:
+        """Top of a loop iteration: close the open root ``episode`` span
+        (``root``, an ``ExitStack``) and open episode ``ep``'s — the root
+        closes where the next opens, so everything an iteration does lies
+        under it and what no child covers is its self time — then read
+        the stop test under ``preempt_check``.  Returns whether to stop."""
+        root.close()
+        timer.episode = ep
+        root.enter_context(phase_span("episode", timer, hub))
+        with phase_span("preempt_check", timer, hub):
+            return preempt is not None and preempt.triggered
 
     # ---------------------------------------------------------- resilience
     def _recover(self, episode: int, site: str, action: str,
@@ -344,8 +359,9 @@ class Trainer:
         perf = getattr(self.obs, "perf", None) if self.obs else None
         if perf is None:
             return
-        for name, (fn, args, kwargs) in names_args.items():
-            perf.capture(name, fn, args, kwargs)
+        with phase_span("cost_capture", self.phase_timer, self.obs.hub):
+            for name, (fn, args, kwargs) in names_args.items():
+                perf.capture(name, fn, args, kwargs)
 
     def _note_cost_timings(self, timer, primary: Optional[str]):
         """Merge the run's measured host wall into the ledger AFTER the
@@ -590,6 +606,7 @@ class Trainer:
             # compile/eval/checkpoint time is not a pipeline stall
             self.obs.resume_watchdog()
 
+        root = contextlib.ExitStack()   # the open root `episode` span
         pending = []  # dispatched episodes whose metrics are not yet synced
         # serial path drains immediately (the seed behavior); pipelined
         # drains lag one episode so the sync never gates the next dispatch
@@ -704,8 +721,9 @@ class Trainer:
                             # checkpoint must contain (the live carries
                             # may already be an episode ahead)
                             _, g_state, g_buffer = guard.last_good
-                            ckpt_manager.save(g_state, g_buffer,
-                                              episode=k + 1)
+                            with phase_span("ckpt", timer, hub):
+                                ckpt_manager.save(g_state, g_buffer,
+                                                  episode=k + 1)
                     if (publisher is not None and publish_interval
                             and (k + 1 - start_episode)
                             % publish_interval == 0):
@@ -734,9 +752,10 @@ class Trainer:
                             # or the live params whose flag just drained
                             # finite) — skip the publisher's own host
                             # scan
-                            publisher.publish(jax.device_get(src),
-                                              meta={"episode": k + 1},
-                                              verified=True)
+                            with phase_span("publish", timer, hub):
+                                publisher.publish(jax.device_get(src),
+                                                  meta={"episode": k + 1},
+                                                  verified=True)
                     return
                 if guard is None:
                     self._recover(
@@ -759,7 +778,9 @@ class Trainer:
                               if dropped else ""))
 
             for ep in range(start_episode, episodes):
-                if preempt is not None and preempt.triggered:
+                # under the pipeline a drain carries the iteration it ran
+                # in, one past the episode it drains
+                if self._next_episode_span(root, timer, hub, ep, preempt):
                     self.preempted = True
                     self._recover(
                         ep, site="run", action="preempt_snapshot",
@@ -768,6 +789,7 @@ class Trainer:
                                "episodes drain, then the caller "
                                "checkpoints")
                     break
+                emit_episode_spans(hub, timer)
                 if ep > start_episode:
                     topo, traffic = next_episode(ep)
                     env_state, obs = self.env.reset(
@@ -817,6 +839,8 @@ class Trainer:
                 # raise like the serial loop would, not be downgraded
                 drain_one()
         finally:
+            root.close()
+            emit_episode_spans(hub, timer)
             if self.obs:
                 # disarm BEFORE the best-effort teardown drains — a fault
                 # recovery path must not also spray stall events
@@ -1101,7 +1125,7 @@ class Trainer:
             if curr is not None:
                 _curriculum_hook(i, ret, succ, metrics)
 
-        start = time.time()
+        root = contextlib.ExitStack()   # the open root `episode` span
         try:
             # the scheduler may swap topologies mid-run, so drive the
             # harness one episode at a time with that episode's topology —
@@ -1109,7 +1133,8 @@ class Trainer:
             # sees one continuous run (and a resumed run continues it
             # exactly)
             for ep in range(start_episode, episodes):
-                if preempt is not None and preempt.triggered:
+                ep_t0 = time.perf_counter()
+                if self._next_episode_span(root, timer, hub, ep, preempt):
                     self.preempted = True
                     self._recover(
                         ep, site="run", action="preempt_snapshot",
@@ -1117,6 +1142,7 @@ class Trainer:
                         detail=f"stopping before episode {ep}; the caller "
                                "checkpoints the drained state")
                     break
+                emit_episode_spans(hub, timer)
                 # the scenario_regen phase measures what producing this
                 # episode's (topology, traffic) costs the HOST: the full
                 # Python regen wall on host-traffic paths, dispatch-
@@ -1268,88 +1294,97 @@ class Trainer:
                             detail="rollback disabled (Trainer(rollback="
                                    "False)) — continuing with the "
                                    "poisoned state")
-                sps = ((ep - start_episode + 1) * steps_per_ep
-                       * num_replicas / (time.time() - start))
-                row = {"episodic_return": rets[0],
-                       "mean_succ_ratio": succ[0],
-                       "final_succ_ratio": final[0], **learn_row,
-                       "episode": ep, "sps": sps}
-                self.history.append(row)
-                self.rewards_writer.write(rets[0])
-                if self.tb:
-                    gs = (ep + 1) * steps_per_ep
-                    self.tb.add_scalar("charts/episodic_return", rets[0], gs)
-                    self.tb.add_scalar("charts/SPS", sps, gs)
-                if verbose:
-                    log.info("episode=%d return=%.3f succ=%.3f sps=%.1f",
-                             ep, rets[0], succ[0], sps)
-                if self.obs:
-                    extra = {"replicas": num_replicas}
-                    if mix_plan is None and factory is None:
-                        # homogeneous replica batches: one network per
-                        # episode — same stamp as the serial drain (the
-                        # harness's per-replica names cover mixes;
-                        # factory episodes attribute per FAMILY through
-                        # the learn ledger's topo_id segments, not a
-                        # schedule name)
-                        extra = self._topology_extra(ep, rets[0],
-                                                     extra=extra)
-                    self.obs.episode_end(
-                        episode=ep, global_step=(ep + 1) * steps_per_ep - 1,
-                        metrics={k: v for k, v in row.items()
-                                 if k not in ("episode", "sps")},
-                        sps=sps, phases=timer.summary(),
-                        replay_bytes=buffer_nbytes(buffers),
-                        extra=extra)
+                with phase_span("episode_log", timer, hub):
+                    # this episode's steps over its root span so far (the
+                    # publish and checkpoint cadences follow the row)
+                    sps = (steps_per_ep * num_replicas
+                           / (time.perf_counter() - ep_t0))
+                    row = {"episodic_return": rets[0],
+                           "mean_succ_ratio": succ[0],
+                           "final_succ_ratio": final[0], **learn_row,
+                           "episode": ep, "sps": sps}
+                    self.history.append(row)
+                    self.rewards_writer.write(rets[0])
+                    if self.tb:
+                        gs = (ep + 1) * steps_per_ep
+                        self.tb.add_scalar("charts/episodic_return",
+                                           rets[0], gs)
+                        self.tb.add_scalar("charts/SPS", sps, gs)
+                    if verbose:
+                        log.info("episode=%d return=%.3f succ=%.3f sps=%.1f",
+                                 ep, rets[0], succ[0], sps)
+                    if self.obs:
+                        extra = {"replicas": num_replicas}
+                        if mix_plan is None and factory is None:
+                            # homogeneous replica batches: one network per
+                            # episode — same stamp as the serial drain (the
+                            # harness's per-replica names cover mixes;
+                            # factory episodes attribute per FAMILY through
+                            # the learn ledger's topo_id segments, not a
+                            # schedule name)
+                            extra = self._topology_extra(ep, rets[0],
+                                                         extra=extra)
+                        self.obs.episode_end(
+                            episode=ep,
+                            global_step=(ep + 1) * steps_per_ep - 1,
+                            metrics={k: v for k, v in row.items()
+                                     if k not in ("episode", "sps")},
+                            sps=sps, phases=timer.summary(),
+                            replay_bytes=buffer_nbytes(buffers),
+                            extra=extra)
                 self._last_drained = ep
                 if (publisher is not None and publish_interval
                         and (ep + 1 - start_episode) % publish_interval
                         == 0):
-                    # hot-swap publish from the replica path (ROADMAP
-                    # item 3's last leftover): only the ACTOR subtree
-                    # ships, so gather exactly that — device_get
-                    # assembles sharded leaves to host arrays (the same
-                    # per-leaf move the plan's gather fns perform;
-                    # pulling the whole state would move ~5x the bytes,
-                    # and critic/targets/moments never serve).  With no
-                    # rollback guard here, finite-verify before
-                    # anything reaches the fleet.  Host gather at
-                    # publish cadence only, never per episode.
-                    params = jax.device_get(state.actor_params)
-                    if self._finite_host(params):
-                        publisher.publish(params, meta={"episode": ep + 1},
-                                          verified=True)
-                    else:
-                        self._recover(
-                            ep, site="learner_state", action="detected",
-                            fault="non_finite_state",
-                            detail="replica path has no rollback guard — "
-                                   "hot-swap publish skipped so a "
-                                   "poisoned state never reaches the "
-                                   "serving fleet")
+                    with phase_span("publish", timer, hub):
+                        # hot-swap publish from the replica path (ROADMAP
+                        # item 3's last leftover): only the ACTOR subtree
+                        # ships, so gather exactly that — device_get
+                        # assembles sharded leaves to host arrays (the same
+                        # per-leaf move the plan's gather fns perform;
+                        # pulling the whole state would move ~5x the bytes,
+                        # and critic/targets/moments never serve).  With no
+                        # rollback guard here, finite-verify before
+                        # anything reaches the fleet.  Host gather at
+                        # publish cadence only, never per episode.
+                        params = jax.device_get(state.actor_params)
+                        if self._finite_host(params):
+                            publisher.publish(params, meta={"episode": ep + 1},
+                                              verified=True)
+                        else:
+                            self._recover(
+                                ep, site="learner_state", action="detected",
+                                fault="non_finite_state",
+                                detail="replica path has no rollback guard "
+                                       "— hot-swap publish skipped so a "
+                                       "poisoned state never reaches the "
+                                       "serving fleet")
                 if (ckpt_manager is not None and ckpt_interval
                         and (ep + 1 - start_episode) % ckpt_interval == 0):
-                    # the replica harness drains synchronously, so the
-                    # live carries ARE the state after episode ep — but
-                    # with no rollback guard on this path the state must
-                    # be verified HERE, or a NaN-poisoned run would
-                    # checksum garbage into the last-good resume target.
-                    # One host-side scan at checkpoint cadence (the save
-                    # needs these leaves on host anyway — under a plan
-                    # the gather IS the mesh-agnostic checkpoint layout).
-                    h_state, h_buffers = to_host(state, buffers)
-                    if self._finite_host(h_state):
-                        ckpt_manager.save(h_state, h_buffers,
-                                          episode=ep + 1)
-                    else:
-                        self._recover(
-                            ep, site="learner_state", action="detected",
-                            fault="non_finite_state",
-                            detail="replica path has no rollback guard — "
-                                   "checkpoint skipped so the last-good "
-                                   "pointer keeps the previous verified "
-                                   "state")
+                    with phase_span("ckpt", timer, hub):
+                        # the replica harness drains synchronously, so the
+                        # live carries ARE the state after episode ep — but
+                        # with no rollback guard on this path the state must
+                        # be verified HERE, or a NaN-poisoned run would
+                        # checksum garbage into the last-good resume target.
+                        # One host-side scan at checkpoint cadence (the save
+                        # needs these leaves on host anyway — under a plan
+                        # the gather IS the mesh-agnostic checkpoint layout).
+                        h_state, h_buffers = to_host(state, buffers)
+                        if self._finite_host(h_state):
+                            ckpt_manager.save(h_state, h_buffers,
+                                              episode=ep + 1)
+                        else:
+                            self._recover(
+                                ep, site="learner_state", action="detected",
+                                fault="non_finite_state",
+                                detail="replica path has no rollback guard "
+                                       "— checkpoint skipped so the "
+                                       "last-good pointer keeps the previous "
+                                       "verified state")
         finally:
+            root.close()
+            emit_episode_spans(hub, timer)
             if self.obs:
                 self.obs.pause_watchdog()
         self.completed_episodes = self._last_drained + 1

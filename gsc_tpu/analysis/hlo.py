@@ -21,10 +21,12 @@ argument is an already-compiled jax ``Compiled`` object (or its
 """
 from __future__ import annotations
 
+import collections
 import re
+from typing import List, NamedTuple
 
 __all__ = ["collective_stats", "count_fusions", "count_ops", "hlo_text",
-           "op_histogram"]
+           "op_histogram", "op_scopes", "scope_stats"]
 
 
 def hlo_text(compiled_or_text) -> str:
@@ -144,3 +146,212 @@ def collective_stats(compiled_or_text, ops=COLLECTIVE_OPS) -> dict:
     return {"ops": present,
             "count": sum(r["count"] for r in present.values()),
             "bytes": sum(r["bytes"] for r in present.values())}
+
+
+# ------------------------------------------------------- operations by scope
+#: instructions that are no device operation of their own: values the
+#: compiler threads through, and the control flow whose bodies are walked
+_NOT_AN_OP = frozenset(("parameter", "constant", "tuple",
+                        "get-tuple-element", "bitcast",
+                        "while", "call", "conditional"))
+_COPY_OPS = ("copy", "copy-start")
+# `[ENTRY ]%name (params) -> type {`
+_COMPUTATION_RE = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s*\(.*->.*\{\s*$")
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+# computations an instruction RUNS (a fusion's `calls=` and a reducer's
+# `to_apply=` are part of their instruction, not operations of their own)
+_RUNS_RE = re.compile(r"(?:body|condition|true_computation|"
+                      r"false_computation)=%?([\w.\-]+)")
+_BRANCHES_RE = re.compile(r"branch_computations=\{([^}]*)\}")
+_TO_APPLY_RE = re.compile(r"to_apply=%?([\w.\-]+)")
+_WORD_RE = re.compile(r"[A-Za-z_][A-Za-z0-9_]*")
+# `jit(name)` on a path is a function's name, never a scope
+_JIT_NAME_RE = re.compile(r"p?jit\([^()]*\)")
+_INSTR_NAME_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = ")
+_OPERAND_RE = re.compile(r"%([\w.\-]+)")
+
+
+class _Instr(NamedTuple):
+    name: str
+    opcode: str
+    type_text: str
+    path: List[str]        # scope names on its own op_name, outermost first
+    operands: List[str]
+    named: bool            # carries an op_name at all
+    runs: List[str]        # computations a control-flow instruction runs
+
+
+def _split_instruction(line: str):
+    """``(result type text, opcode, rest)`` of an HLO instruction line, or
+    None.  The result type is one token, or a parenthesised tuple."""
+    _, eq, rhs = line.partition(" = ")
+    if not eq:
+        return None
+    if rhs.startswith("("):
+        depth = 0
+        for i, ch in enumerate(rhs):
+            depth += (ch == "(") - (ch == ")")
+            if depth == 0:
+                break
+        type_text, rest = rhs[:i + 1], rhs[i + 1:].lstrip()
+    else:
+        type_text, _, rest = rhs.partition(" ")
+    opcode, paren, rest = rest.partition("(")
+    if not paren or not re.fullmatch(r"[a-z][a-z0-9\-]*", opcode):
+        return None
+    return type_text, opcode, rest
+
+
+def _walk_ops(text: str, scopes):
+    """Every device operation of an HLO module text as ``(instruction
+    name, opcode, result type text, scope path, own)``: ``path`` the
+    names of ``scopes`` the operation lies under, outermost first, and
+    ``own`` whether its own ``op_name`` gave them (else inherited)."""
+    known = frozenset(scopes)
+    instructions = {}          # computation -> [_Instr]
+    entry = current = None
+    for line in text.splitlines():
+        head = _COMPUTATION_RE.match(line)
+        if head:
+            current = head.group(2)
+            instructions[current] = []
+            if head.group(1):
+                entry = current
+            continue
+        parts = _split_instruction(line) if current else None
+        if parts is None:
+            continue
+        type_text, opcode, rest = parts
+        found = _OP_NAME_RE.search(rest)
+        path = [w for part in (found.group(1) if found else "").split("/")
+                for w in _WORD_RE.findall(_JIT_NAME_RE.sub("", part))
+                if w in known]
+        runs = []
+        if opcode in ("while", "conditional"):
+            runs = _RUNS_RE.findall(rest)
+            for group in _BRANCHES_RE.findall(rest):
+                runs += [b.strip().lstrip("%")
+                         for b in group.split(",") if b.strip()]
+        elif opcode == "call":
+            runs = _TO_APPLY_RE.findall(rest)
+        instructions[current].append(_Instr(
+            _INSTR_NAME_RE.match(line).group(1), opcode, type_text, path,
+            _OPERAND_RE.findall(rest[:rest.find(")") + 1]), bool(found),
+            runs))
+    seen, todo = set(), [(entry, [])] if entry else []
+    while todo:
+        comp, caller_path = todo.pop()
+        if comp in seen or comp not in instructions:
+            continue
+        seen.add(comp)
+        body = instructions[comp]
+        inherited = _inherit_paths(body)
+        # what is left without a path: the path more than half of the
+        # body's scoped instructions share (a loop body written under one
+        # scope), else that of the instruction that runs the body
+        own_paths = collections.Counter(tuple(i.path) for i in body
+                                        if i.path)
+        common, n = (own_paths.most_common(1) or [((), 0)])[0]
+        rest = list(common) if 2 * n > sum(own_paths.values()) \
+            else caller_path
+        for i in body:
+            path = i.path or inherited.get(i.name) \
+                or ([] if i.named else rest)
+            todo += [(c, path) for c in i.runs]
+            if i.opcode not in _NOT_AN_OP:
+                yield i.name, i.opcode, i.type_text, path, bool(i.path)
+
+
+def scope_stats(compiled_or_text, scopes) -> dict:
+    """Device operations of a compiled program by ``jax.named_scope``.
+
+    Walks the post-optimisation HLO text once.  An *operation* is an
+    instruction of a computation the device steps through — the entry,
+    ``while`` bodies and conditions, ``call`` and ``conditional`` targets —
+    and not one inside a fused computation or a reducer; ``parameter``,
+    ``constant``, ``tuple``, ``get-tuple-element``, ``bitcast`` and the
+    control-flow instructions themselves are not operations.  Each is
+    counted once per text occurrence (a scan body once, not once per
+    iteration), the convention of :func:`count_fusions`.
+
+    An operation's scope is the innermost name of ``scopes`` on its
+    ``op_name`` path (a fusion carries its root's path; autodiff wraps a
+    name as ``transpose(jvp(name))`` and it still counts; ``jit(name)`` is
+    a function's name and does not).  What the compiler inserts carries
+    no path at all — layout copies, the ``copy-start``/``copy-done`` and
+    ``slice-start``/``slice-done`` pairs of its prefetches, buffer
+    allocations: such an instruction is put down to the scoped
+    instruction it feeds, else to the one that feeds it, within its
+    computation (through tuples, as a loop's operands are), else to the
+    path that more than half of its computation's scoped instructions
+    share (a loop body written under one scope), else to the control-flow
+    instruction that runs its computation (a loop the compiler itself
+    made of a scatter or a sort), and counted under ``inherited`` as
+    well.  Whatever is left is ``unscoped``.
+
+    Returns ``{scope: {"ops", "fusions", "copies", "out_bytes",
+    "inherited", "ops_incl"}}`` for every scope and ``unscoped``:
+    ``copies`` are ``copy``/``copy-start``, ``out_bytes`` the result
+    bytes, and ``ops_incl`` the operations whose path passes through the
+    scope at any depth, so a layer can be read with what is nested in
+    it."""
+    scopes = tuple(scopes)
+    out = {name: {"ops": 0, "fusions": 0, "copies": 0, "out_bytes": 0,
+                  "inherited": 0, "ops_incl": 0}
+           for name in scopes + ("unscoped",)}
+    for _, opcode, type_text, path, own in _walk_ops(
+            hlo_text(compiled_or_text), scopes):
+        rec = out[path[-1] if path else "unscoped"]
+        rec["ops"] += 1
+        rec["fusions"] += opcode == "fusion"
+        rec["copies"] += opcode in _COPY_OPS
+        rec["out_bytes"] += _shape_bytes(type_text)
+        rec["inherited"] += bool(path) and not own
+        for scope in set(path) or ("unscoped",):
+            out[scope]["ops_incl"] += 1
+    return out
+
+
+def op_scopes(compiled_or_text, scopes) -> dict:
+    """``{instruction name: innermost scope}`` (``unscoped`` where none)
+    for every device operation, by :func:`scope_stats`'s rules: the join
+    from a device trace's events, which carry the instruction's name, to
+    the program's layers."""
+    return {name: (path[-1] if path else "unscoped")
+            for name, _, _, path, _ in _walk_ops(
+                hlo_text(compiled_or_text), tuple(scopes))}
+
+
+def _inherit_paths(body) -> dict:
+    """``{instruction: scope path}`` for the instructions of one
+    computation that carry no ``op_name`` of their own and feed one that
+    has a scope — directly or through others like them, so the chain
+    ``copy-start`` -> ``copy-done`` -> fusion settles in a few passes —
+    or else are fed by one whose scope is its own."""
+    paths = {i.name: i.path for i in body if i.path}
+    # a tuple gathers values of many scopes (a loop body's root): it may
+    # pass its user's path on to what feeds it, never take an operand's
+    bare = [(i.name, [] if i.opcode == "tuple" else i.operands)
+            for i in body if not i.named and i.opcode != "parameter"]
+    if not bare or not paths:
+        return {}
+    users = {}
+    for i in body:
+        for arg in i.operands:
+            users.setdefault(arg, []).append(i.name)
+    got = {}
+    for _ in range(8):
+        changed = False
+        for name, args in bare:
+            if name in got:
+                continue
+            path = next((paths.get(u) or got.get(u)
+                         for u in users.get(name, [])
+                         if u in paths or u in got), None) \
+                or next((paths[a] for a in args if a in paths), None)
+            if path:
+                got[name] = path
+                changed = True
+        if not changed:
+            break
+    return got

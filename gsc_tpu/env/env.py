@@ -128,10 +128,11 @@ class ServiceCoordEnv:
             active_ing, self.limits.sf_pool)
         sim, metrics = self.engine.apply(state.sim, topo, traffic, schedule,
                                          placement)
-        reward, ewma, info = compute_reward(
-            self.agent, metrics, placement, topo.node_mask,
-            self.limits.sf_pool, self.min_delay, self.diameter,
-            state.ewma_flows)
+        with jax.named_scope("env_observe"):
+            reward, ewma, info = compute_reward(
+                self.agent, metrics, placement, topo.node_mask,
+                self.limits.sf_pool, self.min_delay, self.diameter,
+                state.ewma_flows)
         step = state.step + 1
         done = step >= self.agent.episode_steps
         info["run_generated"] = metrics.run_generated
@@ -141,4 +142,6 @@ class ServiceCoordEnv:
         info["placement"] = placement
         info["schedule"] = schedule
         state = EnvState(sim=sim, step=step, ewma_flows=ewma)
-        return state, self._obs(sim, topo, traffic), reward, done, info
+        with jax.named_scope("env_observe"):
+            next_obs = self._obs(sim, topo, traffic)
+        return state, next_obs, reward, done, info

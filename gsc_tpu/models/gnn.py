@@ -57,9 +57,10 @@ class GATv2Conv(nn.Module):
             return fn(x, edge_index, edge_mask, node_mask)
         if self.impl == "pallas":
             from ..ops.pallas_gat import gatv2_pallas
-            xl = project(x, w_l, b_l, cd)
-            xr = project(x, w_r, b_r, cd)
-            return gatv2_pallas(xl, xr, att, bias, adj, self.mean_aggr)
+            with jax.named_scope("gat_layer"):
+                xl = project(x, w_l, b_l, cd)
+                xr = project(x, w_r, b_r, cd)
+                return gatv2_pallas(xl, xr, att, bias, adj, self.mean_aggr)
         return gatv2_dense(x, adj, w_l, b_l, w_r, b_r, att, bias,
                            self.mean_aggr, compute_dtype=cd)
 

@@ -12,7 +12,9 @@ makes that evidence a per-run artifact: every watched jitted entry point
   accessed per call;
 - HLO structure (:mod:`gsc_tpu.analysis.hlo`): fusion count — the
   op-count perf proxy the megakernel campaign gates on — plus a small
-  op histogram (while/dot/scatter/gather) and the collective-op stats
+  op histogram (while/dot/scatter/gather), the device operations per
+  ``jax.named_scope`` layer (``scopes``: ops, fusions, copies, result
+  bytes for each of ``obs.trace.DEVICE_SCOPES``) and the collective-op stats
   (all-reduce/all-gather/reduce-scatter count + payload bytes) that
   make the ``tp``-vs-``sharded`` interconnect comparison machine-read
   (on a sharded dispatch the trainer additionally captures the
@@ -50,7 +52,9 @@ import logging
 import time
 from typing import Dict, Optional
 
-from ..analysis.hlo import collective_stats, count_fusions, op_histogram
+from ..analysis.hlo import (collective_stats, count_fusions, op_histogram,
+                            scope_stats)
+from .trace import DEVICE_SCOPES
 
 log = logging.getLogger("gsc_tpu.obs.perf")
 
@@ -115,6 +119,25 @@ def resolve_lowerable(owner, name: str):
     return getattr(type(owner), name), (owner,)
 
 
+# The persistent compile cache keys a program on its operations alone, so
+# a hit hands back the executable with the ``op_name`` metadata of
+# whichever source compiled it first.  Compiling the same lowering under a
+# compiler option that changes nothing but the cache key (what the
+# compiler logs) gives an executable of its own: the names of THIS source
+# the first time, a cache hit after that.
+OWN_CACHE_KEY = {"xla_detailed_logging": False}
+
+
+def _mine_scopes(compiled):
+    """``(hlo text, operations by named scope)`` of a compiled program;
+    ``("", {})`` on a backend without HLO text access."""
+    try:
+        hlo = compiled.as_text()
+    except Exception:
+        return "", {}
+    return hlo, (scope_stats(hlo, DEVICE_SCOPES) if hlo else {})
+
+
 def _cost_dict(compiled) -> Dict[str, float]:
     """``compiled.cost_analysis()`` as a plain ``{metric: value}`` dict."""
     return dict(compiled.cost_analysis() or {})
@@ -176,8 +199,19 @@ class CostLedger:
         t0 = time.perf_counter()
         try:
             fn, args, kwargs = _unwrap_partial(fn, args, kwargs)
-            compiled = fn.lower(*args, **kwargs).compile()
-            entry = self.capture_compiled(name, compiled)
+            lowered = fn.lower(*args, **kwargs)
+            compiled = lowered.compile()
+            hlo, scopes = _mine_scopes(compiled)
+            if scopes and not any(rec["ops"] for scope, rec in scopes.items()
+                                  if scope != "unscoped"):
+                # no scope name at all: a cache hit compiled from a source
+                # that had none
+                log.info("cost-ledger capture of %r: the cached executable "
+                         "carries no scope names, compiling the lowering "
+                         "under a cache key of its own", name)
+                compiled = lowered.compile(compiler_options=OWN_CACHE_KEY)
+                hlo, scopes = _mine_scopes(compiled)
+            entry = self.capture_compiled(name, compiled, hlo, scopes)
             entry["capture_s"] = round(time.perf_counter() - t0, 3)
             return entry
         except Exception as e:  # noqa: BLE001 - observability must not kill
@@ -187,15 +221,14 @@ class CostLedger:
                                    "error": f"{type(e).__name__}: {e}"}
             return self._entries[name]
 
-    def capture_compiled(self, name: str, compiled) -> Dict:
+    def capture_compiled(self, name: str, compiled, hlo=None,
+                         scopes=None) -> Dict:
         """Record an already-compiled ``jax.stages.Compiled`` (the serve
-        path holds one per bucket after warmup)."""
+        path holds one per bucket after warmup).  ``hlo``/``scopes`` are
+        :func:`_mine_scopes`'s result where the caller already has it."""
         cost = _cost_dict(compiled)
-        hlo = ""
-        try:
-            hlo = compiled.as_text()
-        except Exception:   # backends without HLO text access
-            pass
+        if hlo is None:
+            hlo, scopes = _mine_scopes(compiled)
         entry: Dict = {
             "available": True,
             "flops": float(cost.get("flops", 0.0)),
@@ -208,6 +241,9 @@ class CostLedger:
             # machine-read side of the tp-vs-sharded interconnect claim
             "collectives": (collective_stats(hlo) if hlo
                             else {"ops": {}, "count": 0, "bytes": 0}),
+            # device operations by named scope (obs.trace.DEVICE_SCOPES
+            # plus `unscoped`): the program's op count put down to layers
+            "scopes": scopes,
         }
         if entry["flops"] and entry["bytes_accessed"]:
             entry["arithmetic_intensity"] = round(
@@ -228,7 +264,8 @@ class CostLedger:
                            bytes_accessed=entry["bytes_accessed"],
                            fusions=entry["fusions"],
                            ops=entry["ops"],
-                           collectives=entry["collectives"])
+                           collectives=entry["collectives"],
+                           scopes=entry["scopes"])
             if entry["fusions"] is not None:
                 self.hub.gauge("compile_fusions", entry["fusions"], fn=name)
         return entry

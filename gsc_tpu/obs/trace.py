@@ -9,8 +9,14 @@ device time to pipeline phases.  :func:`phase_span` wraps the host-side
 phases in ``jax.profiler.TraceAnnotation`` and :func:`episode_span` marks
 each episode dispatch with ``jax.profiler.StepTraceAnnotation``.
 Annotation names are stable API — tooling and docs reference them:
-``host_sample``, ``host_sample_wait``, ``dispatch``, ``drain`` (phase
-ranges) and ``episode_step`` (the per-episode step marker).
+:data:`SPAN_NAMES` (the phase ranges: the root ``episode`` and its
+children) and ``episode_step`` (the per-episode step marker).  With a
+``PhaseTimer`` every span is also kept with its start, duration, parent
+and episode, and the episode loops emit them as one ``episode_spans``
+event per episode.  The device program's layers carry
+``jax.named_scope`` names from :data:`DEVICE_SCOPES`; they reach the
+compiled HLO as ``op_name`` metadata, where
+:func:`gsc_tpu.analysis.hlo.scope_stats` counts operations by them.
 
 **Post-hoc export** — a run's ``events.jsonl`` already carries everything
 a timeline needs (episode boundaries, cumulative PhaseTimer totals,
@@ -33,10 +39,11 @@ put->pop residency), and a learner track (``replay_ingest`` /
 ``learn_burst`` slices, ``publish`` marks) — with put->pop flow arrows
 carrying block size + staleness wait and publish->adopt arrows linking
 every weight version to each actor that adopted it.
-Phase sub-spans are RECONSTRUCTED from the
-cumulative per-episode deltas (laid back-to-back inside each episode's
-span and clamped to it), so they show relative share faithfully but not
-exact start times.  :func:`validate_trace` is the strict schema check
+Phase sub-spans sit at their recorded start times where the stream holds
+``episode_spans`` events; a stream without them (older runs) gets them
+RECONSTRUCTED from the cumulative per-episode deltas, laid back-to-back
+inside each episode's span and clamped to it.
+:func:`validate_trace` is the strict schema check
 (monotone ts per track, matched B/E pairs, pid/tid present) that CI and
 the exporter gate on; ``tools/trace_export.py`` is the CLI.
 
@@ -47,29 +54,71 @@ from __future__ import annotations
 
 import json
 import os
+import threading
 from contextlib import contextmanager
 from typing import Dict, List, Optional
+
+# Every ``phase_span`` name in the package, stable API.  ``episode`` is the
+# root an episode loop opens at the top of each iteration; the rest are
+# its children, in the order the replica loop runs them, then the
+# serial/pipelined loop's own two.
+SPAN_NAMES = (
+    "episode", "preempt_check", "scenario_regen", "cost_capture",
+    "reset_enqueue", "dispatch", "drain", "harness_observe", "episode_log",
+    "publish", "ckpt", "host_sample", "host_sample_wait")
+
+# Every ``jax.named_scope`` name in the package, stable API: the layer
+# boundaries of the device program.  The innermost of these on an HLO
+# instruction's ``op_name`` path is its scope (``analysis.hlo.scope_stats``).
+DEVICE_SCOPES = (
+    "rollout_step", "sim_substep", "traffic_arrivals", "policy_forward",
+    "env_observe", "replay_write", "learn_burst", "replay_sample",
+    "critic_update", "actor_update", "target_update", "gat_layer",
+    "finite_guard")
+
+class _OpenSpans(threading.local):
+    """Per-thread stack of the open ``phase_span`` names."""
+
+    def __init__(self):
+        self.stack = []
+
+
+_open_spans = _OpenSpans()
 
 
 @contextmanager
 def phase_span(name: str, timer=None, hub=None):
     """One pipeline phase: profiler range + optional
-    :class:`~gsc_tpu.utils.telemetry.PhaseTimer` accumulation + hub
-    last-phase bookkeeping (what a stall event reports being stuck in)."""
+    :class:`~gsc_tpu.utils.telemetry.PhaseTimer` span (totals, and the
+    span itself with the enclosing ``phase_span`` of this thread as its
+    parent) + hub last-phase bookkeeping (what a stall event reports
+    being stuck in)."""
     import jax
 
+    stack = _open_spans.stack
+    parent = stack[-1] if stack else None
     if hub is not None:
         hub.note_phase(name, done=False)
+    stack.append(name)
     with jax.profiler.TraceAnnotation(name):
         try:
             if timer is not None:
-                with timer.phase(name):
+                with timer.span(name, parent):
                     yield
             else:
                 yield
         finally:
+            stack.pop()
             if hub is not None:
                 hub.note_phase(name, done=True)
+
+
+def emit_episode_spans(hub, timer) -> None:
+    """One ``episode_spans`` event with every span the timer closed since
+    the last emission; nothing without a hub or without spans."""
+    spans = timer.take_spans()
+    if hub is not None and spans:
+        hub.event("episode_spans", spans=spans)
 
 
 @contextmanager
@@ -176,8 +225,12 @@ def _us(ts: float, t0: float) -> float:
 def build_trace(events: List[Dict]) -> Dict:
     """Chrome trace-event JSON from an obs event stream.
 
-    Episode slices sit back-to-back on the episode track (each ends at
-    its event's wall ts); phase sub-spans are reconstructed from the
+    Where a run's stream holds ``episode_spans`` events, every recorded
+    span is one complete slice at its own start time and duration (the
+    root ``episode`` span is the episode's slice, its children nest
+    inside it by containment).  A run without them keeps the older
+    layout: episode slices back-to-back on the episode track (each ends
+    at its event's wall ts), phase sub-spans reconstructed from the
     per-episode deltas of the cumulative PhaseTimer totals, laid
     sequentially inside the episode slice and scaled down if they would
     overflow it — faithful shares, synthetic start times.  Stalls /
@@ -212,6 +265,8 @@ def build_trace(events: List[Dict]) -> Dict:
         elif k == "async_learner_spans":
             for field in ("ingests", "bursts", "publishes"):
                 t_min.extend(float(r[0]) for r in (e.get(field) or []))
+        elif k == "episode_spans":
+            t_min.extend(float(sp["t0"]) for sp in (e.get("spans") or []))
     t0 = min(t_min)
     run = next((e.get("run") for e in events if e.get("run")), "run")
     out: List[Dict] = []
@@ -257,6 +312,14 @@ def build_trace(events: List[Dict]) -> Dict:
         if e.get("event") == "run_start":
             seg += 1
         seg_of[id(e)] = seg
+    # runs whose phases are recorded spans, and what their ``episode``
+    # events say (the root span's slice carries it as args)
+    span_segs = {seg_of[id(e)] for e in events
+                 if e.get("event") == "episode_spans"}
+    ep_args = {(seg_of[id(e)], e.get("episode")):
+               {"episode": e.get("episode"), "sps": e.get("sps"),
+                "return": e.get("episodic_return")}
+               for e in events if e.get("event") == "episode"}
     flush_ts = {(seg_of[id(e)], e.get("flush_id")): _us(float(e["ts"]), t0)
                 for e in events
                 if e.get("event") == "serve_flush"
@@ -293,6 +356,19 @@ def build_trace(events: List[Dict]) -> Dict:
                  args={k: v for k, v in ev.items()
                        if k in ("run", "episodes", "replicas", "pipeline",
                                 "precision", "substep_impl", "mesh")})
+        elif kind == "episode_spans":
+            for sp in (ev.get("spans") or []):
+                root = sp.get("name") == "episode"
+                start = _us(float(sp["t0"]), t0)
+                dur = round(max(float(sp.get("dur_s") or 0.0), 0.0) * 1e6, 1)
+                push("X", (f"episode {sp.get('episode')}" if root
+                           else sp.get("name")), ep_tid, start, dur=dur,
+                     args=(ep_args.get((seg_of[id(ev)], sp.get("episode")))
+                           if root else {"episode": sp.get("episode"),
+                                         "parent": sp.get("parent")}))
+                prev_end = max(prev_end, round(start + dur, 1))
+        elif kind == "episode" and seg_of[id(ev)] in span_segs:
+            pass    # drawn from its recorded root span, above
         elif kind == "episode":
             start = max(prev_end, 0.0)
             end = max(ts_us, start)

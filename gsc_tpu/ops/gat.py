@@ -121,9 +121,10 @@ def gatv2_dense(x: jnp.ndarray, adj: jnp.ndarray, w_l: jnp.ndarray,
     """Dense masked GATv2 layer.  x: [..., N, F_in], adj: [..., N, N] bool.
     ``compute_dtype`` (PrecisionPolicy.gnn_compute) selects the attention
     precision; None is the exact f32 path."""
-    xl = project(x, w_l, b_l, compute_dtype)  # [..., N, F] source projection
-    xr = project(x, w_r, b_r, compute_dtype)  # [..., N, F] target projection
-    return attention_dense(xl, xr, att, bias, adj, mean_aggr)
+    with jax.named_scope("gat_layer"):
+        xl = project(x, w_l, b_l, compute_dtype)  # [..., N, F] source proj.
+        xr = project(x, w_r, b_r, compute_dtype)  # [..., N, F] target proj.
+        return attention_dense(xl, xr, att, bias, adj, mean_aggr)
 
 
 def gatv2_segment(x: jnp.ndarray, edge_index: jnp.ndarray,

@@ -408,9 +408,11 @@ class ParallelDDPG:
             mask = action_mask(tp.node_mask, self.env.limits.num_sfcs,
                                self.env.limits.max_sfs)
             step_mask = shuffle.step_mask(ob, mask, perm)
-            action = self.ddpg.choose_action(
-                state.actor_params, ob, step_mask, episode_start_step + i, key)
-            action = self.env.process_action(action)
+            with jax.named_scope("policy_forward"):
+                action = self.ddpg.choose_action(
+                    state.actor_params, ob, step_mask,
+                    episode_start_step + i, key)
+                action = self.env.process_action(action)
             es, next_ob, reward, done, info = self.env.step(
                 es, tp, tr, shuffle.env_action(action, perm))
             next_ob, next_perm = shuffle.advance(
@@ -435,8 +437,9 @@ class ParallelDDPG:
             return (env_states, obs, perms, buffers), stats
 
         T = self.agent.episode_steps if num_steps is None else num_steps
-        (env_states, obs, _, buffers), stats = jax.lax.scan(
-            step_fn, (env_states, obs, perms0, buffers), jnp.arange(T))
+        with jax.named_scope("rollout_step"):   # what no inner layer claims
+            (env_states, obs, _, buffers), stats = jax.lax.scan(
+                step_fn, (env_states, obs, perms0, buffers), jnp.arange(T))
         # stats leaves: [T, B]
         episode_stats = {
             "episodic_return": stats["reward"].sum(0).mean(),

@@ -72,9 +72,10 @@ def run_chunked_episodes(pddpg, topo, episode_traffic: Callable,
     returns, succ, final_succ = [], [], []
     for ep in range(episodes):
         traffic = episode_traffic(ep)
-        env_states, obs = pddpg.reset_all(
-            jax.random.fold_in(jax.random.PRNGKey(seed + 2), ep),
-            topo, traffic)
+        with phase_span("reset_enqueue", timer, hub):
+            env_states, obs = pddpg.reset_all(
+                jax.random.fold_in(jax.random.PRNGKey(seed + 2), ep),
+                topo, traffic)
         chunk_stats = []
         n_chunks = episode_steps // chunk
         with phase_span("dispatch", timer, hub):
@@ -101,65 +102,69 @@ def run_chunked_episodes(pddpg, topo, episode_traffic: Callable,
             # comparable to Trainer stats / the historical BENCH quality
             # bars
             final_succ.append(float(chunk_stats[-1]["final_succ_ratio"]))
-        if hub is not None:
-            # replica-resolved telemetry (the harness's own series — the
-            # episodes_* counters belong to whoever drives the run).  The
-            # event carries the GLOBAL episode index: per-episode drivers
-            # (train_parallel) call with episodes=1 and a step_offset, so
-            # the loop-local ep alone would stamp every record episode=0.
-            global_ep = step_offset // episode_steps + ep
-            per_rep = [np.asarray(s["per_replica_return"])
-                       for s in chunk_stats if "per_replica_return" in s]
-            rep_returns = (np.sum(per_rep, axis=0).tolist()
-                           if per_rep else None)
-            if rep_returns is not None:
-                for r, v in enumerate(rep_returns):
-                    hub.gauge("replica_return", v, replica=str(r))
-            per_topo = None
-            if rep_returns is not None and topo_names:
-                groups = {}
-                for name, v in zip(topo_names, rep_returns):
-                    groups.setdefault(name, []).append(v)
-                per_topo = {name: float(np.mean(vs))
-                            for name, vs in groups.items()}
-                for name, v in per_topo.items():
-                    hub.gauge("topology_return", v, topology=name)
-            if buffers is not None and hasattr(buffers, "size"):
-                for r, fill in enumerate(np.asarray(buffers.size).tolist()):
-                    hub.gauge("replica_replay_fill", fill, replica=str(r))
-            # divergence-guard verdict for the episode: the rollout flags
-            # (state entering each chunk) AND the learn burst's post-update
-            # flag — all device scalars already synced by the drain above;
-            # absent on fakes/legacy stats (None, not a false alarm)
-            finite = None
-            flags = [s["state_finite"] for s in chunk_stats
-                     if "state_finite" in s]
-            if metrics is not None and "state_finite" in metrics:
-                flags.append(metrics["state_finite"])
-            if flags:
-                finite = bool(min(float(f) for f in flags) > 0)
-            hub.event("harness_episode", episode=global_ep,
-                      episodic_return=returns[-1],
-                      mean_succ_ratio=succ[-1],
-                      final_succ_ratio=final_succ[-1],
-                      per_replica_return=rep_returns,
-                      state_finite=finite,
-                      # mixed-topology attribution; absent (not null-
-                      # spammed) on homogeneous runs to keep the legacy
-                      # event schema byte-stable
-                      **({"topology": list(topo_names),
-                          "per_topology_return": per_topo}
-                         if topo_names else {}))
-            signal = (metrics or {}).get("learn_signal") \
-                if isinstance(metrics, dict) else None
-            replay = chunk_stats[-1].get("replay") \
-                if isinstance(chunk_stats[-1], dict) else None
-            if signal is not None or replay is not None:
-                # everything here was synced by the drain above — the
-                # emit is pure host bookkeeping, never a device wait
-                emit_learn_signal(hub, global_ep, signal=signal,
-                                  replay=replay,
-                                  segment_names=learn_names)
-        if on_episode is not None:
-            on_episode(ep, returns[-1], succ[-1], metrics)
+        # everything the harness does for the hub after the drain, and the
+        # caller's per-episode hook
+        with phase_span("harness_observe", timer, hub):
+            if hub is not None:
+                # replica-resolved telemetry (the harness's own series — the
+                # episodes_* counters belong to whoever drives the run).  The
+                # event carries the GLOBAL episode index: per-episode drivers
+                # (train_parallel) call with episodes=1 and a step_offset, so
+                # the loop-local ep alone would stamp every record episode=0.
+                global_ep = step_offset // episode_steps + ep
+                per_rep = [np.asarray(s["per_replica_return"])
+                           for s in chunk_stats if "per_replica_return" in s]
+                rep_returns = (np.sum(per_rep, axis=0).tolist()
+                               if per_rep else None)
+                if rep_returns is not None:
+                    for r, v in enumerate(rep_returns):
+                        hub.gauge("replica_return", v, replica=str(r))
+                per_topo = None
+                if rep_returns is not None and topo_names:
+                    groups = {}
+                    for name, v in zip(topo_names, rep_returns):
+                        groups.setdefault(name, []).append(v)
+                    per_topo = {name: float(np.mean(vs))
+                                for name, vs in groups.items()}
+                    for name, v in per_topo.items():
+                        hub.gauge("topology_return", v, topology=name)
+                if buffers is not None and hasattr(buffers, "size"):
+                    fills = np.asarray(buffers.size).tolist()
+                    for r, fill in enumerate(fills):
+                        hub.gauge("replica_replay_fill", fill, replica=str(r))
+                # divergence-guard verdict for the episode: the rollout flags
+                # (state entering each chunk) AND the learn burst's post-update
+                # flag — all device scalars already synced by the drain;
+                # absent on fakes/legacy stats (None, not a false alarm)
+                finite = None
+                flags = [s["state_finite"] for s in chunk_stats
+                         if "state_finite" in s]
+                if metrics is not None and "state_finite" in metrics:
+                    flags.append(metrics["state_finite"])
+                if flags:
+                    finite = bool(min(float(f) for f in flags) > 0)
+                hub.event("harness_episode", episode=global_ep,
+                          episodic_return=returns[-1],
+                          mean_succ_ratio=succ[-1],
+                          final_succ_ratio=final_succ[-1],
+                          per_replica_return=rep_returns,
+                          state_finite=finite,
+                          # mixed-topology attribution; absent (not null-
+                          # spammed) on homogeneous runs to keep the legacy
+                          # event schema byte-stable
+                          **({"topology": list(topo_names),
+                              "per_topology_return": per_topo}
+                             if topo_names else {}))
+                signal = (metrics or {}).get("learn_signal") \
+                    if isinstance(metrics, dict) else None
+                replay = chunk_stats[-1].get("replay") \
+                    if isinstance(chunk_stats[-1], dict) else None
+                if signal is not None or replay is not None:
+                    # everything here was synced by the drain above — the
+                    # emit is pure host bookkeeping, never a device wait
+                    emit_learn_signal(hub, global_ep, signal=signal,
+                                      replay=replay,
+                                      segment_names=learn_names)
+            if on_episode is not None:
+                on_episode(ep, returns[-1], succ[-1], metrics)
     return state, buffers, returns, succ, final_succ

@@ -34,12 +34,13 @@ def all_finite(tree: Any) -> jnp.ndarray:
     """Scalar f32 flag (1.0/0.0): every inexact leaf of ``tree`` is
     finite.  Pure jnp — safe to trace inside the fused episode programs;
     integer leaves (PRNG keys, ring-buffer counters) are skipped."""
-    flags = [jnp.isfinite(leaf).all()
-             for leaf in jax.tree_util.tree_leaves(tree)
-             if jnp.issubdtype(jnp.asarray(leaf).dtype, jnp.inexact)]
-    if not flags:
-        return jnp.float32(1.0)
-    return jnp.stack(flags).all().astype(jnp.float32)
+    with jax.named_scope("finite_guard"):
+        flags = [jnp.isfinite(leaf).all()
+                 for leaf in jax.tree_util.tree_leaves(tree)
+                 if jnp.issubdtype(jnp.asarray(leaf).dtype, jnp.inexact)]
+        if not flags:
+            return jnp.float32(1.0)
+        return jnp.stack(flags).all().astype(jnp.float32)
 
 
 def tree_copy(tree: Any) -> Any:

@@ -366,10 +366,11 @@ class SimEngine:
         the XLA body, asserted by ``pytest -m megakernel``).  Per-flow
         external decisions always run the XLA body (SimConfig rejects
         the pallas impl for controller="per_flow")."""
-        if self.cfg.substep_impl == "pallas" and ext_decisions is None:
-            return self._substep_pallas(state, topo, traffic, cap_now)
-        return self._substep_xla(state, topo, traffic, cap_now,
-                                 ext_decisions)
+        with jax.named_scope("sim_substep"):
+            if self.cfg.substep_impl == "pallas" and ext_decisions is None:
+                return self._substep_pallas(state, topo, traffic, cap_now)
+            return self._substep_xla(state, topo, traffic, cap_now,
+                                     ext_decisions)
 
     def _substep_pallas(self, state: SimState, topo: Topology,
                         traffic: TrafficSchedule,
@@ -452,63 +453,69 @@ class SimEngine:
         need_proc_a = arrived & ~to_eg_flag
 
         # --- 3. arrivals ----------------------------------------------------
-        cand = state.cursor + jnp.arange(_ARRIVALS_PER_SUBSTEP)
-        cand_c = jnp.clip(cand, 0, traffic.capacity - 1)
-        due = (traffic.arr_time[cand_c] < t + dt - _EPS) & (cand < traffic.capacity) \
-            & jnp.isfinite(traffic.arr_time[cand_c])
-        free = phase == PH_FREE
-        free_rank = jnp.cumsum(free.astype(jnp.int32)) - 1
-        n_free = free.sum()
-        arr_rank = jnp.cumsum(due.astype(jnp.int32)) - 1
-        spawn = due & (arr_rank < n_free)
-        # slot_of_rank[r] = slot index of the r-th free slot (one-hot
-        # transpose scatter; the [A]-sized rank gather stays native)
-        oh_rank = _onehot(jnp.where(free, free_rank, self.M), self.M)
-        slot_of_rank = jnp.round(jnp.dot(slots.astype(jnp.float32), oh_rank,
-                                         precision=_HI)).astype(jnp.int32)
-        tgt = slot_of_rank[jnp.clip(arr_rank, 0, self.M - 1)]
+        with jax.named_scope("traffic_arrivals"):
+            cand = state.cursor + jnp.arange(_ARRIVALS_PER_SUBSTEP)
+            cand_c = jnp.clip(cand, 0, traffic.capacity - 1)
+            due = (traffic.arr_time[cand_c] < t + dt - _EPS) \
+                & (cand < traffic.capacity) \
+                & jnp.isfinite(traffic.arr_time[cand_c])
+            free = phase == PH_FREE
+            free_rank = jnp.cumsum(free.astype(jnp.int32)) - 1
+            n_free = free.sum()
+            arr_rank = jnp.cumsum(due.astype(jnp.int32)) - 1
+            spawn = due & (arr_rank < n_free)
+            # slot_of_rank[r] = slot index of the r-th free slot (one-hot
+            # transpose scatter; the [A]-sized rank gather stays native)
+            oh_rank = _onehot(jnp.where(free, free_rank, self.M), self.M)
+            slot_of_rank = jnp.round(
+                jnp.dot(slots.astype(jnp.float32), oh_rank,
+                        precision=_HI)).astype(jnp.int32)
+            tgt = slot_of_rank[jnp.clip(arr_rank, 0, self.M - 1)]
 
-        # one packed scatter per dtype instead of 11 per-field scatters —
-        # scatters end XLA fusions, so per-substep op count (the TPU cost
-        # driver) tracks the number of scatters, not the bytes moved
-        arr_idx = jnp.where(spawn, tgt, self.M)
-        a_i32 = jnp.zeros_like(cand)
-        int_cur = jnp.stack([phase, node, position, F.sfc, F.egress, F.dest],
-                            axis=-1)                           # [M, 6]
-        int_new = jnp.stack([a_i32 + PH_DECIDE, traffic.arr_ingress[cand_c],
-                             a_i32, traffic.arr_sfc[cand_c],
-                             traffic.arr_egress[cand_c], a_i32 - 1],
-                            axis=-1)                           # [A, 6]
-        int_cur = int_cur.at[arr_idx].set(int_new, mode="drop")
-        phase, node, position, sfc, egress, dest = (
-            int_cur[:, 0], int_cur[:, 1], int_cur[:, 2], int_cur[:, 3],
-            int_cur[:, 4], int_cur[:, 5])
-        a_f32 = jnp.zeros(cand.shape, jnp.float32)
-        flt_cur = jnp.stack([F.dr, F.duration, ttl, e2e, F.pend_path],
-                            axis=-1)                           # [M, 5]
-        flt_new = jnp.stack([traffic.arr_dr[cand_c],
-                             traffic.arr_duration[cand_c],
-                             traffic.arr_ttl[cand_c], a_f32, a_f32],
-                            axis=-1)                           # [A, 5]
-        flt_cur = flt_cur.at[arr_idx].set(flt_new, mode="drop")
-        dr, duration, ttl, e2e, pend_path = (
-            flt_cur[:, 0], flt_cur[:, 1], flt_cur[:, 2], flt_cur[:, 3],
-            flt_cur[:, 4])
-        hop_next = F.hop_next
-        n_spawn = spawn.sum()
-        cursor = state.cursor + n_spawn
-        # arrivals spawning after their scheduled substep were delayed by
-        # slot exhaustion / the per-substep arrival budget — count each once
-        late = spawn & (traffic.arr_time[cand_c] < t - _EPS)
-        truncated = state.truncated_arrivals + late.sum()
-        m = m.replace(
-            generated=m.generated + n_spawn,
-            run_generated=m.run_generated + n_spawn,
-            active=m.active + n_spawn,
-            run_requested_node=m.run_requested_node.at[
-                jnp.where(spawn, traffic.arr_ingress[cand_c], self.N)
-            ].add(jnp.where(spawn, traffic.arr_dr[cand_c], 0.0), mode="drop"),
-        )
+            # one packed scatter per dtype instead of 11 per-field scatters —
+            # scatters end XLA fusions, so per-substep op count (the TPU cost
+            # driver) tracks the number of scatters, not the bytes moved
+            arr_idx = jnp.where(spawn, tgt, self.M)
+            a_i32 = jnp.zeros_like(cand)
+            int_cur = jnp.stack(
+                [phase, node, position, F.sfc, F.egress, F.dest],
+                axis=-1)                                       # [M, 6]
+            int_new = jnp.stack(
+                [a_i32 + PH_DECIDE, traffic.arr_ingress[cand_c], a_i32,
+                 traffic.arr_sfc[cand_c], traffic.arr_egress[cand_c],
+                 a_i32 - 1], axis=-1)                          # [A, 6]
+            int_cur = int_cur.at[arr_idx].set(int_new, mode="drop")
+            phase, node, position, sfc, egress, dest = (
+                int_cur[:, 0], int_cur[:, 1], int_cur[:, 2], int_cur[:, 3],
+                int_cur[:, 4], int_cur[:, 5])
+            a_f32 = jnp.zeros(cand.shape, jnp.float32)
+            flt_cur = jnp.stack([F.dr, F.duration, ttl, e2e, F.pend_path],
+                                axis=-1)                           # [M, 5]
+            flt_new = jnp.stack([traffic.arr_dr[cand_c],
+                                 traffic.arr_duration[cand_c],
+                                 traffic.arr_ttl[cand_c], a_f32, a_f32],
+                                axis=-1)                           # [A, 5]
+            flt_cur = flt_cur.at[arr_idx].set(flt_new, mode="drop")
+            dr, duration, ttl, e2e, pend_path = (
+                flt_cur[:, 0], flt_cur[:, 1], flt_cur[:, 2], flt_cur[:, 3],
+                flt_cur[:, 4])
+            hop_next = F.hop_next
+            n_spawn = spawn.sum()
+            cursor = state.cursor + n_spawn
+            # arrivals spawning after their scheduled substep were delayed
+            # by slot exhaustion / the per-substep arrival budget — count
+            # each once
+            late = spawn & (traffic.arr_time[cand_c] < t - _EPS)
+            truncated = state.truncated_arrivals + late.sum()
+            m = m.replace(
+                generated=m.generated + n_spawn,
+                run_generated=m.run_generated + n_spawn,
+                active=m.active + n_spawn,
+                run_requested_node=m.run_requested_node.at[
+                    jnp.where(spawn, traffic.arr_ingress[cand_c], self.N)
+                ].add(jnp.where(spawn, traffic.arr_dr[cand_c], 0.0),
+                      mode="drop"),
+            )
 
         # recompute flags after arrivals.  The UN-clipped one-hot zero-rows
         # out-of-range SFC ids (reachable only with corrupt traffic data):

@@ -129,8 +129,9 @@ def renewal_stream(cfg: SimConfig, means, active, next_active,
     # the merge scan is `capacity` tiny sequential steps (12.8k on the
     # flagship): unrolling amortizes the per-iteration loop overhead,
     # which dominates a body this small on TPU
-    _, rows = jax.lax.scan(emit, t_init, jnp.arange(capacity),
-                           unroll=8 if capacity % 8 == 0 else 1)
+    with jax.named_scope("traffic_arrivals"):
+        _, rows = jax.lax.scan(emit, t_init, jnp.arange(capacity),
+                               unroll=8 if capacity % 8 == 0 else 1)
     return rows
 
 

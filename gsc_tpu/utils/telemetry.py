@@ -13,7 +13,7 @@ import csv
 import os
 import time
 from contextlib import contextmanager
-from typing import Dict, Optional, Sequence
+from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
@@ -41,20 +41,57 @@ class PhaseTimer:
     clock), and an unlocked read-modify-write would drop increments under
     that interleaving."""
 
-    def __init__(self):
+    # closed spans kept until the loop takes them: a few thousand covers
+    # hundreds of episodes, so a loop that never takes them (the async
+    # path) holds a bounded ring, not a leak
+    MAX_SPANS = 4096
+
+    def __init__(self, wall=time.time, perf=time.perf_counter):
+        import collections
         import threading
 
         self._total: Dict[str, float] = {}
         self._count: Dict[str, int] = {}
         self._lock = threading.Lock()
+        self._wall, self._perf = wall, perf
+        self._spans = collections.deque(maxlen=self.MAX_SPANS)
+        # the identifier the spans of one episode share; the episode loop
+        # sets it at the top of each iteration
+        self.episode: Optional[int] = None
 
     @contextmanager
     def phase(self, name: str):
-        t0 = time.perf_counter()
+        t0 = self._perf()
         try:
             yield
         finally:
-            self.add(name, time.perf_counter() - t0)
+            self.add(name, self._perf() - t0)
+
+    @contextmanager
+    def span(self, name: str, parent: Optional[str] = None):
+        """:meth:`phase` that also keeps the closed span itself:
+        ``{name, parent, episode, t0, dur_s}`` — ``t0`` on the wall clock
+        (the clock of the hub's ``ts``), ``dur_s`` on the monotonic one,
+        ``episode`` as it stood when the span opened.  ``parent`` is the
+        caller's to give: :func:`gsc_tpu.obs.trace.phase_span` keeps the
+        per-thread stack of open spans."""
+        t0, p0, episode = self._wall(), self._perf(), self.episode
+        try:
+            yield
+        finally:
+            dur = self._perf() - p0
+            self.add(name, dur)
+            with self._lock:
+                self._spans.append({"name": name, "parent": parent,
+                                    "episode": episode, "t0": t0,
+                                    "dur_s": dur})
+
+    def take_spans(self) -> List[Dict]:
+        """The spans closed since the last call, oldest first."""
+        with self._lock:
+            out = list(self._spans)
+            self._spans.clear()
+        return out
 
     def add(self, name: str, seconds: float):
         with self._lock:
