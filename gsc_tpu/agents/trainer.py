@@ -1083,7 +1083,9 @@ class Trainer:
                     lambda *xs: jax.numpy.stack(xs), *stacked)
             # key by the topology OBJECT the episode actually uses — the
             # driver owns the schedule; re-deriving its index here would
-            # duplicate that invariant
+            # duplicate that invariant.  One sampler per topology for the
+            # run, so its jitted `traffic_sample` traces once per topology
+            # the schedule visits, not once per episode
             if id(topo) not in samplers:
                 samplers[id(topo)] = DeviceTraffic(
                     self.env.sim_cfg, self.env.service, topo, steps_per_ep,
@@ -1145,10 +1147,14 @@ class Trainer:
                 emit_episode_spans(hub, timer)
                 # the scenario_regen phase measures what producing this
                 # episode's (topology, traffic) costs the HOST: the full
-                # Python regen wall on host-traffic paths, dispatch-
-                # enqueue time on device-sampling paths — the cost the
-                # factory deletes, measured instead of asserted
-                # (SCEN_r01 banks the before/after)
+                # Python regen wall on host-traffic paths; on device-
+                # sampling paths one async dispatch of a memoised jit
+                # (`traffic_sample` per (topology, B), `factory_sample`
+                # per B — traced once, never waited on here, so the
+                # sampler's device scan queues ahead of reset_all and
+                # the first chunk_step).  The cost the factory deletes,
+                # measured instead of asserted (SCEN_r01 banks the
+                # before/after)
                 with phase_span("scenario_regen", timer, hub):
                     if factory is not None:
                         # fresh per-replica scenarios, entirely on

@@ -34,12 +34,14 @@ from typing import Dict, Iterable, List, Optional, Tuple
 
 # entry points a training run cares about: the fused episode/chunk kernels
 # and their two-call fallbacks (agents/ddpg.py, parallel/dp.py, env reset),
-# plus the on-device scenario sampler (topology/factory.py) and the async
-# replay service insert (parallel/async_rl.py) — a factory or async run's
-# stream contract is exactly one trace per entry point
+# plus the on-device scenario sampler (topology/factory.py), the device
+# traffic sampler (sim/traffic_device.py: one trace per (topology, B)) and
+# the async replay service insert (parallel/async_rl.py) — a factory or
+# async run's stream contract is exactly one trace per entry point
 DEFAULT_WATCH = ("episode_step", "rollout_episode", "learn_burst",
                  "chunk_step", "rollout_episodes", "reset_all", "reset",
-                 "step", "factory_sample", "replay_ingest")
+                 "step", "factory_sample", "traffic_sample",
+                 "replay_ingest")
 
 _TRACE_RE = re.compile(
     r"Finished tracing \+ transforming (.+?) for pjit in ([0-9.eE+-]+) sec")

@@ -3,10 +3,13 @@
 The host generator (``traffic.py``) is the reference-parity path; this
 module is the THROUGHPUT path: per-episode traffic resampling as a jitted
 device computation keyed per (replica, episode), so training never ships
-MB-scale flow tensors host->device between episodes.  At B=256 on the
-flagship scenario the host path moves ~90 MB per episode host->device;
-host-side SAMPLING is cheap (~0.5 s/256 traces) — the transfer is the
-cost being deleted here.  (What that transfer costs on a local chip is
+MB-scale flow tensors host->device between episodes.  The loops' entry is
+``DeviceTraffic.sample_batch``: one jit named ``traffic_sample`` per
+(sampler, B), traced on first use and dispatched asynchronously every
+episode after (a watched entry point of ``analysis.sentinels``).  At B=256
+on the flagship scenario the host path moves ~90 MB per episode
+host->device; host-side SAMPLING is cheap (~0.5 s/256 traces) — the
+transfer is the cost being deleted here.  (What that transfer costs on a local chip is
 not measured; the builders' round-3 figure was taken over a remote link.)
 
 Semantics follow ``traffic.generate_traffic`` / the reference generator
@@ -218,6 +221,7 @@ class DeviceTraffic:
             names = [s.name for s in cfg.states]
             self.init_state = (0 if cfg.init_state is None
                                else names.index(cfg.init_state))
+        self._jit = {}   # num_replicas -> the jitted batch sampler
 
     # ------------------------------------------------------------- sampling
     def _interval_means(self, key) -> jnp.ndarray:
@@ -263,5 +267,15 @@ class DeviceTraffic:
             edge_cap_t=self.edge_cap_t)
 
     def sample_batch(self, key, num_replicas: int) -> TrafficSchedule:
-        """[B]-stacked schedules (one per replica), a single device call."""
-        return jax.vmap(self.sample)(jax.random.split(key, num_replicas))
+        """[B]-stacked schedules (one per replica): ONE jitted device call,
+        built on first use and memoized per ``num_replicas`` — one trace
+        per (sampler, B) for the whole run, an async dispatch after."""
+        fn = self._jit.get(num_replicas)
+        if fn is None:
+
+            def traffic_sample(key):
+                return jax.vmap(self.sample)(
+                    jax.random.split(key, num_replicas))
+
+            fn = self._jit[num_replicas] = jax.jit(traffic_sample)
+        return fn(key)

@@ -231,6 +231,67 @@ def test_factory_zero_retrace_across_50_episode_stream():
     assert np.isfinite(float(stats["episodic_return"]))
 
 
+class _BoundaryGuard:
+    """A ``preempt`` that never stops the loop: ``train_parallel`` reads
+    ``triggered`` at the top of every episode, after the previous
+    episode's drain, so the read before episode ``guard_from`` is where
+    ``open_region`` (a no-retrace region over the rest of the run) is
+    called."""
+    signame = "none"
+
+    def __init__(self, open_region, guard_from):
+        self.open_region, self.guard_from = open_region, guard_from
+        self.reads = 0
+
+    @property
+    def triggered(self):
+        if self.reads == self.guard_from:
+            self.open_region()
+        self.reads += 1
+        return False
+
+
+@pytest.mark.parametrize("n_topologies,episodes", [(1, 3), (2, 4)],
+                         ids=["one_topology", "two_topology_schedule"])
+def test_train_parallel_device_traffic_traces_per_topology(
+        tmp_path, n_topologies, episodes):
+    """The product loop with device traffic on (no factory, no mix): the
+    sampler's ``traffic_sample`` traces once per topology the schedule
+    visits — not once per episode — and from the second episode on
+    neither it nor ``chunk_step``/``reset_all`` traces again."""
+    import contextlib
+
+    from gsc_tpu.agents.trainer import Trainer
+    from gsc_tpu.analysis.sentinels import CompileMonitor
+
+    env, agent = _det_env(2)
+    topos = [compile_topology(spec, max_nodes=8, max_edges=8)
+             for spec in (triangle(), line(3))[:n_topologies]]
+    files = tuple(f"{i}.graphml" for i in range(n_topologies))
+    sched = SchedulerConfig(training_network_files=files,
+                            inference_network=files[0], period=1)
+    driver = EpisodeDriver(sched, env.sim_cfg, env.service, 2,
+                           max_nodes=8, max_edges=8, topologies=topos,
+                           inference_topology=topos[0])
+    tr = Trainer(env, driver, agent, seed=0, result_dir=str(tmp_path))
+    names = ("traffic_sample", "chunk_step", "reset_all")
+    # the region (closed, and a retrace raised, where the `with` ends)
+    # opens once every topology has been seen: before episode 1 with one,
+    # before episode 2 with two alternating (episode 1 brings the second
+    # sampler's one trace)
+    with CompileMonitor(watch=names) as mon, \
+            contextlib.ExitStack() as region:
+        guard = _BoundaryGuard(
+            lambda: region.enter_context(mon.assert_no_retrace(*names)),
+            guard_from=n_topologies)
+        tr.train_parallel(episodes, num_replicas=2, chunk=1,
+                          preempt=guard)
+        assert guard.reads == episodes
+    assert mon.trace_counts["traffic_sample"] == n_topologies
+    assert len(tr.history) == episodes
+    assert all(np.isfinite(h["episodic_return"]) for h in tr.history)
+
+
 # --------------------------------------------------------------- curriculum
 def test_curriculum_ewma_math_hand_computed():
     c = Curriculum(["a", "b"], CurriculumConfig(alpha=0.5, floor=0.0,

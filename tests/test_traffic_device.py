@@ -1,10 +1,14 @@
 """On-device traffic generator tests: bitwise parity with the host
 generator on deterministic configs, distributional parity on stochastic
-ones, trace/MMPP semantics, and engine compatibility."""
+ones, trace/MMPP semantics, engine compatibility, and the batched
+sampler's one-jit contract (``traffic_sample``: same schedule as the
+un-jitted ``vmap``, one trace per (sampler, B))."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
+from gsc_tpu.analysis.sentinels import DEFAULT_WATCH, CompileMonitor
 from gsc_tpu.config.schema import EnvLimits, MMPPState, SimConfig
 from gsc_tpu.sim.engine import SimEngine
 from gsc_tpu.sim.traffic import TraceEvents, generate_traffic
@@ -158,3 +162,80 @@ def test_trace_overrides_mmpp_means():
     post = np.sort(t[t >= 1500.0])
     gaps = np.diff(post)
     assert np.allclose(gaps, 5.0)
+
+
+# ------------------------------------------------ sample_batch: one jit
+# the benchmark cells' simulator document (benchmarks/configs/*.json):
+# every draw degenerate, so the schedule must not move by a bit
+_CELLS = dict(ttl_choices=(100.0,), inter_arrival_mean=10.0,
+              deterministic_arrival=True, deterministic_size=True,
+              flow_dr_mean=1.0, flow_dr_stdev=0.0, flow_size_shape=0.001)
+_STOCHASTIC = dict(
+    ttl_choices=(50.0, 100.0), deterministic_arrival=False,
+    deterministic_size=False, flow_size_shape=2.0, flow_dr_mean=1.0,
+    flow_dr_stdev=0.3, use_states=True, init_state="s0",
+    rand_init_state=True,
+    states=(MMPPState(name="s0", inter_arr_mean=5.0, switch_p=0.5),
+            MMPPState(name="s1", inter_arr_mean=50.0, switch_p=0.5)))
+
+
+@pytest.mark.parametrize("kwargs,bitwise", [(_CELLS, True),
+                                            (_STOCHASTIC, False)],
+                         ids=["cells_deterministic", "stochastic"])
+def test_sample_batch_equals_unjitted_vmap(kwargs, bitwise):
+    """The jitted batch sampler draws what a bare ``vmap(sample)`` over the
+    split key draws, dispatched operation by operation: every field bit for
+    bit on the cells' configuration and the integer fields always, the
+    float fields to 1e-6 relative where real draws pass through fused
+    arithmetic."""
+    dt = DeviceTraffic(SimConfig(**kwargs), service(), topo(2),
+                       episode_steps=6)
+    B, key = 3, jax.random.PRNGKey(2**31 + 11)
+    got = dt.sample_batch(key, B)
+    # the eager path the loops ran before: each jnp operation dispatched
+    # on its own, the merge scan as its own `jit_scan`
+    want = jax.vmap(dt.sample)(jax.random.split(key, B))
+    assert int(np.isfinite(np.asarray(got.arr_time)).sum()) > B * 10
+    for field in ("arr_time", "arr_ingress", "arr_dr", "arr_duration",
+                  "arr_ttl", "arr_sfc", "arr_egress", "ingress_active",
+                  "node_cap"):
+        g, w = np.asarray(getattr(got, field)), np.asarray(
+            getattr(want, field))
+        assert g.dtype == w.dtype and g.shape == w.shape, field
+        if bitwise or g.dtype.kind in "ib":
+            np.testing.assert_array_equal(g, w, err_msg=field)
+        else:
+            np.testing.assert_allclose(g, w, rtol=1e-6, err_msg=field)
+    assert got.edge_cap_t is None and want.edge_cap_t is None
+
+
+def test_sample_batch_traces_once_per_sampler_and_batch():
+    """``traffic_sample`` is traced on first use and never again for fresh
+    keys; a second ``num_replicas`` on the same sampler costs exactly one
+    more trace."""
+    dt = DeviceTraffic(SimConfig(**_CELLS), service(), topo(2),
+                       episode_steps=4)
+    with CompileMonitor(watch=("traffic_sample",)) as mon:
+        first = dt.sample_batch(jax.random.PRNGKey(0), 4)
+        with mon.assert_no_retrace("traffic_sample"):
+            for seed in (1, 2):
+                again = dt.sample_batch(jax.random.PRNGKey(seed), 4)
+        assert mon.trace_counts["traffic_sample"] == 1
+        assert again.arr_time.shape == first.arr_time.shape
+        other = dt.sample_batch(jax.random.PRNGKey(3), 2)
+        dt.sample_batch(jax.random.PRNGKey(4), 2)
+        assert mon.trace_counts["traffic_sample"] == 2
+    assert other.arr_time.shape == (2, dt.capacity)
+
+
+def test_traffic_sample_is_a_default_watched_entry_point():
+    """The observer's default monitor turns the sampler's trace into a
+    ``compile`` event and a ``jit_traces_total{fn=traffic_sample}``
+    count: the sampler is covered by ``window_compiles``."""
+    assert "traffic_sample" in DEFAULT_WATCH
+    dt = DeviceTraffic(SimConfig(**_CELLS), service(), topo(1),
+                       episode_steps=2)
+    with CompileMonitor() as mon:
+        dt.sample_batch(jax.random.PRNGKey(0), 2)
+    assert [e["fn"] for e in mon.events if e["kind"] == "trace"
+            ] == ["traffic_sample"]
