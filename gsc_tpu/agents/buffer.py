@@ -5,11 +5,22 @@ a numpy *object* array and re-batches them on every sample
 (src/rlsp/agents/buffer.py:16-89) — host memory, pointer chasing, CPU
 collation.  Here observations are already fixed-shape pytrees (GraphObs or
 flat vectors), so the whole buffer is a pytree with a leading [capacity]
-axis resident in device memory: ``add`` is a dynamic-index scatter, ``sample``
-a gather — both jit/scan-able, so rollout and learning never leave the
-device.  Works for any transition pytree (graph obs store nodes, edge_index,
-masks per transition, which also preserves cross-topology replay when the
-topology schedule swaps networks mid-training).
+axis resident in device memory: ``add`` is one in-place
+``dynamic_update_slice`` per leaf at the write cursor, ``sample`` a gather —
+both jit/scan-able, so rollout and learning never leave the device.  Works
+for any transition pytree (graph obs store nodes, edge_index, masks per
+transition, which also preserves cross-topology replay when the topology
+schedule swaps networks mid-training).
+
+Two writers share the layout.  ``buffer_add`` writes ONE ring at its own
+cursor (the single-environment agent).  The replica path holds B rings as
+one ``[B, capacity, ...]`` pytree whose cursors advance in lockstep, and
+writes a whole control step's B transitions with
+``buffer_write_lockstep``: one ``[B, 1, ...]`` slab per leaf at one SCALAR
+cursor.  ``buffer_add`` under ``vmap`` stores the same bits, but its
+per-replica cursor turns each leaf's update into a scatter with ``[B]``
+indices, which the TPU compiler expands into a sequential loop over the
+replicas — per leaf, per control step.
 
 Storage layout: per-transition leaves with ndim >= 2 (e.g. GraphObs.nodes
 [N, F], edge_index [2, E]) are stored FLATTENED to 1-D — [capacity, N*F] —
@@ -91,6 +102,61 @@ def buffer_add(buf: ReplayBuffer, item: Any) -> ReplayBuffer:
         return ReplayBuffer(data=data, pos=(buf.pos + 1) % capacity,
                             size=jnp.minimum(buf.size + 1, capacity),
                             shapes=buf.shapes)
+
+
+def buffer_write_lockstep(data: Any, items: Any, cursor) -> Any:
+    """Write one transition per replica into the ``data`` of the replica
+    path's ``[B, capacity, ...]`` rings: ``items`` is the ``[B]``-stacked
+    transition pytree, ``cursor`` the SCALAR slot every ring writes next.
+
+    The rings of the replica path advance in lockstep (every ``pos`` starts
+    at 0, a rollout adds its steps to every replica, ``replay_ingest`` adds
+    T to every row), so the write is one ``[B, 1, ...]`` slab per leaf at
+    ``cursor`` along axis 1: in place on a donated ring, no gather, no
+    scatter, no loop over the replicas — and, sharded on axis 0, no
+    collective.  Stores the bits ``jax.vmap(buffer_add)`` stores (leaves
+    flattened per replica as ``flatten_transition`` does, cast to the
+    leaf's storage dtype).  ``pos``/``size`` are the caller's to advance,
+    once for all the steps it wrote (``buffer_advance``), and the
+    invariant is the caller's too: ``cursor`` follows the rings' common
+    ``pos`` (``lockstep_cursor`` checks it where rings come from outside)."""
+    with jax.named_scope("replay_write"):
+        return jax.tree_util.tree_map(
+            lambda d, x: jax.lax.dynamic_update_slice_in_dim(
+                d, jnp.asarray(x).astype(d.dtype).reshape(
+                    d.shape[:1] + (1,) + d.shape[2:]), cursor, axis=1,
+                # a cursor is never negative: no index normalisation
+                allow_negative_indices=False),
+            data, items)
+
+
+def buffer_advance(buf: ReplayBuffer, data: Any, n: int) -> ReplayBuffer:
+    """``buf`` with ``data`` in place and every ring of the ``[B, capacity,
+    ...]`` pytree advanced by the ``n`` slots written into it."""
+    capacity = jax.tree_util.tree_leaves(data)[0].shape[1]
+    return buf.replace(data=data, pos=(buf.pos + n) % capacity,
+                       size=jnp.minimum(buf.size + n, capacity))
+
+
+def lockstep_cursor(buf: ReplayBuffer) -> int:
+    """The common write cursor of ``[B, capacity, ...]`` rings, checked on
+    the host: ``buffer_write_lockstep`` writes every replica's row at ONE
+    slot, so rings handed in from outside (a restored checkpoint) whose
+    cursors differ are refused, the replicas that differ named."""
+    import numpy as np
+
+    pos = np.asarray(jax.device_get(buf.pos)).reshape(-1)
+    values, counts = np.unique(pos, return_counts=True)
+    common = int(values[np.argmax(counts)])
+    off = np.flatnonzero(pos != common)
+    if off.size:
+        shown = ", ".join(f"{int(r)} (pos {int(pos[r])})" for r in off[:16])
+        raise ValueError(
+            f"replay rings out of lockstep: {off.size} of {pos.size} "
+            f"replicas have a write cursor other than {common}: replicas "
+            f"{shown}{', ...' if off.size > 16 else ''} — the replica "
+            "path writes every ring at one cursor")
+    return common
 
 
 def buffer_nbytes(buf: ReplayBuffer, local: bool = False) -> int:

@@ -40,7 +40,7 @@ from ..resilience.retry import (RetryPolicy, TransientDispatchError,
                                 call_with_retry)
 from ..utils.debug import check_invariants
 from ..utils.telemetry import PhaseTimer
-from .buffer import buffer_nbytes
+from .buffer import buffer_nbytes, lockstep_cursor
 from .ddpg import DDPG, DDPGState
 
 log = logging.getLogger("gsc_tpu.agents.trainer")
@@ -1037,6 +1037,9 @@ class Trainer:
         if init_state is not None:
             init_state = jax.tree_util.tree_map(jnp.copy, init_state)
         if init_buffers is not None:
+            # rings from outside: the rollout writes every replica's row
+            # at ONE cursor, so cursors that differ are refused here
+            lockstep_cursor(init_buffers)
             init_buffers = jax.tree_util.tree_map(jnp.copy, init_buffers)
 
         topo0, traffic0 = self.driver.episode(0, False)

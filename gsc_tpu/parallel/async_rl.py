@@ -94,7 +94,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..agents.buffer import ReplayBuffer, buffer_nbytes
+from ..agents.buffer import ReplayBuffer, buffer_advance, buffer_nbytes
 from ..resilience.faults import FaultInjected
 from ..resilience.guard import RollbackGuard, all_finite, poison_tree
 from ..resilience.retry import (RetryPolicy, TransientDispatchError,
@@ -137,9 +137,7 @@ def make_replay_ingest(num_replicas: int, capacity: int, sharding=None):
         data = jax.tree_util.tree_map(
             lambda d, s: d.at[rows, idx].set(s.astype(d.dtype)),
             buffers.data, block)
-        return buffers.replace(
-            data=data, pos=(buffers.pos + T) % capacity,
-            size=jnp.minimum(buffers.size + T, capacity))
+        return buffer_advance(buffers, data, T)
 
     if sharding is None:
         @partial(jax.jit, donate_argnums=(0,))

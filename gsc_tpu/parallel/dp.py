@@ -27,7 +27,8 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..agents.buffer import (ReplayBuffer, buffer_add, flatten_transition,
+from ..agents.buffer import (ReplayBuffer, buffer_advance,
+                             buffer_write_lockstep, flatten_transition,
                              restore_batch, transition_shapes)
 from ..agents.ddpg import DDPG, DDPGState, donated_jit
 from ..resilience.guard import all_finite
@@ -404,7 +405,7 @@ class ParallelDDPG:
         perms0 = jax.vmap(shuffle.init_perm)(jax.random.split(k0, self.B))
         obs = jax.vmap(shuffle.permute_obs)(obs, perms0)
 
-        def one_step(es, ob, perm, buf, tr, tp, key, i):
+        def one_step(es, ob, perm, tr, tp, key, i):
             mask = action_mask(tp.node_mask, self.env.limits.num_sfcs,
                                self.env.limits.max_sfs)
             step_mask = shuffle.step_mask(ob, mask, perm)
@@ -417,29 +418,46 @@ class ParallelDDPG:
                 es, tp, tr, shuffle.env_action(action, perm))
             next_ob, next_perm = shuffle.advance(
                 jax.random.fold_in(key, 1), next_ob, perm)
-            buf = buffer_add(buf, {
+            transition = {
                 "obs": ob, "next_obs": next_ob, "action": action,
                 "reward": reward, "done": done.astype(jnp.float32),
                 # per-replica network attribution: in mixed-topology
                 # batches tp is this replica's topology slice, so its
                 # topo_id is the mix-entry index
-                "topo_idx": tp.topo_id})
+                "topo_idx": tp.topo_id}
             stats = {"reward": reward, "succ_ratio": info["succ_ratio"],
                      "avg_e2e_delay": info["avg_e2e_delay"]}
-            return es, next_ob, next_perm, buf, stats
+            return es, next_ob, next_perm, transition, stats
+
+        # The replay write sits OUTSIDE the vmap over replicas: the body
+        # hands back the control step's transitions and step_fn writes
+        # the [B]-stacked slab once per leaf at one scalar cursor.  A
+        # buffer_add inside the vmap stores the same bits, but its
+        # per-replica cursor is a [B]-index scatter that the TPU compiler
+        # expands into a sequential loop over the replicas per leaf.  The
+        # rings advance in lockstep (buffer_write_lockstep), so the cursor
+        # is read once per dispatch, here, each step's slot follows from
+        # the scan index, and pos/size advance once, after the scan —
+        # only the ring's data rides the carry.
+        capacity = jax.tree_util.tree_leaves(buffers.data)[0].shape[1]
+        cursor0 = buffers.pos[0]
 
         def step_fn(carry, i):
-            env_states, obs, perms, buffers = carry
+            env_states, obs, perms, ring = carry
             keys = jax.random.split(jax.random.fold_in(sub, i), self.B)
-            env_states, obs, perms, buffers, stats = jax.vmap(
-                one_step, in_axes=(0, 0, 0, 0, 0, self._t_ax, 0, None))(
-                    env_states, obs, perms, buffers, traffic, topo, keys, i)
-            return (env_states, obs, perms, buffers), stats
+            env_states, obs, perms, transitions, stats = jax.vmap(
+                one_step, in_axes=(0, 0, 0, 0, self._t_ax, 0, None))(
+                    env_states, obs, perms, traffic, topo, keys, i)
+            ring = buffer_write_lockstep(ring, transitions,
+                                         (cursor0 + i) % capacity)
+            return (env_states, obs, perms, ring), stats
 
         T = self.agent.episode_steps if num_steps is None else num_steps
         with jax.named_scope("rollout_step"):   # what no inner layer claims
-            (env_states, obs, _, buffers), stats = jax.lax.scan(
-                step_fn, (env_states, obs, perms0, buffers), jnp.arange(T))
+            (env_states, obs, _, ring), stats = jax.lax.scan(
+                step_fn, (env_states, obs, perms0, buffers.data),
+                jnp.arange(T))
+            buffers = buffer_advance(buffers, ring, T)
         # stats leaves: [T, B]
         episode_stats = {
             "episodic_return": stats["reward"].sum(0).mean(),
@@ -471,7 +489,9 @@ class ParallelDDPG:
                              DDPGState, ReplayBuffer, Any, Any,
                              Dict[str, jnp.ndarray]]:
         """One episode on every replica: scan over steps of a vmapped
-        (action -> env.step -> buffer.add) body.  Parameters are shared
+        (action -> env.step) body and one replay write of the step's B
+        transitions (the rings' cursors must be equal on entry:
+        ``buffer.lockstep_cursor``).  Parameters are shared
         (replicated); env state, obs, buffers and traffic carry the leading
         [B] replica axis.
 

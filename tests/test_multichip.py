@@ -214,6 +214,63 @@ def test_plan_replica_divisibility_checked():
                      plan=ShardingPlan.from_spec("4x2"))
 
 
+def test_lockstep_ring_write_partitions_with_no_collective_in_the_scan():
+    """The one-slab replay write under a ``4x1`` plan: the ring is sharded
+    on its replica axis and the scalar cursor replicated, so the compiled
+    ``chunk_step`` holds no collective under the rollout's scan (the
+    parent's had none either: its collectives are the learn burst's batch
+    gathers and the entry's reductions); the cursor's read is one scalar
+    all-reduce in the entry; the ring leaves the program in the plan's
+    ``data_sharding``."""
+    from __graft_entry__ import _flagship
+    from gsc_tpu.analysis.hlo import COLLECTIVE_OPS, _shape_bytes, _walk_ops
+    from gsc_tpu.obs.trace import DEVICE_SCOPES
+
+    B = 8
+    env, agent, topo, one_traffic = _flagship(
+        max_nodes=8, max_edges=8, episode_steps=2, max_flows=32)
+    plan = ShardingPlan.from_spec("4x1", rules="sharded")
+    pddpg = ParallelDDPG(env, agent, num_replicas=B, plan=plan, donate=True)
+    traffic = jax.tree_util.tree_map(lambda x: jnp.stack([x] * B),
+                                     one_traffic)
+    env_states, obs = pddpg.reset_all(jax.random.PRNGKey(0), topo, traffic)
+    one_obs = jax.tree_util.tree_map(lambda x: x[0], obs)
+    state = pddpg.init(jax.random.PRNGKey(1), one_obs)
+    buffers = pddpg.init_buffers(one_obs)
+    fn = pddpg.sharded_lowerable("chunk_step", state)
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = fn.func.lower(pddpg, state, buffers, env_states, obs,
+                                 topo, traffic, np.int32(0), 2,
+                                 True).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    text = compiled.as_text()
+    kinds = COLLECTIVE_OPS + tuple(f"{op}-start" for op in COLLECTIVE_OPS)
+    collectives = [(name, op, type_text, path)
+                   for name, op, type_text, path, _ in _walk_ops(
+                       text, DEVICE_SCOPES) if op in kinds]
+    assert collectives                               # it IS partitioned
+    in_scan = [c for c in collectives if "rollout_step" in c[3]
+               or "replay_write" in c[3]]
+    assert in_scan == []
+    # outside the learn burst: the cursor and the episode's statistics,
+    # scalars and [B] vectors — never a ring leaf gathered
+    outside = [c for c in collectives if "learn_burst" not in c[3]]
+    assert 1 <= len(outside) <= 4
+    assert max(_shape_bytes(c[2]) for c in outside) <= 4 * B
+    # the update is a per-shard slab: B / 4 replicas' rows a device
+    cap = jax.tree_util.tree_leaves(buffers.data)[0].shape[1]
+    assert f"f32[{B // 4},{cap}]" in text and f"f32[{B},{cap}]" not in text
+
+    out = pddpg.chunk_step(state, buffers, env_states, obs, topo, traffic,
+                           np.int32(0), num_steps=2, learn=True)
+    for leaf in jax.tree_util.tree_leaves(out[1]):
+        assert leaf.sharding.is_equivalent_to(plan.data_sharding, leaf.ndim)
+    np.testing.assert_array_equal(np.asarray(out[1].pos), 2)
+    np.testing.assert_array_equal(np.asarray(out[1].size), 2)
+
+
 # ------------------------------------------------------------ elastic resume
 def test_subprocess_elastic_resume_8_to_4_devices(tmp_path):
     """Satellite acceptance: a run checkpointed on an 8-device 4x2 mesh

@@ -253,3 +253,201 @@ def test_chunked_rollout_rejects_shuffle():
     # whole-episode calls with shuffling stay allowed
     pddpg.rollout_episodes(state, buffers, env_states, obs, topo, traffic,
                            jnp.int32(0), 4)
+
+
+# ------------------------------------------------- the lockstep ring write
+def _ring_stack(B, chunk, capacity, precision="f32", per_replica=False):
+    """Tiny flagship stack whose rings hold ``capacity`` rows per replica,
+    with traffic for ``chunk`` control steps."""
+    import dataclasses
+
+    import __graft_entry__ as ge
+    from gsc_tpu.sim.traffic import generate_traffic
+    from gsc_tpu.topology import stack_topologies
+    from gsc_tpu.topology.compiler import compile_topology
+    from gsc_tpu.topology.synthetic import line, triangle
+
+    env, agent, topo, _ = ge._flagship(max_nodes=8, max_edges=8,
+                                       episode_steps=chunk, max_flows=32,
+                                       gen_traffic=False)
+    agent = dataclasses.replace(agent, precision=precision,
+                                mem_limit=capacity * B)
+    env.agent = agent
+    if per_replica:
+        nets = [compile_topology(spec, max_nodes=8, max_edges=8)
+                for spec in ([triangle(), line(4)] * B)[:B]]
+        nets = [n.replace(topo_id=jnp.int32(k)) for k, n in enumerate(nets)]
+        topo = stack_topologies(nets)
+    else:
+        nets = [topo] * B
+    traffic = jax.tree_util.tree_map(
+        lambda *xs: jnp.stack(xs),
+        *[generate_traffic(env.sim_cfg, env.service, t, chunk, seed=k)
+          for k, t in enumerate(nets)])
+    pddpg = ParallelDDPG(env, agent, num_replicas=B,
+                         per_replica_topology=per_replica)
+    env_states, obs = pddpg.reset_all(jax.random.PRNGKey(0), topo, traffic)
+    one_obs = jax.tree_util.tree_map(lambda x: x[0], obs)
+    state = pddpg.init(jax.random.PRNGKey(1), one_obs)
+    return pddpg, state, pddpg.init_buffers(one_obs), env_states, obs, \
+        topo, traffic
+
+
+def _rollout_with_vmapped_buffer_add(pddpg, state, buffers, env_states, obs,
+                                     topo, traffic, episode_start_step,
+                                     num_steps):
+    """The replica rollout as it was before the one-slab write: the same
+    key schedule, policy and env step, with ``buffer_add`` INSIDE the vmap
+    over replicas, each replica's ring at its own ``pos`` — the reference
+    the lockstep write is held to.  Returns the rings."""
+    from gsc_tpu.agents.buffer import buffer_add
+    from gsc_tpu.env.actions import action_mask
+    from gsc_tpu.env.permutation import ShuffleOps
+
+    env, ddpg, B = pddpg.env, pddpg.ddpg, pddpg.B
+    _, sub = jax.random.split(state.rng)
+    shuffle = ShuffleOps(pddpg.agent, env.limits)
+    sub, k0 = jax.random.split(sub)
+    perms0 = jax.vmap(shuffle.init_perm)(jax.random.split(k0, B))
+    obs = jax.vmap(shuffle.permute_obs)(obs, perms0)
+
+    def one_step(es, ob, perm, buf, tr, tp, key, i):
+        mask = action_mask(tp.node_mask, env.limits.num_sfcs,
+                           env.limits.max_sfs)
+        action = env.process_action(ddpg.choose_action(
+            state.actor_params, ob, shuffle.step_mask(ob, mask, perm),
+            episode_start_step + i, key))
+        es, next_ob, reward, done, _ = env.step(
+            es, tp, tr, shuffle.env_action(action, perm))
+        next_ob, next_perm = shuffle.advance(
+            jax.random.fold_in(key, 1), next_ob, perm)
+        buf = buffer_add(buf, {
+            "obs": ob, "next_obs": next_ob, "action": action,
+            "reward": reward, "done": done.astype(jnp.float32),
+            "topo_idx": tp.topo_id})
+        return es, next_ob, next_perm, buf
+
+    def step_fn(carry, i):
+        env_states, obs, perms, buffers = carry
+        keys = jax.random.split(jax.random.fold_in(sub, i), B)
+        return jax.vmap(
+            one_step, in_axes=(0, 0, 0, 0, 0, pddpg._t_ax, 0, None))(
+                env_states, obs, perms, buffers, traffic, topo, keys, i), None
+
+    carry, _ = jax.lax.scan(step_fn, (env_states, obs, perms0, buffers),
+                            jnp.arange(num_steps))
+    return carry[3]
+
+
+@pytest.mark.parametrize("case", [
+    dict(id="wraps", B=3, chunk=5, calls=3, capacity=7),
+    dict(id="capacity_one", B=2, chunk=3, calls=1, capacity=1),
+    dict(id="bf16_replay", B=2, chunk=5, calls=2, capacity=7,
+         precision="bf16"),
+    dict(id="per_replica_topology", B=4, chunk=5, calls=2, capacity=7,
+         per_replica=True),
+    dict(id="chunk25", B=2, chunk=25, calls=2, capacity=40),
+    dict(id="chunk50", B=2, chunk=50, calls=1, capacity=40),
+], ids=lambda c: c["id"])
+def test_lockstep_ring_write_equals_vmapped_buffer_add(case):
+    """The ring after rollouts through the one-slab-per-leaf write equals,
+    leaf for leaf and bit for bit, the ring ``jax.vmap(buffer_add)`` gives
+    from the same transitions; ``pos``/``size`` stay ``[B]`` and read
+    ``writes % capacity`` / ``min(writes, capacity)``."""
+    B, chunk, cap = case["B"], case["chunk"], case["capacity"]
+    pddpg, state, buffers, env_states, obs, topo, traffic = _ring_stack(
+        B, chunk, cap, case.get("precision", "f32"),
+        case.get("per_replica", False))
+    assert jax.tree_util.tree_leaves(buffers.data)[0].shape[:2] == (B, cap)
+
+    reference = jax.jit(_rollout_with_vmapped_buffer_add,
+                        static_argnums=(0, 8))
+    got = want = buffers
+    for c in range(case["calls"]):
+        # the same env state, traffic and learner key every call: only
+        # the rings move on
+        start = jnp.int32(c * chunk)
+        got = pddpg.rollout_episodes(state, got, env_states, obs, topo,
+                                     traffic, start, chunk)[1]
+        want = reference(pddpg, state, want, env_states, obs, topo,
+                         traffic, start, chunk)
+
+    writes = chunk * case["calls"]
+    for ring in (got, want):
+        assert ring.pos.shape == ring.size.shape == (B,)
+        assert ring.pos.dtype == ring.size.dtype == jnp.int32
+        np.testing.assert_array_equal(np.asarray(ring.pos), writes % cap)
+        np.testing.assert_array_equal(np.asarray(ring.size),
+                                      min(writes, cap))
+    assert got.shapes == want.shapes == buffers.shapes
+    flat_got = jax.tree_util.tree_leaves_with_path(got.data)
+    flat_want = jax.tree_util.tree_leaves(want.data)
+    assert len(flat_got) == len(flat_want) == 14
+    for (path, g), w in zip(flat_got, flat_want):
+        assert g.dtype == w.dtype and g.shape == w.shape, path
+        np.testing.assert_array_equal(
+            np.asarray(g.astype(jnp.float32)),
+            np.asarray(w.astype(jnp.float32)), err_msg=str(path))
+    if case.get("precision") == "bf16":
+        assert got.data["obs"].nodes.dtype == jnp.bfloat16
+        assert got.data["reward"].dtype == jnp.float32
+    if case.get("per_replica"):
+        # each replica's rows carry its own network's index
+        np.testing.assert_array_equal(
+            np.asarray(got.data["topo_idx"][:, 0]), np.arange(B))
+    # the rings hold something: the last row written is a real transition
+    last = (writes - 1) % cap
+    assert np.asarray(got.data["obs"].node_mask[:, last]).any()
+
+
+def test_replay_write_has_no_loop_over_replicas():
+    """Structure of the compiled ``chunk_step``: under ``replay_write``
+    no ``scatter`` and no ``while`` (the vmapped ``buffer_add`` compiled to
+    a scatter per leaf, on the TPU a loop over the replicas), and the same
+    operations whatever the number of replicas."""
+    from gsc_tpu.analysis.hlo import _walk_ops, scope_stats
+    from gsc_tpu.obs.trace import DEVICE_SCOPES
+
+    counts = {}
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        for B in (4, 16):
+            pddpg, state, buffers, env_states, obs, topo, traffic = \
+                _ring_stack(B, 2, 8)
+            compiled = type(pddpg).chunk_step.lower(
+                pddpg, state, buffers, env_states, obs, topo, traffic,
+                np.int32(0), num_steps=2, learn=True).compile()
+            text = compiled.as_text()
+            ops = [op for _, op, _, path, _ in _walk_ops(text, DEVICE_SCOPES)
+                   if "replay_write" in path]
+            assert ops and "scatter" not in ops
+            # a loop the compiler makes of a scatter is run by a `while`
+            # that carries the scatter's name (no operation of its own
+            # to `_walk_ops`, so looked for in the text)
+            assert not [l for l in text.splitlines()
+                        if "replay_write" in l and " while(" in l]
+            counts[B] = scope_stats(compiled, DEVICE_SCOPES)[
+                "replay_write"]["ops_incl"]
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    assert counts[4] == counts[16] > 0
+
+
+@pytest.mark.parametrize("pos,refused", [
+    ([3, 3, 3, 3], None),
+    ([3, 3, 5, 3], r"1 of 4 replicas .* other than 3: replicas 2 \(pos 5\)"),
+    ([0, 2, 2, 1], r"2 of 4 replicas .* other than 2: replicas 0 \(pos 0\), "
+                   r"3 \(pos 1\)"),
+], ids=["lockstep", "one_off", "two_off"])
+def test_rings_out_of_lockstep_are_refused_with_the_replicas_named(
+        pos, refused):
+    from gsc_tpu.agents.buffer import ReplayBuffer, lockstep_cursor
+
+    rings = ReplayBuffer(data={"x": jnp.zeros((4, 8, 2))},
+                         pos=jnp.asarray(pos, jnp.int32),
+                         size=jnp.asarray(pos, jnp.int32))
+    if refused is None:
+        assert lockstep_cursor(rings) == 3
+    else:
+        with pytest.raises(ValueError, match=refused):
+            lockstep_cursor(rings)
