@@ -12,7 +12,10 @@ Per-substep pipeline (mirroring the reference's per-flow state machine,
 flowsimulator.py:72-128):
  1. release capacities whose hold time elapsed (ring buffers; the analogue of
     the delayed ``return_link_resources`` / ``finish_processing`` SimPy
-    processes, default_forwarder.py:112-125, base_processor.py:103-135)
+    processes, default_forwarder.py:112-125, base_processor.py:103-135);
+    the due row is read and cleared by a mask over the whole ring, so that
+    every ring operation of the substep is elementwise or a contraction
+    and the ring keeps one device layout under ``vmap``
  2. advance HOP/PROC timers; completed PROC flows advance their SFC position
     (base_processor.py:104-107) and re-enter decision; completed hops either
     continue the path, arrive for processing, or depart at egress
@@ -407,12 +410,25 @@ class SimEngine:
         rng, k_proc = jax.random.split(state.rng)
 
         # --- 1. capacity releases ------------------------------------------
+        # Row ``ridx`` of each ring is read and cleared through a mask over
+        # the whole [H, K] array, not by index: under vmap ``ridx`` is a
+        # per-replica vector, and a row-indexed read (gather) and clear
+        # (scatter) make the TPU compiler keep a second, row-contiguous
+        # layout of the ring beside the time-minor one the contractions of
+        # stages 5 and 6 write — two whole-ring layout copies per ring per
+        # substep.  Masked, every ring operation is elementwise or a
+        # contraction, one layout serves the whole loop and the clear
+        # fuses into the contraction.  Same bits: the masked sum has one
+        # non-zero term, and release offsets are clipped to [1, H - 1], so
+        # nothing is ever booked on row ``ridx`` itself.
+        at_r = (jnp.arange(self.H) == ridx)[:, None]               # [H, 1]
+        due_node = jnp.where(at_r, state.rel_node, 0.0).sum(0)     # [N*P]
+        due_edge = jnp.where(at_r, state.rel_edge, 0.0).sum(0)     # [E]
         node_load = jnp.maximum(
-            state.node_load - state.rel_node[ridx].reshape(self.N, self.P),
-            0.0)
-        edge_used = jnp.maximum(state.edge_used - state.rel_edge[ridx], 0.0)
-        rel_node = state.rel_node.at[ridx].set(0.0)
-        rel_edge = state.rel_edge.at[ridx].set(0.0)
+            state.node_load - due_node.reshape(self.N, self.P), 0.0)
+        edge_used = jnp.maximum(state.edge_used - due_edge, 0.0)
+        rel_node = jnp.where(at_r, 0.0, state.rel_node)
+        rel_edge = jnp.where(at_r, 0.0, state.rel_edge)
         # graceful SF removal once drained and unplaced (base_processor.py:115-118)
         sf_available = state.sf_available & (state.placed | (node_load > _EPS))
 

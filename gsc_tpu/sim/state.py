@@ -219,10 +219,14 @@ class SimState:
     schedule: jnp.ndarray     # [N,C,S,N] f32 current scheduling weights
     edge_used: jnp.ndarray    # [E] f32 in-flight dr per undirected edge
     # capacity release ring buffers, indexed by substep mod horizon
-    rel_node: jnp.ndarray     # [H,N*P] f32 — flat trailing dim: a ragged
-                              # [N,P] tail makes XLA layout-copy the whole
-                              # ring twice per substep on TPU (~25% of the
-                              # measured substep wall at B=512)
+    # The substep touches a ring only elementwise and by contraction
+    # over the whole [H, K] array (engine stage 1 reads and clears row
+    # ``ridx`` through a mask): a per-replica row index under vmap forces a
+    # row-contiguous device layout that the release contractions do not
+    # want, i.e. two layout copies of the whole ring per substep.  Storing
+    # the rings transposed ([K, H]) compiles to the same layouts.
+    rel_node: jnp.ndarray     # [H,N*P] f32 — flat trailing dim ([N,P]
+                              # flattened), one contraction axis
     rel_edge: jnp.ndarray     # [H,E] f32
     metrics: SimMetrics
     rng: jnp.ndarray          # PRNG key

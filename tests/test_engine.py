@@ -285,3 +285,159 @@ def test_onehot_helpers_match_native_indexing():
                                   np.asarray(v)[np.asarray(perm)])
     back = jnp.dot(sorted_v, pm, precision=jax.lax.Precision.HIGHEST)
     np.testing.assert_array_equal(np.asarray(back), np.asarray(v))
+
+
+# ------------------------------------------------------------ release rings
+# The line scenario's timeline is fixed (test_single_flow_timeline): a flow
+# arriving at substep g0 takes link 0 at g0 and is processed at node 1 by
+# SF j from g0 + 3 + 5 j, so the capacity-release rings can be stated
+# outright — row g mod H is released and cleared, a hold of `off` substeps
+# is booked at row (g + off) mod H — and the engine held to that statement
+# bit for bit while the row index wraps around the horizon.
+RING_SCENARIOS = {
+    # 43 ms flows: a dozen rows live at once, every release inside H
+    "holds_inside_horizon": dict(flow_dr_mean=0.7, flow_size_shape=0.0301),
+    # 300 ms flows: every hold clips to H - 1, the row just behind ridx
+    "holds_clipped_to_horizon": dict(flow_dr_mean=0.7, flow_size_shape=0.21),
+}
+RING_INTERVALS = 3          # 300 substeps > H = 256: the index wraps
+
+
+def ring_statement(traffic, horizon, num_nodes, num_sfs, num_edges,
+                   checkpoints):
+    """The four arrays after each substep count in ``checkpoints``, in
+    plain numpy float32 (dt = 1, link delay 3, processing delay 5)."""
+    f32 = np.float32
+    link, node = 0, 1          # every flow crosses link 0 to node 1
+    edge_book, node_book = {}, {}
+    arr = zip(np.asarray(traffic.arr_time), np.asarray(traffic.arr_dr),
+              np.asarray(traffic.arr_duration))
+    for t0, dr, dur in arr:
+        if not np.isfinite(t0):
+            continue
+        g0 = int(round(float(t0)))
+        off = int(np.clip(np.ceil(3.0 + dur), 1, horizon - 1))
+        edge_book.setdefault(g0, []).append((link, f32(dr), off))
+        off = int(np.clip(np.ceil(5.0 + dur), 1, horizon - 1))
+        for sf in range(num_sfs):
+            node_book.setdefault(g0 + 3 + 5 * sf, []).append(
+                (node * num_sfs + sf, f32(dr), off))
+    node_load = np.zeros(num_nodes * num_sfs, f32)
+    edge_used = np.zeros(num_edges, f32)
+    rel_node = np.zeros((horizon, num_nodes * num_sfs), f32)
+    rel_edge = np.zeros((horizon, num_edges), f32)
+    out = {}
+    for g in range(max(checkpoints)):
+        row = g % horizon
+        node_load = np.maximum(node_load - rel_node[row], f32(0))
+        edge_used = np.maximum(edge_used - rel_edge[row], f32(0))
+        rel_node[row] = 0
+        rel_edge[row] = 0
+        for held, rel, book in ((node_load, rel_node, node_book),
+                                (edge_used, rel_edge, edge_book)):
+            for cell, dr, off in book.get(g, ()):
+                held[cell] += dr
+                rel[(g + off) % horizon, cell] += dr
+        if g + 1 in checkpoints:
+            out[g + 1] = dict(
+                node_load=node_load.reshape(num_nodes, num_sfs).copy(),
+                edge_used=edge_used.copy(), rel_node=rel_node.copy(),
+                rel_edge=rel_edge.copy())
+    return out
+
+
+@pytest.mark.parametrize("scenario", sorted(RING_SCENARIOS))
+@pytest.mark.parametrize("mode", ["unbatched", "vmap_b4", "vmap_own_ridx"])
+def test_release_rings_match_plain_statement(base, scenario, mode):
+    """``rel_node``, ``rel_edge``, ``node_load`` and ``edge_used`` equal
+    the plain statement bit for bit after every interval of a run that
+    wraps the ring index — alone, under ``jax.vmap``, and under
+    ``jax.vmap`` over replicas whose clocks differ by whole intervals, so
+    that each replica reads and clears a row of its own."""
+    service, limits = base
+    cfg = make_cfg(**RING_SCENARIOS[scenario])
+    topo = line_topo(node_cap=100.0)
+    engine = SimEngine(service, cfg, limits)
+    assert engine.H < RING_INTERVALS * engine.substeps
+    ahead = {"unbatched": (0,), "vmap_b4": (0,) * 4,
+             "vmap_own_ridx": (0, 1, 2, 3)}[mode]   # intervals run before
+    traffic = generate_traffic(cfg, service, topo,
+                               episode_steps=RING_INTERVALS + max(ahead),
+                               seed=0)
+    sched = schedule_all_to(limits, 1)
+    place = placement_at(limits, [(1, 0), (1, 1), (1, 2)])
+    want = ring_statement(
+        traffic, engine.H, N, limits.max_sfs, E,
+        {engine.substeps * k
+         for k in range(1, RING_INTERVALS + max(ahead) + 1)})
+
+    def interval(state):
+        return engine.apply.__wrapped__(engine, state, topo, traffic, sched,
+                                        place)[0]
+
+    def check(state, intervals_done):
+        for name, ref in want[engine.substeps * intervals_done].items():
+            np.testing.assert_array_equal(
+                np.asarray(getattr(state, name)), ref,
+                err_msg=f"{name} after {intervals_done} intervals")
+
+    starts = []
+    for k in ahead:
+        state = engine.init(jax.random.PRNGKey(0), topo)
+        for _ in range(k):
+            state = engine.apply(state, topo, traffic, sched, place)[0]
+        starts.append(state)
+    if mode == "unbatched":
+        state = starts[0]
+        for k in range(1, RING_INTERVALS + 1):
+            state = engine.apply(state, topo, traffic, sched, place)[0]
+            check(state, k)
+        return
+    states = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *starts)
+    step = jax.jit(jax.vmap(interval))
+    for k in range(1, RING_INTERVALS + 1):
+        states = step(states)
+        for b, k0 in enumerate(ahead):
+            check(jax.tree_util.tree_map(lambda x: x[b], states), k0 + k)
+
+
+def test_vmapped_substep_keeps_rings_whole(base):
+    """Under ``jax.vmap`` the ring index is a per-replica vector; the
+    substep must still touch a ring only through elementwise operations
+    and contractions over the whole array (a row-indexed read or write
+    forces a second, row-contiguous layout of it on the TPU), and trace
+    to the same primitives whatever the number of replicas."""
+    service, limits = base
+    cfg = make_cfg()
+    topo = line_topo()
+    engine = SimEngine(service, cfg, limits)
+    traffic = generate_traffic(cfg, service, topo, episode_steps=2, seed=0)
+    rings = {(engine.H, N * limits.max_sfs), (engine.H, E)}
+    indexed = ("scatter", "scatter-add", "dynamic_update_slice",
+               "dynamic_slice", "gather")
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from walk(sub)
+
+    def primitives(replicas):
+        one = engine.init(jax.random.PRNGKey(0), topo)
+        states = jax.tree_util.tree_map(
+            lambda x: jnp.stack([x] * replicas), one)
+        jaxpr = jax.make_jaxpr(jax.vmap(
+            lambda s: engine._substep_xla(s, topo, traffic,
+                                          traffic.node_cap[0])))(states)
+        names = set()
+        for eqn in walk(jaxpr.jaxpr):
+            names.add(eqn.primitive.name)
+            if eqn.primitive.name in indexed:
+                shapes = {tuple(v.aval.shape[1:]) for v in eqn.invars
+                          if hasattr(v.aval, "shape")}
+                assert not shapes & rings, (
+                    f"{eqn.primitive.name} on a release ring at B = "
+                    f"{replicas}: {eqn}")
+        return names
+
+    assert primitives(4) == primitives(16)

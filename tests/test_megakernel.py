@@ -300,9 +300,16 @@ def test_scan_unroll_bit_identical(impl):
 # --------------------------------------------------------- fusion budget
 # Pinned compiled-HLO fusion count of the flagship-interval engine.apply
 # (abc service, Abilene limits 24/37, M=128, 100 substeps) on the CPU
-# backend, jaxlib 0.9.0: xla 273, pallas 270 (re-measured and re-pinned in
-# PR 21; the same programs counted 191 / 185 under the previous jaxlib — the
-# compiler's fusion decisions moved, the engine did not).  The budget adds
+# backend, jaxlib 0.9.0: xla 276, pallas 270 (xla 273 when re-measured
+# and re-pinned in PR 21; the same programs counted 191 / 185 under the
+# previous jaxlib — the compiler's fusion decisions moved, the engine did
+# not).  PR 29 re-pinned 273 -> 276: stage 1 of the XLA substep reads and
+# clears the release rings' due row through a mask over the whole ring
+# instead of by index, which the CPU compiler counts as three more
+# fusions of this unbatched program — while the TPU's vmapped program
+# loses four whole-ring layout copies and two scatters per substep.  The
+# pallas twin keeps the indexed form (its bit-exact parity cases above
+# are the old form against the new), so its count stays.  The budget adds
 # NO headroom on purpose — a 281->294-style regression is ~+13, so any
 # slack would swallow exactly the class of change this gate exists to
 # catch.  If a toolchain upgrade moves the count, re-measure and re-pin in
@@ -315,7 +322,7 @@ def test_scan_unroll_bit_identical(impl):
 # pallas < xla half protects only the megakernel's CPU role: TPU Pallas
 # refused to lower it (PR 21), so it never runs on a chip, and whether
 # the twin survives at all is ROADMAP Queue 3 item 3.
-XLA_FUSION_BUDGET = 273
+XLA_FUSION_BUDGET = 276
 
 
 def _flagship_interval_compiled(impl):
