@@ -197,8 +197,7 @@ class Trainer:
         with phase_span("drain", timer, hub):
             # force the episode's device work complete BEFORE reading the
             # wall clock: sps must divide by time that includes the
-            # episode's compute (bench.py's bank() contract), not the
-            # async-dispatch return time
+            # episode's compute, not the async-dispatch return time
             jax.block_until_ready((stats, learn_metrics, trunc_dev))
             # learn-ledger extras are non-scalar (TD segment vectors,
             # layer-norm dicts): split them off before the scalar row

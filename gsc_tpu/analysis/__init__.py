@@ -20,8 +20,8 @@ telemetry contracts (PR 2), the precision-policy dtype discipline (PR 3)
 - :mod:`~gsc_tpu.analysis.hlo` — compiled-HLO structure metrics:
   ``count_fusions`` (the op-count perf proxy that gates substep changes
   — the rejected bit-exact-but-281->294-fusions scatter-merge is the
-  case it encodes), shared by ``tools/profile_substep.py``,
-  ``tools/lever_sweep.py`` and the tier-1 fusion-budget test.
+  case it encodes), read by the cost ledger (``obs/perf.py``) and the
+  tier-1 fusion-budget test (``tests/test_engine.py``).
 - :mod:`~gsc_tpu.analysis.sentinels` — the runtime side:
   :class:`CompileMonitor` (per-entry-point trace/compile counting, wired
   into ``events.jsonl`` as ``compile`` events), ``assert_no_retrace``

@@ -9,11 +9,10 @@ enough: the rejected round-5 scatter-merge was bit-exact yet REGRESSED
 281 -> 294 fusions and lost throughput; a fusion-count check would have
 rejected it before the chip ever saw it.
 
-Consumers: ``tools/profile_substep.py --mfu`` (per-rung roofline rows),
-``tools/lever_sweep.py`` (per-cell rows), and the tier-1 fusion-budget
-regression test (``tests/test_megakernel.py``), which pins the compiled
-flagship-interval ``engine.apply`` count on the CPU backend and asserts
-the pallas megakernel path stays strictly below the XLA path.
+Consumers: the cost ledger (``obs/perf.py``: ``fusions`` and, per
+``jax.named_scope`` layer, ``scopes``) and the tier-1 fusion-budget
+regression test (``tests/test_engine.py``), which pins the compiled
+flagship-interval ``engine.apply`` count on the CPU backend.
 
 Stdlib-only on purpose (the gsc-lint convention for analysis/): the
 argument is an already-compiled jax ``Compiled`` object (or its
@@ -54,7 +53,7 @@ def count_ops(compiled_or_text, op: str) -> int:
     """Occurrences of an HLO op (e.g. ``"while"``, ``"gather"``,
     ``"scatter"``, ``"dot"``) in the compiled executable — the drill-down
     companion to :func:`count_fusions` (a CPU scatter lowers to a serial
-    ``while``, a fact the megakernel work keeps re-learning)."""
+    ``while``)."""
     return hlo_text(compiled_or_text).count(f" {op}(")
 
 

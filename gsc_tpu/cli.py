@@ -157,7 +157,7 @@ def init_configs(out: str):
 
 def _build(agent_config, simulator_config, service, scheduler, seed,
            max_nodes, max_edges, resource_functions_path=None,
-           precision=None, substep_impl=None, unroll=None, topo_mix=None):
+           precision=None, unroll=None, topo_mix=None):
     from .config.loader import load_agent, load_scheduler, load_service, load_sim
     from .config.schema import EnvLimits
     from .env.driver import EpisodeDriver
@@ -166,12 +166,10 @@ def _build(agent_config, simulator_config, service, scheduler, seed,
     # --precision overrides the agent yaml's (or default f32) policy
     agent = load_agent(agent_config,
                        **({"precision": precision} if precision else {}))
-    # --substep-impl / --unroll override the simulator yaml's engine knobs
-    # (`is not None`, not truthiness: an explicit --unroll 0 must reach
-    # SimConfig validation and ERROR, never silently keep the yaml value)
+    # --unroll overrides the simulator yaml's scan_unroll (`is not None`,
+    # not truthiness: an explicit --unroll 0 must reach SimConfig
+    # validation and ERROR, never silently keep the yaml value)
     sim_overrides = {}
-    if substep_impl is not None:
-        sim_overrides["substep_impl"] = substep_impl
     if unroll is not None:
         sim_overrides["scan_unroll"] = unroll
     sim_cfg = load_sim(simulator_config, **sim_overrides)
@@ -298,22 +296,11 @@ def _build(agent_config, simulator_config, service, scheduler, seed,
                    "params/optimizer/TD targets — ~2x MXU throughput, "
                    "half the replay HBM).  Unset = the agent yaml's "
                    "'precision' key (default f32)")
-@click.option("--substep-impl", type=click.Choice(["xla", "pallas"]),
-              default=None,
-              help="simulator substep engine override: xla (default; the "
-                   "hand-fused one-hot pipeline) or pallas (the substep "
-                   "megakernel, ONE kernel invocation per substep — "
-                   "bit-exact vs xla, CPU backend only: TPU Pallas "
-                   "cannot lower it and the engine refuses it there).  "
-                   "Unset = the simulator yaml's 'substep_impl' key "
-                   "(default xla)")
 @click.option("--unroll", type=int, default=None,
               help="substep-scan unroll factor override "
                    "(SimConfig.scan_unroll; trades compile time for less "
-                   "scan overhead on the op-count-bound substep — sweep "
-                   "with tools/lever_sweep.py, then promote the winner "
-                   "here).  Unset = the simulator yaml's 'scan_unroll' "
-                   "key (default 1)")
+                   "scan overhead on the op-count-bound substep).  Unset "
+                   "= the simulator yaml's 'scan_unroll' key (default 1)")
 @click.option("--obs/--no-obs", "obs_enabled", default=True,
               show_default=True,
               help="unified run telemetry: per-episode events.jsonl "
@@ -485,7 +472,7 @@ def train(agent_config, simulator_config, service, scheduler, episodes, seed,
           result_dir, experiment_id, max_nodes, max_edges, tensorboard,
           profile, runs, resume, resource_functions_path, replicas, chunk,
           mesh, partition_rules, topo_mix, pipeline, precision,
-          substep_impl, unroll, obs_enabled, obs_dir, obs_interval,
+          unroll, obs_enabled, obs_dir, obs_interval,
           obs_rotate_mb, obs_series_window, perf_enabled,
           learnobs_enabled, metrics_port,
           watchdog_budget, watchdog_escalate,
@@ -524,8 +511,8 @@ def train(agent_config, simulator_config, service, scheduler, episodes, seed,
         raise click.BadParameter("--metrics-port needs the run observer "
                                  "(drop --no-obs)")
     if unroll is not None and unroll < 1:
-        # same contract as bench.py's --unroll: fail fast with the flag's
-        # name, not a SimConfig traceback from deep inside the run loop
+        # fail fast with the flag's name, not a SimConfig traceback from
+        # deep inside the run loop
         raise click.BadParameter("--unroll must be a positive integer")
     if publish_interval < 1:
         raise click.BadParameter("--publish-interval must be >= 1")
@@ -681,7 +668,6 @@ def train(agent_config, simulator_config, service, scheduler, episodes, seed,
                                     scheduler, run_seed, max_nodes, max_edges,
                                     resource_functions_path,
                                     precision=precision,
-                                    substep_impl=substep_impl,
                                     unroll=unroll, topo_mix=topo_mix)
         # episode-0 topology/traffic memo: mesh_meta and the resume
         # template both need the same deterministic build, and it is
@@ -735,10 +721,9 @@ def train(agent_config, simulator_config, service, scheduler, episodes, seed,
                                 "floor": curriculum_floor}}
                                if curriculum_cfg is not None else {}),
                             "precision": agent.precision,
-                            # the EFFECTIVE engine knobs (yaml or flag),
-                            # read back from the built sim_cfg so the
-                            # recorded values can't drift from what ran
-                            "substep_impl": env.sim_cfg.substep_impl,
+                            # the EFFECTIVE unroll (yaml or flag), read
+                            # back from the built sim_cfg so the recorded
+                            # value can't drift from what ran
                             "unroll": env.sim_cfg.scan_unroll,
                             "result_dir": rdir,
                             "ckpt_interval": ckpt_interval,
@@ -1232,7 +1217,6 @@ def serve(agent_config, simulator_config, service, scheduler, checkpoint,
             "fire_swaps": fire_swaps,
             "trace_sample": trace_sample, "slo_p99_ms": slo_p99_ms,
             "precision": agent.precision,
-            "substep_impl": env.sim_cfg.substep_impl,
             "unroll": env.sim_cfg.scan_unroll,
             "jax_cache_dir": jax_cache_dir,
             "checkpoint": checkpoint, "result_dir": rdir})
@@ -1282,7 +1266,6 @@ def serve(agent_config, simulator_config, service, scheduler, checkpoint,
                 cache=ArtifactCache(cache_dir),
                 fingerprint=checkpoint_fingerprint(checkpoint),
                 precision=agent.precision,
-                substep_impl=env.sim_cfg.substep_impl,
                 graph_mode=agent.graph_mode)
             swap_payload = jax.device_get(state.actor_params)
             if workers == 1:

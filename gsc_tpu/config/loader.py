@@ -110,8 +110,6 @@ def load_sim(path: str, **overrides) -> SimConfig:
                 "admission_iters", "wrr_rank_levels", "scan_unroll"):
         if key in cfg:
             kw[key] = int(cfg[key])
-    if "substep_impl" in cfg:
-        kw["substep_impl"] = str(cfg["substep_impl"])
     if "controller_class" in cfg:
         kw["controller"] = {"DurationController": "duration",
                             "FlowController": "per_flow"}.get(

@@ -131,8 +131,8 @@ class PrecisionPolicy:
 
 
 # Named policies selectable via AgentConfig.precision / `cli train
-# --precision` / `bench.py --precision`.  "f32" is bit-identical to the
-# pre-policy stack; "bf16" is the TPU mixed-precision recipe.
+# --precision`.  "f32" is bit-identical to the pre-policy stack; "bf16" is
+# the TPU mixed-precision recipe.
 PRECISION_POLICIES = {
     "f32": PrecisionPolicy(name="f32"),
     "bf16": PrecisionPolicy(name="bf16", gnn_compute="bfloat16",
@@ -275,15 +275,6 @@ class SimConfig:
     # (and a run_duration/dt divisibility requirement) for less scan
     # overhead on a substep made of many small fusions.
     scan_unroll: int = 1
-    # Substep implementation (mirrors AgentConfig.gnn_impl): "xla" = the
-    # hand-fused one-hot XLA pipeline (default, the reference-parity
-    # workhorse); "pallas" = the substep MEGAKERNEL — the whole
-    # admission/release chain as ONE pallas_call per substep
-    # (gsc_tpu/ops/pallas_substep.py; CPU backend only — SimEngine
-    # refuses it elsewhere; bit-exact vs "xla" by construction and by the
-    # `pytest -m megakernel` suite).
-    # Per-flow control (controller="per_flow") stays on the XLA path.
-    substep_impl: str = "xla"
 
     def __post_init__(self):
         if self.use_states and len(self.states) != 2:
@@ -298,17 +289,6 @@ class SimConfig:
                 "'duration' or 'per_flow'; reference spellings "
                 "DurationController/FlowController are mapped by the "
                 "loader)")
-        if self.substep_impl not in ("xla", "pallas"):
-            raise ValueError(
-                f"unknown substep_impl {self.substep_impl!r} "
-                "(expected 'xla' or 'pallas')")
-        if self.substep_impl == "pallas" and self.controller == "per_flow":
-            # the megakernel covers the batch-control (DurationController)
-            # substep only; per-flow external decisions would silently run
-            # the XLA body anyway — fail fast instead of faking the knob
-            raise ValueError(
-                "substep_impl='pallas' supports only controller='duration' "
-                "(per-flow control runs the XLA substep)")
         if self.scan_unroll < 1:
             raise ValueError("scan_unroll must be >= 1")
 

@@ -2,11 +2,11 @@
 
 The ``"DPxMP"`` mesh grammar and the named-rulebook vocabulary are
 spoken by surfaces on BOTH sides of the jax boundary: the CLI and
-``parallel/partition.py`` import jax anyway, but ``bench.py``'s argument
-parsing and the jax-free ``tools/dryrun_multihost.py`` launcher validate
-a spec before any backend exists.  This module is the one shared
-definition both sides import — ``import gsc_tpu.meshspec`` executes only
-the package docstring, never a jax import.
+``parallel/partition.py`` import jax anyway, but the jax-free
+``tools/dryrun_multihost.py`` launcher validates a spec before any
+backend exists.  This module is the one shared definition both sides
+import — ``import gsc_tpu.meshspec`` executes only the package
+docstring, never a jax import.
 
 Canonical spellings, enforced here so cross-artifact grouping never
 splits one value into two strings:

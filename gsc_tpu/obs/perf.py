@@ -11,8 +11,8 @@ makes that evidence a per-run artifact: every watched jitted entry point
 - XLA's own cost model (``compiled.cost_analysis()``): FLOPs and bytes
   accessed per call;
 - HLO structure (:mod:`gsc_tpu.analysis.hlo`): fusion count — the
-  op-count perf proxy the megakernel campaign gates on — plus a small
-  op histogram (while/dot/scatter/gather), the device operations per
+  op-count perf proxy — plus a small op histogram
+  (while/dot/scatter/gather), the device operations per
   ``jax.named_scope`` layer (``scopes``: ops, fusions, copies, result
   bytes for each of ``obs.trace.DEVICE_SCOPES``) and the collective-op stats
   (all-reduce/all-gather/reduce-scatter count + payload bytes) that
@@ -66,7 +66,7 @@ PERF_SCHEMA_VERSION = 1
 # ``jax.devices()[0].device_kind`` prints.  One row per part somebody has
 # actually run this repo on, each with its source; a device that is not
 # here gets no MFU/roofline (``device_peaks`` returns None) — never a
-# default.  tools/profile_substep.py reads the same table.
+# default.
 DEVICE_PEAKS = {
     # one TPU v5e chip reports itself as "TPU v5 lite" (chip run, PR 21)
     "TPU v5 lite": {
@@ -108,7 +108,7 @@ def resolve_lowerable(owner, name: str):
     the owner passed explicitly (``donate=False``, where the class jit
     IS the dispatched program, and the sharded-plan wrappers, where the
     unsharded class jit is the carving-comparable stand-in).  The single
-    resolver behind Trainer and bench.py capture sites, so the
+    resolver behind the Trainer's capture sites, so the
     donated-wrapper shape is interpreted in exactly one place."""
     fn = owner.__dict__.get(name)
     inner = fn

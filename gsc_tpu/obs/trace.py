@@ -355,7 +355,7 @@ def build_trace(events: List[Dict]) -> Dict:
             push("i", "run_start", ep_tid, ts_us, s="t",
                  args={k: v for k, v in ev.items()
                        if k in ("run", "episodes", "replicas", "pipeline",
-                                "precision", "substep_impl", "mesh")})
+                                "precision", "mesh")})
         elif kind == "episode_spans":
             for sp in (ev.get("spans") or []):
                 root = sp.get("name") == "episode"

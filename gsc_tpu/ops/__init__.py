@@ -2,8 +2,6 @@
 from .gat import (LEAKY_SLOPE, NEG_INF, dense_adj, gatv2_dense,
                   gatv2_segment, project)
 from .pallas_gat import gatv2_pallas
-from .pallas_substep import substep_megakernel
 
 __all__ = ["LEAKY_SLOPE", "NEG_INF", "dense_adj", "gatv2_dense",
-           "gatv2_segment", "gatv2_pallas", "project",
-           "substep_megakernel"]
+           "gatv2_segment", "gatv2_pallas", "project"]

@@ -7,7 +7,7 @@ Three things live here and nowhere else:
   sets another directory; where it is not, the cache is
   ``<checkout>/.jax_cache`` — a fixed path (the path is part of the cache
   key, so a directory that moves never hits).  ``cli train|infer|serve``,
-  ``bench.py``, ``chip_smoke.py``, ``tests/conftest.py`` and the tools all
+  ``chip_smoke.py``, ``tests/conftest.py`` and the tools all
   call it, so the cache is ON by default on the product path;
 - **the device identity** every result carries (:func:`device_summary`):
   platform, ``device_kind`` and device count exactly as JAX reports them;

@@ -7,7 +7,7 @@ plain-JSON dict of everything the compiled bytes depend on:
 
 - checkpoint fingerprint (content checksum of the weights),
 - padded obs leaf shapes/dtypes + the batch bucket,
-- precision policy name and the simulator's ``substep_impl`` knob,
+- precision policy name, graph mode and the GAT implementation,
 - jax/jaxlib versions and the lowering platform,
 - the artifact format version.
 
@@ -41,8 +41,8 @@ ARTIFACT_FORMAT = 1
 
 
 def cache_material(*, fingerprint: str, template, batch: int,
-                   precision: str, substep_impl: str,
-                   graph_mode: bool, gnn_impl: str = "xla") -> Dict:
+                   precision: str, graph_mode: bool,
+                   gnn_impl: str = "xla") -> Dict:
     """The canonical key material for one bucket's artifact (plain JSON;
     ``template`` is a :class:`~gsc_tpu.serve.policy.ObsTemplate`).
     ``gnn_impl`` matters: the actor is lowered THROUGH the configured GAT
@@ -58,7 +58,6 @@ def cache_material(*, fingerprint: str, template, batch: int,
         "obs_leaf_dtypes": list(template.leaf_dtypes),
         "batch": int(batch),
         "precision": precision,
-        "substep_impl": substep_impl,
         "graph_mode": bool(graph_mode),
         "gnn_impl": gnn_impl,
         "jax": jax.__version__,

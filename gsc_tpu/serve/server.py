@@ -78,8 +78,7 @@ class PolicyServer:
                  deadline_ms: float = 5.0,
                  cache: Optional[ArtifactCache] = None,
                  fingerprint: str = "none",
-                 precision: str = "f32", substep_impl: str = "xla",
-                 graph_mode: bool = True,
+                 precision: str = "f32", graph_mode: bool = True,
                  hub=None, stats_interval: int = 50,
                  max_queue: int = 4096, perf=None,
                  tracer=None, slo=None, slo_path: Optional[str] = None,
@@ -100,7 +99,6 @@ class PolicyServer:
         self.cache = cache
         self.fingerprint = fingerprint
         self.precision = precision
-        self.substep_impl = substep_impl
         self.graph_mode = graph_mode
         self.hub = hub
         # device-cost ledger (obs.perf.CostLedger): with one, every bucket
@@ -212,8 +210,7 @@ class PolicyServer:
         t0 = time.perf_counter()
         material = cache_material(
             fingerprint=self.fingerprint, template=self.policy.template,
-            batch=b, precision=self.precision,
-            substep_impl=self.substep_impl, graph_mode=self.graph_mode,
+            batch=b, precision=self.precision, graph_mode=self.graph_mode,
             # the actor is lowered through the configured GAT impl — a
             # module artifact compiled under one impl must miss under the
             # other (their numerics are only interpret-mode-equal)

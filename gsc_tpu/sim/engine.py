@@ -240,16 +240,7 @@ class SimEngine:
         max_hold = (self.H - 1) * self.dt
         if cfg.run_duration > max_hold:
             raise ValueError("release_horizon must cover at least one run_duration")
-        if cfg.substep_impl == "pallas" and jax.default_backend() != "cpu":
-            # tried on the chip (PR 21, TPU v5 lite, jax 0.9.0): Mosaic
-            # refuses the kernel body at its first dynamic gather
-            raise ValueError(
-                "substep_impl='pallas' runs on the CPU backend only — "
-                "Pallas cannot lower its sort and dynamic gathers for "
-                f"{jax.default_backend()!r}; use substep_impl='xla'")
-        # static deterministic-processing-delay flag, shared by both
-        # substep impls (the pallas path draws its noise OUTSIDE the
-        # kernel with the same key, so the rng stream is impl-invariant)
+        # static deterministic-processing-delay flag (read in stage 6)
         self._det_proc = float(np.max(self.tables.proc_std)) == 0.0
 
     # ------------------------------------------------------------------ init
@@ -360,46 +351,13 @@ class SimEngine:
         return state, state.metrics
 
     # ---------------------------------------------------------------- substep
+    @jax.named_scope("sim_substep")
     def _substep(self, state: SimState, topo: Topology,
                  traffic: TrafficSchedule, cap_now: jnp.ndarray,
                  ext_decisions: jnp.ndarray | None = None) -> SimState:
-        """Dispatch on ``cfg.substep_impl``: "xla" = the hand-fused
-        one-hot pipeline below; "pallas" = the substep megakernel (ONE
-        pallas_call per substep, ops/pallas_substep.py — bit-exact vs
-        the XLA body, asserted by ``pytest -m megakernel``).  Per-flow
-        external decisions always run the XLA body (SimConfig rejects
-        the pallas impl for controller="per_flow")."""
-        with jax.named_scope("sim_substep"):
-            if self.cfg.substep_impl == "pallas" and ext_decisions is None:
-                return self._substep_pallas(state, topo, traffic, cap_now)
-            return self._substep_xla(state, topo, traffic, cap_now,
-                                     ext_decisions)
-
-    def _substep_pallas(self, state: SimState, topo: Topology,
-                        traffic: TrafficSchedule,
-                        cap_now: jnp.ndarray) -> SimState:
-        """Megakernel path: advance the rng stream EXACTLY as the XLA
-        body does (split; stochastic configs draw the [M] processing-
-        delay normals from the same k_proc), then run the whole substep
-        as one kernel invocation."""
-        # lazy import: the kernel module reuses this module's one-hot
-        # helpers, so the dependency edge must point pallas_substep ->
-        # engine (resolved once at first trace, never per step)
-        from ..ops.pallas_substep import substep_megakernel
-
-        rng, k_proc = jax.random.split(state.rng)
-        if self._det_proc:
-            noise = jnp.zeros((self.M,), jnp.float32)
-        else:
-            noise = jax.random.normal(k_proc, (self.M,))
-        state = state.replace(rng=rng)
-        return substep_megakernel(state, topo, traffic, cap_now, noise,
-                                  tables=self.tables, cfg=self.cfg,
-                                  limits=self.limits, det=self._det_proc)
-
-    def _substep_xla(self, state: SimState, topo: Topology,
-                     traffic: TrafficSchedule, cap_now: jnp.ndarray,
-                     ext_decisions: jnp.ndarray | None = None) -> SimState:
+        """One fixed substep of every flow slot: the hand-fused one-hot
+        pipeline of the module docstring, stages 1-7.  ``ext_decisions``
+        (per-flow control) replaces stage 4's weighted-round-robin choice."""
         F = state.flows
         m = state.metrics
         dt = self.dt

@@ -14,8 +14,7 @@ identical shapes forever), and an effectively unbounded scenario
 distribution instead of a fixed mix string.
 
 Mix grammar (the ``factory:`` extension of the PR 9 mix string,
-``EpisodeDriver(topo_mix=...)`` / ``cli train --topo-mix`` /
-``bench.py --topo-mix``)::
+``EpisodeDriver(topo_mix=...)`` / ``cli train --topo-mix``)::
 
     factory  := "factory:" families ["+shapes"] ["~faults"]
     families := "all" | family ("-" family)*

@@ -11,8 +11,7 @@ sync: node faults ride the per-interval ``TrafficSchedule.node_cap``
 table, link faults the per-interval ``edge_cap_t`` table the engine
 row-selects at each interval start.
 
-Mix grammar (``EpisodeDriver(topo_mix=...)``, ``cli train --topo-mix``,
-``bench.py --topo-mix``)::
+Mix grammar (``EpisodeDriver(topo_mix=...)``, ``cli train --topo-mix``)::
 
     mix    := entry ("," entry)*
     entry  := "schedule" | name["+" shape]["~" faults][":" seed]

@@ -94,12 +94,11 @@ def test_r4_f32_gates_are_exempt():
 
 
 def test_whole_tree_is_lint_clean_under_baseline():
-    """The acceptance gate: gsc_tpu/ tools/ bench.py with the committed
+    """The acceptance gate: gsc_tpu/ tools/ with the committed
     baseline has zero unsuppressed findings, and every baseline entry
     still matches something (no stale suppressions)."""
     result = lint_paths(
-        [os.path.join(REPO, "gsc_tpu"), os.path.join(REPO, "tools"),
-         os.path.join(REPO, "bench.py")],
+        [os.path.join(REPO, "gsc_tpu"), os.path.join(REPO, "tools")],
         baseline_path=os.path.join(REPO, "tools",
                                    "gsc_lint_baseline.json"),
         root=REPO)
@@ -122,7 +121,7 @@ def test_cli_exit_codes():
         assert p.returncode == 1, (name, p.stdout, p.stderr)
     p = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools", "gsc_lint.py"),
-         "gsc_tpu/", "tools/", "bench.py"],
+         "gsc_tpu/", "tools/"],
         capture_output=True, text=True, env=env, cwd=REPO)
     assert p.returncode == 0, (p.stdout, p.stderr)
 

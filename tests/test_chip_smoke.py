@@ -1,5 +1,5 @@
-"""chip_smoke.py and bench.py refuse a CPU before compiling anything; the
-smoke's phase functions rehearse on the CPU at tiny sizes (``-m slow``)."""
+"""chip_smoke.py refuses a CPU before compiling anything; the smoke's
+phase functions rehearse on the CPU at tiny sizes (``-m slow``)."""
 import os
 import sys
 
@@ -8,12 +8,11 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-import bench  # noqa: E402
 import chip_smoke  # noqa: E402
 
 
 def _no_compile(monkeypatch, module):
-    """Everything either entry point compiles comes after its
+    """Everything the entry point compiles comes after its
     enable_compile_cache() call — reaching it on a CPU fails the test."""
     def reached():
         raise AssertionError("went past the platform check on a CPU")
@@ -27,18 +26,6 @@ def test_chip_smoke_refuses_cpu_before_any_compile(monkeypatch, capsys):
     assert exc.value.code not in (0, None)
     assert "'cpu'" in str(exc.value.code)       # names what it found
     assert '"ok"' not in capsys.readouterr().out   # and prints no result
-
-
-def test_bench_refuses_cpu_and_is_one_process(monkeypatch, capsys):
-    _no_compile(monkeypatch, bench)
-    with pytest.raises(SystemExit) as exc:
-        bench.main([])
-    assert exc.value.code not in (0, None)
-    assert "'cpu'" in str(exc.value.code)
-    assert "env_steps_per_sec" not in capsys.readouterr().out
-    with open(os.path.join(REPO, "bench.py")) as f:
-        src = f.read()
-    assert "subprocess" not in src and "multiprocessing" not in src
 
 
 @pytest.mark.slow

@@ -30,11 +30,9 @@ def _package_source():
                 with open(os.path.join(root, f)) as fh:
                     chunks.append(fh.read())
     # the CLI and graft entry also consume config fields
-    for extra in ("../__graft_entry__.py", "../bench.py"):
-        p = os.path.normpath(os.path.join(PKG, extra))
-        if os.path.exists(p):
-            with open(p) as fh:
-                chunks.append(fh.read())
+    with open(os.path.normpath(
+            os.path.join(PKG, "../__graft_entry__.py"))) as fh:
+        chunks.append(fh.read())
     with open(os.path.join(PKG, "cli.py")) as fh:
         chunks.append(fh.read())
     return "\n".join(chunks)

@@ -6,6 +6,10 @@ simulator's *semantics* — per-flow timelines, drop classification, WRR splits 
 on scenarios small enough to verify by hand against the reference's rules
 (coordsim/simulation/flowsimulator.py:72-128 and its components).
 """
+import os
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -20,12 +24,16 @@ from gsc_tpu.config.schema import (
 from gsc_tpu.sim import SimEngine, generate_traffic
 from gsc_tpu.topology.compiler import NetworkSpec, compile_topology
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+REFERENCE = os.environ.get("GSC_REFERENCE_DIR", "/root/reference")
+
 N, E = 8, 8  # small padded dims for fast tests
 
 
-def make_service():
+def make_service(std=0.0, startup=0.0):
     sf = lambda n: ServiceFunction(name=n, processing_delay_mean=5.0,
-                                   processing_delay_stdev=0.0)
+                                   processing_delay_stdev=std,
+                                   startup_delay=startup)
     return ServiceConfig(sfc_list={"sfc_1": ("a", "b", "c")},
                          sf_list={n: sf(n) for n in "abc"})
 
@@ -40,6 +48,16 @@ def line_topo(node_cap=10.0, link_cap=100.0, link_delay=3.0):
     return compile_topology(spec, max_nodes=N, max_edges=E)
 
 
+def triangle_topo():
+    """0(Ingress) adjacent to 1 and 2, so a 50/50 row can split."""
+    spec = NetworkSpec(
+        node_caps=[20.0, 20.0, 20.0],
+        node_types=["Ingress", "Normal", "Normal"],
+        edges=[(0, 1, 100.0, 1.0), (0, 2, 100.0, 1.0), (1, 2, 100.0, 1.0)],
+    )
+    return compile_topology(spec, max_nodes=N, max_edges=E)
+
+
 def make_cfg(**kw):
     kw.setdefault("ttl_choices", (100.0,))
     return SimConfig(**kw)
@@ -50,6 +68,22 @@ def schedule_all_to(limits, dst):
     sched = np.zeros(limits.scheduling_shape, np.float32)
     sched[:, :, :, dst] = 1.0
     return jnp.asarray(sched)
+
+
+def schedule_wrr_split(limits):
+    """SF a from the ingress splits 50/50 over nodes 1 / 2; later SFs
+    stay where they are."""
+    sched = np.zeros(limits.scheduling_shape, np.float32)
+    sched[0, 0, 0, 1] = 0.5
+    sched[0, 0, 0, 2] = 0.5
+    for n in (1, 2):
+        sched[n, 0, 1, n] = 1.0
+        sched[n, 0, 2, n] = 1.0
+    return jnp.asarray(sched)
+
+
+ALL_AT_1 = [(1, 0), (1, 1), (1, 2)]
+ALL_AT_1_AND_2 = ALL_AT_1 + [(2, 0), (2, 1), (2, 2)]
 
 
 def placement_at(limits, nodes_sfs):
@@ -185,24 +219,12 @@ def test_wrr_split(base):
     (default_decision_maker.py:42-66)."""
     service, limits = base
     cfg = make_cfg()
-    # triangle so both destinations are adjacent to the ingress
-    spec = NetworkSpec(
-        node_caps=[20.0, 20.0, 20.0],
-        node_types=["Ingress", "Normal", "Normal"],
-        edges=[(0, 1, 100.0, 1.0), (0, 2, 100.0, 1.0), (1, 2, 100.0, 1.0)],
-    )
-    topo = compile_topology(spec, max_nodes=N, max_edges=E)
+    topo = triangle_topo()
     engine = SimEngine(service, cfg, limits)
     traffic = generate_traffic(cfg, service, topo, episode_steps=2, seed=0)
-    sched = np.zeros(limits.scheduling_shape, np.float32)
-    sched[0, 0, 0, 1] = 0.5   # sf a from ingress: split 1 / 2
-    sched[0, 0, 0, 2] = 0.5
-    for n in (1, 2):          # later SFs stay put
-        sched[n, 0, 1, n] = 1.0
-        sched[n, 0, 2, n] = 1.0
-    place = placement_at(limits, [(1, 0), (1, 1), (1, 2),
-                                  (2, 0), (2, 1), (2, 2)])
-    _, out = run_intervals(engine, topo, traffic, jnp.asarray(sched), place, 1)
+    sched = schedule_wrr_split(limits)
+    place = placement_at(limits, ALL_AT_1_AND_2)
+    _, out = run_intervals(engine, topo, traffic, sched, place, 1)
     (m,) = out
     counts = np.asarray(m.run_flow_counts)[0, 0, 0]
     assert counts[1] == 5 and counts[2] == 5
@@ -427,8 +449,8 @@ def test_vmapped_substep_keeps_rings_whole(base):
         states = jax.tree_util.tree_map(
             lambda x: jnp.stack([x] * replicas), one)
         jaxpr = jax.make_jaxpr(jax.vmap(
-            lambda s: engine._substep_xla(s, topo, traffic,
-                                          traffic.node_cap[0])))(states)
+            lambda s: engine._substep(s, topo, traffic,
+                                      traffic.node_cap[0])))(states)
         names = set()
         for eqn in walk(jaxpr.jaxpr):
             names.add(eqn.primitive.name)
@@ -441,3 +463,281 @@ def test_vmapped_substep_keeps_rings_whole(base):
         return names
 
     assert primitives(4) == primitives(16)
+
+
+# ------------------------------------------------- the program shape the chip runs
+# The battery above drives one environment.  The chip runs ``jax.vmap`` of
+# ``engine.apply`` over replicas that differ in everything that is not static
+# — topology capacities, schedule, placement, traffic, rng — and a
+# per-replica index under ``vmap`` compiles to something else than the
+# unbatched program does.  So every drop and decision branch of the battery
+# also runs as one row of such a mixed batch, and the row must equal the
+# scenario's own unbatched run over the whole state and metrics pytree,
+# bit for bit.
+def assert_tree_bitequal(a, b):
+    """Same structure, shapes, dtypes and values (no tolerance)."""
+    la = jax.tree_util.tree_flatten_with_path(a)[0]
+    lb = jax.tree_util.tree_leaves(b)
+    assert len(la) == len(lb)
+    for (path, x), y in zip(la, lb):
+        x, y = np.asarray(x), np.asarray(y)
+        assert x.shape == y.shape and x.dtype == y.dtype, \
+            (jax.tree_util.keystr(path), x.dtype, y.dtype)
+        np.testing.assert_array_equal(
+            x, y, err_msg=f"leaf {jax.tree_util.keystr(path)} diverged")
+
+
+def _clean(m):
+    assert int(m.processed) > 0 and int(m.dropped) == 0
+
+
+def _dropped(m):
+    assert int(m.dropped) > 0       # the branch under test actually fired
+
+
+def _wrr_alternated(m):
+    counts = np.asarray(m.run_flow_counts)[0, 0, 0]
+    assert counts[1] == counts[2]   # the split actually alternated
+
+
+def _link_cap_pressure(m):
+    assert int(m.drop_reasons[2]) > 0
+
+
+def _generated(m):
+    assert int(m.generated) > 0
+
+
+# scenario -> (batch it rides in, what its own metrics must show); a batch
+# is one static (service, SimConfig) and so one pair of compiled programs
+BATTERY = {
+    "line_default": ("deterministic", _clean),
+    "node_cap": ("deterministic", _dropped),
+    "link_cap": ("deterministic", _dropped),
+    "unplaced_sf": ("deterministic", _dropped),
+    "empty_schedule": ("deterministic", _dropped),
+    "wrr_collisions": ("deterministic", _wrr_alternated),
+    "stochastic_startup": ("stochastic", _generated),
+    "ttl": ("ttl10", _dropped),
+    "line3_linkcap2_asset": ("linkcap2", _link_cap_pressure),
+    "triangle": ("reference_triangle", _generated),
+    "abilene": ("reference_abilene", _generated),
+}
+REFERENCE_NETS = {
+    "reference_triangle": "configs/networks/triangle/"
+                          "triangle-in2-cap10-delay10.graphml",
+    "reference_abilene": "configs/networks/abilene/"
+                         "abilene-in4-rand-cap1-2.graphml",
+}
+
+
+def _line_rows(limits):
+    """name -> (topology, schedule, placement) on the three-node stacks."""
+    to1 = schedule_all_to(limits, 1)
+    all1 = placement_at(limits, ALL_AT_1)
+    return {
+        "line_default": (line_topo(), to1, all1),
+        "node_cap": (line_topo(node_cap=0.5), to1, all1),
+        "link_cap": (line_topo(link_cap=0.5), to1, all1),
+        "unplaced_sf": (line_topo(), to1,
+                        placement_at(limits, [(1, 0), (1, 1)])),
+        "empty_schedule": (line_topo(),
+                           jnp.zeros(limits.scheduling_shape, jnp.float32),
+                           placement_at(limits, [])),
+        "wrr_collisions": (triangle_topo(), schedule_wrr_split(limits),
+                           placement_at(limits, ALL_AT_1_AND_2)),
+    }
+
+
+def _batch_spec(batch, base):
+    """-> (service, cfg, limits, intervals, {row name: (topo, sched,
+    place)}).  Rows no scenario names only make the batch mixed."""
+    service, limits = base
+    rows = _line_rows(limits)
+    mixers = {"mix_node_cap": rows["node_cap"],
+              "mix_wrr": rows["wrr_collisions"]}
+    if batch == "deterministic":
+        return service, make_cfg(), limits, 2, rows
+    if batch == "stochastic":
+        return (make_service(std=1.0, startup=2.0), make_cfg(), limits, 2,
+                {"stochastic_startup": rows["line_default"], **mixers})
+    if batch == "ttl10":
+        return (service, make_cfg(ttl_choices=(10.0,)), limits, 2,
+                {"ttl": rows["line_default"], **mixers})
+    from gsc_tpu.config.loader import load_sim
+    from gsc_tpu.topology.compiler import load_topology
+    if batch == "linkcap2":
+        # the in-repo LINK_CAP-dominated oracle of test_reference_parity:
+        # saturated links make nearly every substep a same-substep
+        # admission tie
+        from gsc_tpu.config.catalog import abc_service
+        service = abc_service()
+        cfg = load_sim(os.path.join(REPO, "tests", "assets",
+                                    "linkcap_config.yaml"))
+        nets = [load_topology(os.path.join(REPO, "tests", "assets",
+                                           "line3-linkcap2.graphml"),
+                              max_nodes=N, max_edges=E)] * 3
+        names = ["line3_linkcap2_asset", "mix_to_1", "mix_to_0"]
+        targets = [2, 1, 0]     # the asset: all toward the line's far end
+        intervals = 6
+    else:
+        # the frozen reference-parity scenarios through the uniform-action
+        # harness of tools/reward_curve.py (uniform schedule over real
+        # nodes, everything placed everywhere), one seed per row
+        from gsc_tpu.config.loader import load_service
+        sys.path.insert(0, os.path.join(REPO, "tools"))
+        from reward_curve import CONFIG, SERVICE
+        service = load_service(os.path.join(REFERENCE, SERVICE))
+        cfg = load_sim(os.path.join(REFERENCE, CONFIG))
+        nets = [load_topology(os.path.join(REFERENCE, REFERENCE_NETS[batch]),
+                              max_nodes=24, max_edges=37, seed=1234 + k)
+                for k in range(3)]
+        names = [batch[len("reference_"):], "mix_seed_1", "mix_seed_2"]
+        targets = [None] * 3
+        intervals = 25
+    limits = EnvLimits.for_service(service, max_nodes=nets[0].max_nodes,
+                                   max_edges=nets[0].max_edges)
+    rows = {}
+    for name, topo, dst in zip(names, nets, targets):
+        real = np.asarray(topo.node_mask)
+        sched = np.zeros(limits.scheduling_shape, np.float32)
+        if dst is None:
+            sched[:, :, :, real] = 1.0 / real.sum()
+        else:
+            sched[:, :, :, dst] = 1.0
+        place = np.broadcast_to(real[:, None],
+                                (limits.max_nodes, limits.max_sfs)).copy()
+        rows[name] = (topo, jnp.asarray(sched), jnp.asarray(place))
+    return service, cfg, limits, intervals, rows
+
+
+@pytest.fixture(scope="module")
+def mixed_batch(base):
+    """batch name -> {row name: ((state, metrics) alone, (state, metrics)
+    as its row of the batch)}; each batch is built and run once."""
+    done = {}
+
+    def run(batch):
+        if batch in done:
+            return done[batch]
+        service, cfg, limits, intervals, rows = _batch_spec(batch, base)
+        engine = SimEngine(service, cfg, limits)
+        inputs = []
+        for seed, (topo, sched, place) in enumerate(rows.values()):
+            traffic = generate_traffic(cfg, service, topo,
+                                       episode_steps=intervals, seed=seed)
+            inputs.append((engine.init(jax.random.PRNGKey(seed), topo),
+                           topo, traffic, sched, place))
+        alone = []
+        for state, *args in inputs:
+            for _ in range(intervals):
+                state, metrics = engine.apply(state, *args)
+            alone.append((state, metrics))
+        states, *args = jax.tree_util.tree_map(
+            lambda *xs: jnp.stack(xs), *inputs)
+        step = jax.jit(jax.vmap(
+            lambda *a: engine.apply.__wrapped__(engine, *a)))
+        for _ in range(intervals):
+            states, metrics = step(states, *args)
+        done[batch] = {
+            name: (alone[b], jax.tree_util.tree_map(lambda x: x[b],
+                                                    (states, metrics)))
+            for b, name in enumerate(rows)}
+        return done[batch]
+
+    return run
+
+
+@pytest.mark.parametrize("scenario", [
+    pytest.param(name, marks=pytest.mark.skipif(
+        BATTERY[name][0] in REFERENCE_NETS and not os.path.isdir(REFERENCE),
+        reason="reference tree not available"))
+    for name in BATTERY])
+def test_battery_row_in_mixed_batch_equals_alone(mixed_batch, scenario):
+    batch, shows = BATTERY[scenario]
+    alone, row = mixed_batch(batch)[scenario]
+    assert_tree_bitequal(alone, row)
+    shows(alone[1])
+
+
+def test_scan_unroll_bit_identical(base):
+    """cfg.scan_unroll only restructures the substep loop: unroll=4 must
+    be BIT-identical to unroll=1 (the precondition for promoting a swept
+    unroll winner)."""
+    service, limits = base
+    topo = line_topo()
+    sched = schedule_all_to(limits, 1)
+    place = placement_at(limits, ALL_AT_1)
+    out = []
+    for cfg in (make_cfg(), make_cfg(scan_unroll=4)):
+        engine = SimEngine(service, cfg, limits)
+        traffic = generate_traffic(cfg, service, topo, episode_steps=4,
+                                   seed=0)
+        state, metrics = run_intervals(engine, topo, traffic, sched, place, 2)
+        out.append((state, metrics[-1]))
+    assert_tree_bitequal(*out)
+
+
+# --------------------------------------------------------- fusion budget
+# Pinned compiled-HLO fusion count of the flagship-interval engine.apply
+# (abc service, Abilene limits 24/37, M=128, 100 substeps) on the CPU
+# backend, jaxlib 0.9.0: 276 (273 when re-measured and re-pinned in PR 21;
+# the same program counted 191 under the previous jaxlib — the compiler's
+# fusion decisions moved, the engine did not).  PR 29 re-pinned 273 -> 276:
+# stage 1 of the substep reads and clears the release rings' due row
+# through a mask over the whole ring instead of by index, which the CPU
+# compiler counts as three more fusions of this unbatched program — while
+# the TPU's vmapped program loses four whole-ring layout copies and two
+# scatters per substep.  The budget adds NO headroom on purpose — a
+# 281->294-style regression (the round-5 scatter-merge: bit-exact, yet
+# slower) is ~+13, so any slack would swallow exactly the class of change
+# this gate exists to catch.  If a toolchain upgrade moves the count,
+# re-measure and re-pin in the same commit as the upgrade (the assertion
+# message carries the recipe).
+#
+# What the pin protects: the engine's op count as the CPU compiler sees it
+# — a proxy, not the chip's count (the TPU compiler fuses differently; the
+# benchmark's `substep_device_ops` is the chip's own).
+FUSION_BUDGET = 276
+
+
+def _flagship_interval_compiled():
+    from gsc_tpu.config.catalog import abc_service
+    from gsc_tpu.topology.synthetic import abilene
+
+    service = abc_service()
+    limits = EnvLimits(max_nodes=24, max_edges=37, num_sfcs=1, max_sfs=3)
+    topo = compile_topology(abilene(), max_nodes=24, max_edges=37)
+    cfg = make_cfg()
+    engine = SimEngine(service, cfg, limits)
+    traffic = generate_traffic(cfg, service, topo, episode_steps=2, seed=0)
+    sched = np.zeros(limits.scheduling_shape, np.float32)
+    for n_ in range(24):
+        sched[n_, 0, :, n_] = 1.0
+    place = jnp.ones((24, 3), bool)
+    state = engine.init(jax.random.PRNGKey(0), topo)
+    return jax.jit(engine.apply.__wrapped__, static_argnums=0).lower(
+        engine, state, topo, traffic, jnp.asarray(sched), place).compile()
+
+
+def test_fusion_budget_flagship_interval():
+    """Tier-1 op-count gate: the substep within the pinned budget."""
+    from gsc_tpu.analysis.hlo import count_fusions
+
+    n = count_fusions(_flagship_interval_compiled())
+    assert n <= FUSION_BUDGET, (
+        f"substep fusion count regressed: {n} > pinned {FUSION_BUDGET}.  "
+        "If this is an intended engine change, re-measure with "
+        "tests/test_engine.py::_flagship_interval_compiled and re-pin "
+        "FUSION_BUDGET in the same commit, saying why beside the pin.")
+
+
+def test_ops_do_not_import_the_simulator():
+    """``gsc_tpu.ops`` (kernels) sits below ``gsc_tpu.sim`` (the engine
+    that calls them): importing it must not pull the simulator in."""
+    code = ("import sys, gsc_tpu.ops; "
+            "assert 'gsc_tpu.sim.engine' not in sys.modules")
+    p = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                       env=dict(os.environ, JAX_PLATFORMS="cpu"),
+                       capture_output=True, text=True)
+    assert p.returncode == 0, p.stderr

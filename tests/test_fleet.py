@@ -597,7 +597,6 @@ def test_learned_tier_swap_bit_identical_to_single_shot(learned, tmp_path):
     policy = GreedyServePolicy(ddpg, obs)
     kwargs = dict(buckets=(1, 2), deadline_ms=1.0,
                   precision=agent.precision,
-                  substep_impl=env.sim_cfg.substep_impl,
                   graph_mode=agent.graph_mode)
     cache = ArtifactCache(str(tmp_path / "cache"))
 
@@ -648,7 +647,6 @@ def test_learned_tier_rejects_mismatched_swap(learned, tmp_path):
                        cache=ArtifactCache(str(tmp_path / "cache")),
                        fingerprint="fp-v0",
                        precision=agent.precision,
-                       substep_impl=env.sim_cfg.substep_impl,
                        graph_mode=agent.graph_mode,
                        hot_swap_dir=str(tmp_path / "w"),
                        swap_poll_s=60.0).start()

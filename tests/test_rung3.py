@@ -99,12 +99,44 @@ def test_trace_changes_traffic(assets):
     assert nc[12, 0] == 4.0 and nc[5, 0] != 4.0
 
 
+# rung -> (builder in __graft_entry__, padded nodes, padded edges, flow
+# slots, replay rows in all, action width): the sizes BASELINE.md's configs
+# 4 and 5 and benchmarks/configs/interroute.json state
+STACK_SHAPES = {
+    "rung4": ("_rung4_stack", 64, 128, 512, 10000, 64 * 3 * 64),
+    "interroute": ("_interroute_stack", 128, 192, 1024, 2048, 49152),
+    "rung5": ("_rung5_stack", 256, 384, 1024, 1024, 256 * 2 * 3 * 256),
+}
+
+
+@pytest.mark.parametrize("rung", sorted(STACK_SHAPES))
+def test_stack_shapes(rung):
+    """Each ladder stack builds, and ``env.reset`` traces (no compile) to
+    state and observation of the stated sizes."""
+    import __graft_entry__ as ge
+    from gsc_tpu.sim.traffic import generate_traffic
+
+    builder, nodes, edges, slots, mem_limit, action = STACK_SHAPES[rung]
+    env, agent, topo = getattr(ge, builder)(episode_steps=2)
+    assert (topo.max_nodes, topo.max_edges) == (nodes, edges)
+    assert env.sim_cfg.max_flows == slots
+    assert agent.mem_limit == mem_limit
+    assert env.limits.action_dim == action
+    traffic = generate_traffic(env.sim_cfg, env.service, topo, 2, seed=0)
+    state, obs = jax.eval_shape(env.reset, jax.random.PRNGKey(0), topo,
+                                traffic)
+    assert state.sim.flows.phase.shape == (slots,)
+    assert obs.nodes.shape[0] == nodes
+    assert obs.edge_index.shape == (2, 2 * edges)
+    assert obs.mask.shape == (action,)
+
+
 def test_rung4_random_network_trains():
     """Rung-4 entry (BASELINE.md config 4): a 64-node randomized topology
     trains through the parallel rollout + learn path at reduced replicas."""
     import jax.numpy as jnp
 
-    from bench import _rung4_stack
+    from __graft_entry__ import _rung4_stack
     from gsc_tpu.parallel import ParallelDDPG
     from gsc_tpu.sim.traffic import generate_traffic
 

@@ -154,11 +154,11 @@ def _run_rung5_sharded():
 
 
 def test_bench_interroute_scenario_builds_and_steps():
-    """The bench.py interroute scenario (110n/146e, 1024 flow slots)
+    """The interroute stack (110n/146e, 1024 flow slots)
     constructs and rolls one 2-step episode through the parallel path."""
     import jax.numpy as jnp
 
-    from bench import _interroute_stack
+    from __graft_entry__ import _interroute_stack
     from gsc_tpu.parallel import ParallelDDPG
     from gsc_tpu.sim import generate_traffic
 
@@ -180,9 +180,9 @@ def test_bench_interroute_scenario_builds_and_steps():
 
 
 def test_bench_rung5_scenario_matches_config5():
-    """The bench.py rung5 scenario IS BASELINE config 5: 200-node
+    """The rung5 stack IS BASELINE config 5: 200-node
     synthetic topology, mixed 2-chain catalog over a 5-SF pool."""
-    from bench import _rung5_stack
+    from __graft_entry__ import _rung5_stack
 
     env, agent, topo = _rung5_stack(episode_steps=2)
     assert int(np.asarray(topo.node_mask).sum()) == 200

@@ -41,7 +41,6 @@ def _material(policy, env, agent, batch, fingerprint="fp-test",
               gnn_impl=None):
     return cache_material(fingerprint=fingerprint, template=policy.template,
                           batch=batch, precision=agent.precision,
-                          substep_impl=env.sim_cfg.substep_impl,
                           graph_mode=agent.graph_mode,
                           gnn_impl=gnn_impl or policy.ddpg.actor.gnn_impl)
 

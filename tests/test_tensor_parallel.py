@@ -2,8 +2,8 @@
 rules, psum-partial-product numerics vs the unsharded reference WITHIN
 tolerance, the no-layout-move resident-sharding contract on the dispatch
 path, carving-invariance WITHIN the bench_diff curve bands (2x2 vs 1x4
-digests need not agree — curves must), the jax-free meshspec grammar
-shared with bench.py, and collective-op HLO mining into the cost ledger.
+digests need not agree — curves must), the jax-free meshspec grammar,
+and collective-op HLO mining into the cost ledger.
 
 All marked ``tensor_parallel`` — ``pytest -m tensor_parallel -q`` is the
 standalone smoke group for the tp dispatch path.  Everything runs on the
@@ -96,7 +96,7 @@ def test_plan_tp_book_and_residency_flags():
 
 # ------------------------------------------------------- meshspec grammar
 def test_meshspec_is_the_one_grammar():
-    """The jax-free helper bench.py and partition.py both import:
+    """The jax-free helper the launcher and partition.py both import:
     canonical spellings, validation errors, the rulebook vocabulary —
     and partition.parse_mesh_shape IS meshspec's (no third copy)."""
     import gsc_tpu.meshspec as ms
@@ -114,9 +114,8 @@ def test_meshspec_is_the_one_grammar():
     with pytest.raises(ValueError, match="unknown rulebook"):
         validate_partition_rules("zerO")
     # jax-free by contract: no import statement in the module (or the
-    # package __init__ it pulls in) may touch jax — argument parsing
-    # (bench.py) and the jax-free dryrun launcher validate mesh specs
-    # before any backend exists
+    # package __init__ it pulls in) may touch jax — the jax-free dryrun
+    # launcher validates mesh specs before any backend exists
     import ast
     import importlib
 

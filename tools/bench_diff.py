@@ -14,8 +14,8 @@ This tool makes that trajectory a guarded artifact:
   artifact name; re-ingesting updates in place);
 - **diff**: compare a current row (by name, or straight from a file)
   against a NAMED BASELINE row with per-metric tolerance bands, exit
-  nonzero on any regression — the fusion-budget discipline of the
-  megakernel PR, generalized to every perf-relevant number.
+  nonzero on any regression — the fusion-budget discipline,
+  generalized to every perf-relevant number.
 
 Verdicts per metric: ``ok`` (within band), ``improved``, ``regression``
 (beyond band in the bad direction), ``missing`` (only one side has it —
@@ -146,7 +146,8 @@ def _num(v) -> Optional[float]:
 
 
 def _bench_row(d: Dict) -> Dict:
-    """A bench.py artifact line (possibly a driver wrapper's `parsed`)."""
+    """An ``env_steps_per_sec_per_chip`` artifact line (the root
+    ``*_rNN.json`` records; possibly a driver wrapper's `parsed`)."""
     status = d.get("status") or ("failed" if d.get("error") else "ok")
     metrics: Dict[str, float] = {}
     if status == "ok":
@@ -208,8 +209,8 @@ def _bench_row(d: Dict) -> Dict:
                     metrics[f"{fn}_{k}"] = float(cost[k])
     return {"kind": "bench", "status": status, "metrics": metrics,
             "context": {k: d.get(k) for k in
-                        ("pipeline", "precision", "substep_impl", "unroll",
-                         "mesh", "topo_mix", "async_actors",
+                        ("pipeline", "precision", "unroll", "mesh",
+                         "topo_mix", "async_actors",
                          "policy_lag_max", "produced_steps",
                          "ingested_steps", "ring_shards") if k in d}}
 

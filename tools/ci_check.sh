@@ -13,7 +13,7 @@ cd "$(dirname "$0")/.."
 echo "== gsc-lint (rules R1-R10, baseline: tools/gsc_lint_baseline.json) =="
 # the summary line carries a stale-suppression count when the baseline
 # has drifted — `python tools/gsc_lint.py --prune-stale` clears it
-python tools/gsc_lint.py gsc_tpu/ tools/ bench.py
+python tools/gsc_lint.py gsc_tpu/ tools/
 
 echo "== gsc-lint self-check (concurrency rules must catch a seeded inversion) =="
 # negative control: a throwaway ABBA lock-order fixture MUST fail the
@@ -56,15 +56,6 @@ if [[ "${1:-}" == "--lint-only" ]]; then
     echo "ci_check: lint-only pass OK"
     exit 0
 fi
-
-echo "== megakernel interpret-parity smoke (pallas substep == xla) =="
-# one fast scenario through both substep impls, full post-interval state
-# bit-compared (the standalone `pytest -m megakernel` group runs the whole
-# battery inside tier-1 below; this stage fails FAST and by name when the
-# kernel drifts)
-env JAX_PLATFORMS=cpu python -m pytest \
-    "tests/test_megakernel.py::test_megakernel_parity_smoke" -q \
-    -p no:cacheprovider
 
 echo "== serve smoke (AOT policy serving: cold compile -> cache-hit restart) =="
 # tiny checkpoint -> in-process server -> N requests twice: run 1 must

@@ -2,7 +2,7 @@
 """gsc-lint CLI — JAX-aware static analysis for this repo.
 
 Usage:
-    python tools/gsc_lint.py [paths...]            # default: gsc_tpu/ tools/ bench.py
+    python tools/gsc_lint.py [paths...]            # default: gsc_tpu/ tools/
     python tools/gsc_lint.py --json [paths...]
     python tools/gsc_lint.py --rules R1,R4 [paths...]
     python tools/gsc_lint.py --changed [REF]       # only files in git diff REF
@@ -63,7 +63,7 @@ from gsc_tpu.analysis import (  # noqa: E402
 from gsc_tpu.analysis.astlint import _iter_py_files, lint_files  # noqa: E402
 from gsc_tpu.analysis.baseline import build_result  # noqa: E402
 
-DEFAULT_PATHS = ("gsc_tpu/", "tools/", "bench.py")
+DEFAULT_PATHS = ("gsc_tpu/", "tools/")
 DEFAULT_BASELINE = os.path.join(REPO_ROOT, "tools",
                                 "gsc_lint_baseline.json")
 

@@ -5,8 +5,9 @@ throughput).
 Trains ParallelDDPG on Abilene rand-cap1-2 (the reference benchmark
 workload) for ``--episodes`` full 200-step episodes across ``--replicas``
 vmapped envs and prints per-episode mean return / success ratio plus the
-first-10 vs last-10 summary.  Episodes run CHUNKED (see bench.py) so the
-TPU never sees a 200-step single-call scan.
+first-10 vs last-10 summary.  Episodes run CHUNKED
+(``parallel.harness.run_chunked_episodes``) so the TPU never sees a
+200-step single-call scan.
 
 On the single shared TPU run it via::
 

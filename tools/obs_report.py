@@ -9,9 +9,8 @@ does by default), prints:
 
 - a per-run header with the dtype policy (the ``precision`` event /
   run_start meta: policy name plus param/gnn/mlp/replay dtypes) and the
-  engine knobs (run_start meta: ``substep_impl`` + ``unroll``) so a
-  throughput comparison across runs is attributable to precision and
-  substep engine;
+  substep scan's unroll factor (run_start meta: ``unroll``) so a
+  throughput comparison across runs is attributable to both;
 - a per-episode table: SPS, return, success ratio, learner losses, the
   per-episode *delta* of each pipeline phase's host wall (the stream
   carries cumulative ``PhaseTimer`` totals), and device bytes-in-use;
@@ -326,13 +325,12 @@ def summarize(events: List[Dict], mem_growth_threshold: float = 0.2,
                                "mlp_compute", "replay_dtype")}
     elif run_start is not None and run_start.get("precision"):
         precision = {"name": run_start["precision"]}
-    # engine-knob header fields (run_start meta, cli train): the substep
-    # implementation and scan-unroll factor the run was built with, so a
-    # throughput comparison across runs attributes the engine share
+    # engine-knob header field (run_start meta, cli train): the
+    # scan-unroll factor the run was built with, so a throughput
+    # comparison across runs attributes the engine share
     engine = None
-    if run_start is not None and run_start.get("substep_impl"):
-        engine = {"substep_impl": run_start["substep_impl"],
-                  "unroll": run_start.get("unroll", 1)}
+    if run_start is not None and run_start.get("unroll") is not None:
+        engine = {"unroll": run_start["unroll"]}
     # mesh header fields (run_start meta, cli train --mesh): the DPxMP
     # carving, the partition rulebook and the compact per-leaf spec
     # counts, so a multi-chip run's layout is readable off the report
@@ -687,8 +685,7 @@ def render_text(summary: Dict, out=sys.stdout):
         w(f"precision: {prec.get('name')}{detail}\n")
     eng = summary.get("engine")
     if eng:
-        w(f"substep: {eng.get('substep_impl')}  "
-          f"unroll: {eng.get('unroll')}\n")
+        w(f"substep unroll: {eng.get('unroll')}\n")
     mesh = summary.get("mesh")
     if mesh:
         specs = mesh.get("partition_specs") or {}
@@ -975,7 +972,7 @@ def _synthetic_events(path: str, episodes: int = 5):
 
         emit({"event": "run_start", "ts": base, "run": "selftest",
               "episodes": episodes, "precision": "bf16",
-              "substep_impl": "pallas", "unroll": 2,
+              "unroll": 2,
               "mesh": "4x2", "partition_rules": "sharded",
               "topo_mix": "schedule,abilene+bursty",
               "partition_specs": {"PartitionSpec()": 87,
@@ -1270,8 +1267,7 @@ def selftest() -> int:
             "name": "bf16", "param_dtype": "float32",
             "gnn_compute": "bfloat16", "mlp_compute": "bfloat16",
             "replay_dtype": "bfloat16"}, "precision header not surfaced"
-        assert summary["engine"] == {
-            "substep_impl": "pallas", "unroll": 2}, \
+        assert summary["engine"] == {"unroll": 2}, \
             "engine-knob header not surfaced"
         assert summary["mesh"] == {
             "mesh": "4x2", "partition_rules": "sharded",
