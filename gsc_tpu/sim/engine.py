@@ -19,7 +19,13 @@ flowsimulator.py:72-128):
  2. advance HOP/PROC timers; completed PROC flows advance their SFC position
     (base_processor.py:104-107) and re-enter decision; completed hops either
     continue the path, arrive for processing, or depart at egress
- 3. admit new arrivals from the pre-generated TrafficSchedule into free slots
+ 3. admit new arrivals from the pre-generated TrafficSchedule into free
+    slots; the candidates are always the contiguous run of eight records at
+    the cursor, so they are fetched as one run, for all fields at once and
+    through masks instead of indices (under ``vmap`` the cursor is a
+    per-replica vector, and an indexed read at it is serial on the TPU):
+    once per interval the few table rows the cursor can reach, every
+    substep the run out of those rows
  4. decisions: egress routing for finished flows (default_decision_maker.py:
     27-31) and weighted-round-robin next-node selection against the
     scheduling table with per-(node,SFC,SF) realized-ratio counters
@@ -64,6 +70,7 @@ from ..config.registry import get_resource_function
 from ..config.schema import EnvLimits, ServiceConfig, SimConfig
 from ..topology.compiler import Topology
 from .state import (
+    ARRIVAL_RUN,
     DROP_DECISION,
     DROP_LINK_CAP,
     DROP_NODE_CAP,
@@ -82,7 +89,7 @@ from .state import (
 _EPS = 1e-4
 # arrivals admitted per substep; later arrivals spill to the next substep
 # (with default dt=1ms this is never binding outside extreme overload)
-_ARRIVALS_PER_SUBSTEP = 8
+_ARRIVALS_PER_SUBSTEP = ARRIVAL_RUN
 
 
 @dataclass(frozen=True)
@@ -289,8 +296,15 @@ class SimEngine:
         if traffic.edge_cap_t is not None:
             topo = topo.replace(edge_cap=traffic.edge_cap_t[idx_now])
 
+        # the table rows this interval's arrivals can come from, masked out
+        # of the episode-long table once, here, not per substep: a cursor
+        # moves at most _ARRIVALS_PER_SUBSTEP records a substep
+        with jax.named_scope("traffic_arrivals"):
+            arrivals = traffic.window(
+                state.cursor, (self.substeps - 1) * _ARRIVALS_PER_SUBSTEP + 1)
+
         def sub(st, _):
-            return self._substep(st, topo, traffic, cap_now), None
+            return self._substep(st, topo, arrivals, cap_now), None
 
         # unroll trades compile time for per-iteration scan overhead — the
         # substep is a chain of small fusions, so on TPU the loop machinery
@@ -334,8 +348,8 @@ class SimEngine:
             # same link-fault row select as apply() — per-flow control
             # sees the identical capacity timeline
             topo = topo.replace(edge_cap=traffic.edge_cap_t[idx])
-        return self._substep(state, topo, traffic, cap_now,
-                             ext_decisions=ext_decisions)
+        return self._substep(state, topo, traffic.window(state.cursor, 1),
+                             cap_now, ext_decisions=ext_decisions)
 
     def apply_per_flow(self, state: SimState, topo: Topology,
                        traffic: TrafficSchedule, decide_fn
@@ -352,12 +366,14 @@ class SimEngine:
 
     # ---------------------------------------------------------------- substep
     @jax.named_scope("sim_substep")
-    def _substep(self, state: SimState, topo: Topology,
-                 traffic: TrafficSchedule, cap_now: jnp.ndarray,
+    def _substep(self, state: SimState, topo: Topology, arrivals,
+                 cap_now: jnp.ndarray,
                  ext_decisions: jnp.ndarray | None = None) -> SimState:
         """One fixed substep of every flow slot: the hand-fused one-hot
-        pipeline of the module docstring, stages 1-7.  ``ext_decisions``
-        (per-flow control) replaces stage 4's weighted-round-robin choice."""
+        pipeline of the module docstring, stages 1-7.  ``arrivals`` is a
+        ``traffic.window`` that holds this substep's run of candidate
+        records (stage 3); ``ext_decisions`` (per-flow control) replaces
+        stage 4's weighted-round-robin choice."""
         F = state.flows
         m = state.metrics
         dt = self.dt
@@ -428,46 +444,51 @@ class SimEngine:
 
         # --- 3. arrivals ----------------------------------------------------
         with jax.named_scope("traffic_arrivals"):
-            cand = state.cursor + jnp.arange(_ARRIVALS_PER_SUBSTEP)
-            cand_c = jnp.clip(cand, 0, traffic.capacity - 1)
-            due = (traffic.arr_time[cand_c] < t + dt - _EPS) \
-                & (cand < traffic.capacity) \
-                & jnp.isfinite(traffic.arr_time[cand_c])
+            # The candidates are the contiguous run cursor … cursor + 7 of
+            # the time-sorted table, fetched ONCE for all seven fields and
+            # through masks, not indices (``TrafficSchedule.read_run``);
+            # every later use in this stage reads these [8] arrays.  Under
+            # vmap ``cursor`` is a per-replica vector: a field-by-field,
+            # record-by-record read compiles on the TPU to one serial
+            # gather of 8 x B scalars per field, and the table is far too
+            # long to mask every substep — so the caller hands in the few
+            # rows of it the cursor can reach (``arrivals``).
+            (a_time, a_dr, a_duration, a_ttl, a_ingress, a_sfc,
+             a_egress) = TrafficSchedule.read_run(*arrivals, state.cursor)
+            # (records behind the table's capacity are padding, time inf)
+            due = (a_time < t + dt - _EPS) & jnp.isfinite(a_time)
             free = phase == PH_FREE
             free_rank = jnp.cumsum(free.astype(jnp.int32)) - 1
             n_free = free.sum()
             arr_rank = jnp.cumsum(due.astype(jnp.int32)) - 1
             spawn = due & (arr_rank < n_free)
-            # slot_of_rank[r] = slot index of the r-th free slot (one-hot
-            # transpose scatter; the [A]-sized rank gather stays native)
-            oh_rank = _onehot(jnp.where(free, free_rank, self.M), self.M)
-            slot_of_rank = jnp.round(
-                jnp.dot(slots.astype(jnp.float32), oh_rank,
-                        precision=_HI)).astype(jnp.int32)
-            tgt = slot_of_rank[jnp.clip(arr_rank, 0, self.M - 1)]
+            # tgt[a] = slot of the arr_rank[a]-th free slot, as a masked sum
+            # over the [A, M] match (one non-zero integer term, or none
+            # when the rank has no free slot: such a record does not
+            # spawn) — a gather at the per-replica ranks would again be
+            # serial under vmap
+            match = free[None, :] & (free_rank[None, :] == arr_rank[:, None])
+            tgt = jnp.where(match, slots[None, :], 0).sum(-1)
 
             # one packed scatter per dtype instead of 11 per-field scatters —
             # scatters end XLA fusions, so per-substep op count (the TPU cost
             # driver) tracks the number of scatters, not the bytes moved
             arr_idx = jnp.where(spawn, tgt, self.M)
-            a_i32 = jnp.zeros_like(cand)
+            a_i32 = jnp.zeros(_ARRIVALS_PER_SUBSTEP, jnp.int32)
             int_cur = jnp.stack(
                 [phase, node, position, F.sfc, F.egress, F.dest],
                 axis=-1)                                       # [M, 6]
             int_new = jnp.stack(
-                [a_i32 + PH_DECIDE, traffic.arr_ingress[cand_c], a_i32,
-                 traffic.arr_sfc[cand_c], traffic.arr_egress[cand_c],
+                [a_i32 + PH_DECIDE, a_ingress, a_i32, a_sfc, a_egress,
                  a_i32 - 1], axis=-1)                          # [A, 6]
             int_cur = int_cur.at[arr_idx].set(int_new, mode="drop")
             phase, node, position, sfc, egress, dest = (
                 int_cur[:, 0], int_cur[:, 1], int_cur[:, 2], int_cur[:, 3],
                 int_cur[:, 4], int_cur[:, 5])
-            a_f32 = jnp.zeros(cand.shape, jnp.float32)
+            a_f32 = jnp.zeros(_ARRIVALS_PER_SUBSTEP, jnp.float32)
             flt_cur = jnp.stack([F.dr, F.duration, ttl, e2e, F.pend_path],
                                 axis=-1)                           # [M, 5]
-            flt_new = jnp.stack([traffic.arr_dr[cand_c],
-                                 traffic.arr_duration[cand_c],
-                                 traffic.arr_ttl[cand_c], a_f32, a_f32],
+            flt_new = jnp.stack([a_dr, a_duration, a_ttl, a_f32, a_f32],
                                 axis=-1)                           # [A, 5]
             flt_cur = flt_cur.at[arr_idx].set(flt_new, mode="drop")
             dr, duration, ttl, e2e, pend_path = (
@@ -479,16 +500,15 @@ class SimEngine:
             # arrivals spawning after their scheduled substep were delayed
             # by slot exhaustion / the per-substep arrival budget — count
             # each once
-            late = spawn & (traffic.arr_time[cand_c] < t - _EPS)
+            late = spawn & (a_time < t - _EPS)
             truncated = state.truncated_arrivals + late.sum()
             m = m.replace(
                 generated=m.generated + n_spawn,
                 run_generated=m.run_generated + n_spawn,
                 active=m.active + n_spawn,
                 run_requested_node=m.run_requested_node.at[
-                    jnp.where(spawn, traffic.arr_ingress[cand_c], self.N)
-                ].add(jnp.where(spawn, traffic.arr_dr[cand_c], 0.0),
-                      mode="drop"),
+                    jnp.where(spawn, a_ingress, self.N)
+                ].add(jnp.where(spawn, a_dr, 0.0), mode="drop"),
             )
 
         # recompute flags after arrivals.  The UN-clipped one-hot zero-rows

@@ -22,6 +22,7 @@ TPU HBM, be advanced by ``lax.scan`` and batched with ``vmap``:
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from flax import struct
@@ -161,6 +162,39 @@ class SimMetrics:
                          self.run_e2e_sum / jnp.maximum(self.run_processed, 1), 0.0)
 
 
+# Records the engine reads per substep (stage 3): the contiguous run
+# ``cursor … cursor + ARRIVAL_RUN - 1`` of the time-sorted arrival table.
+ARRIVAL_RUN = 8
+# Records per row of the arrival table (the TPU's lane count: a row is one
+# full vector register row, so row masks and lane masks tile without waste)
+ARRIVAL_LANES = 128
+# Row order of the arrival table, with the value each field's padding holds
+_ARRIVAL_FIELDS = (
+    ("arr_time", jnp.float32, np.inf),    # sorted ascending
+    ("arr_dr", jnp.float32, 0.0),
+    ("arr_duration", jnp.float32, 0.0),   # size/dr*1000
+    ("arr_ttl", jnp.float32, 0.0),
+    ("arr_ingress", jnp.int32, 0),
+    ("arr_sfc", jnp.int32, 0),
+    ("arr_egress", jnp.int32, -1),        # -1: none
+)
+
+
+def _masked_rows(x: jnp.ndarray, first: jnp.ndarray, count: int,
+                 axis: int) -> jnp.ndarray:
+    """``count`` consecutive entries of ``x`` along ``axis`` from the traced
+    index ``first`` on, as a masked sum over the whole axis (entries past
+    the end read 0).  Integer ``x``: the sum has one non-zero term, so any
+    bit pattern comes through unchanged — and, unlike ``dynamic_slice``, a
+    per-replica ``first`` under ``vmap`` stays elementwise + reduce: no
+    gather, no loop over the replicas on the TPU."""
+    x = jnp.moveaxis(x, axis, -1)
+    want = first + jnp.arange(count)
+    hit = want[:, None] == jnp.arange(x.shape[-1])        # [count, n]
+    out = jnp.where(hit, x[..., None, :], 0).sum(-1)      # [..., count]
+    return jnp.moveaxis(out, -1, axis)
+
+
 @struct.dataclass
 class TrafficSchedule:
     """Pre-generated per-episode traffic, the tensor analogue of the
@@ -169,16 +203,28 @@ class TrafficSchedule:
     switching (simulatorparams.py:143-176) and trace-driven scenario changes
     (trace_processor.py:23-54) — all host-precomputed into dense arrays.
 
-    Flow records are sorted by arrival time; the engine keeps a cursor.
+    Flow records are sorted by arrival time; the engine keeps a cursor and
+    reads, every substep, the ``ARRIVAL_RUN`` records from the cursor on.
+    The seven per-record fields live in ONE table ``arr`` of 32-bit
+    patterns (float fields bit-cast, which is exact), field-major, the
+    record axis cut into rows of ``ARRIVAL_LANES``, so that the run is
+    fetched once for all fields and without an index: under ``vmap`` the
+    cursor is a per-replica vector, and any indexed read of it (a gather
+    per field and record, a ``dynamic_slice`` per replica) is serial on
+    the TPU.  Instead :meth:`window` masks out, once per control interval,
+    the few rows the cursor can reach in that interval, and
+    :meth:`read_run` masks the run out of those rows every substep — both
+    elementwise + reduce, the first streaming the table once, the second
+    touching the window only.  The table is padded (time ``inf``: never
+    due) to whole rows that hold at least ``ARRIVAL_RUN`` records behind
+    its ``capacity``, so a run that starts at the last record, or past it,
+    stays inside.  Build with :meth:`pack`; the ``arr_*`` properties are
+    the per-field ``[..., F]`` views for everything but the substep.
     """
 
-    arr_time: jnp.ndarray     # [F] f32, sorted ascending (inf for padding)
-    arr_ingress: jnp.ndarray  # [F] i32
-    arr_dr: jnp.ndarray       # [F] f32
-    arr_duration: jnp.ndarray  # [F] f32 (size/dr*1000)
-    arr_ttl: jnp.ndarray      # [F] f32
-    arr_sfc: jnp.ndarray      # [F] i32
-    arr_egress: jnp.ndarray   # [F] i32 (-1: none)
+    arr: jnp.ndarray   # [7, rows, ARRIVAL_LANES] i32, fields _ARRIVAL_FIELDS
+    # number of records F (static: the table's shape only knows whole rows)
+    capacity: int = struct.field(pytree_node=False)
     # Per control interval [T, N]: which ingresses generate flows (trace rows
     # can deactivate an ingress, trace_processor.py:37-38; affects placement
     # derivation via get_active_ingress_nodes, siminterface/simulator.py:261-263)
@@ -195,9 +241,68 @@ class TrafficSchedule:
     # to the fault-unaware stack.
     edge_cap_t: jnp.ndarray = None   # [T, E] f32 or None
 
-    @property
-    def capacity(self) -> int:
-        return self.arr_time.shape[-1]
+    @classmethod
+    def pack(cls, *, ingress_active, node_cap, edge_cap_t=None,
+             **fields) -> "TrafficSchedule":
+        """The one constructor: the seven ``[F]`` per-record arrays
+        (``arr_time``, sorted ascending with ``inf`` behind the last
+        record, ``arr_dr``, ``arr_duration``, ``arr_ttl``, ``arr_ingress``,
+        ``arr_sfc``, ``arr_egress``) into the table."""
+        capacity = np.shape(fields["arr_time"])[-1]
+        rows = -(-(capacity + ARRIVAL_RUN) // ARRIVAL_LANES)
+        pad = rows * ARRIVAL_LANES - capacity
+
+        def bits(name, dtype, fill):
+            x = jnp.concatenate([jnp.asarray(fields.pop(name), dtype),
+                                 jnp.full((pad,), fill, dtype)])
+            return jax.lax.bitcast_convert_type(x, jnp.int32)
+
+        arr = jnp.stack([bits(*f) for f in _ARRIVAL_FIELDS])
+        if fields:
+            raise TypeError(f"unknown arrival fields: {sorted(fields)}")
+        return cls(arr=arr.reshape(len(_ARRIVAL_FIELDS), rows, ARRIVAL_LANES),
+                   ingress_active=ingress_active, node_cap=node_cap,
+                   edge_cap_t=edge_cap_t, capacity=capacity)
+
+    def _field(self, k: int) -> jnp.ndarray:
+        _, dtype, _ = _ARRIVAL_FIELDS[k]
+        x = self.arr[..., k, :, :]
+        x = x.reshape(x.shape[:-2] + (-1,))[..., :self.capacity]
+        return jax.lax.bitcast_convert_type(x, dtype)
+
+    arr_time = property(lambda self: self._field(0))
+    arr_dr = property(lambda self: self._field(1))
+    arr_duration = property(lambda self: self._field(2))
+    arr_ttl = property(lambda self: self._field(3))
+    arr_ingress = property(lambda self: self._field(4))
+    arr_sfc = property(lambda self: self._field(5))
+    arr_egress = property(lambda self: self._field(6))
+
+    def window(self, cursor: jnp.ndarray, records: int):
+        """``(rows, base)``: the table rows that hold every run starting in
+        ``[cursor, cursor + records)`` — the whole table where that is no
+        more — and the record index of the first of them."""
+        total = self.arr.shape[-2]
+        count = min(total,
+                    -(-(ARRIVAL_LANES - 1 + records + ARRIVAL_RUN - 1)
+                      // ARRIVAL_LANES))
+        first = jnp.clip(cursor // ARRIVAL_LANES, 0, total - count)
+        return (_masked_rows(self.arr, first, count, axis=-2),
+                first * ARRIVAL_LANES)
+
+    @staticmethod
+    def read_run(rows: jnp.ndarray, base: jnp.ndarray, cursor: jnp.ndarray):
+        """The seven ``[ARRIVAL_RUN]`` field arrays (order of
+        ``_ARRIVAL_FIELDS``) of the records ``cursor … cursor +
+        ARRIVAL_RUN - 1``, out of a :meth:`window` that holds them: first
+        the two rows the run can touch, then its lanes out of those."""
+        row, lane = jnp.divmod(cursor - base, ARRIVAL_LANES)
+        two = _masked_rows(rows, row, 2, axis=-2)         # [7, 2, LANES]
+        offset = jnp.arange(2 * ARRIVAL_LANES).reshape(2, ARRIVAL_LANES)
+        hit = offset == (lane + jnp.arange(ARRIVAL_RUN))[:, None, None]
+        run = jnp.where(hit, two[:, None], 0).sum((-2, -1))   # [7, RUN]
+        return tuple(jax.lax.bitcast_convert_type(run[k], dtype)
+                     for k, (_, dtype, _) in enumerate(_ARRIVAL_FIELDS))
 
 
 @struct.dataclass

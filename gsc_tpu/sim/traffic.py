@@ -179,7 +179,7 @@ def generate_traffic(
             out[:len(vals)] = np.asarray(vals, dtype)
             return out
 
-        return TrafficSchedule(
+        return TrafficSchedule.pack(
             arr_time=jnp.asarray(pad_native(n_times, np.inf, np.float32)),
             arr_ingress=jnp.asarray(pad_native(n_ing, 0, np.int32)),
             arr_dr=jnp.asarray(pad_native(n_drs, 0.0, np.float32)),
@@ -254,7 +254,7 @@ def generate_traffic(
             out[:f] = np.asarray(vals, dtype)[order]
         return out
 
-    return TrafficSchedule(
+    return TrafficSchedule.pack(
         arr_time=jnp.asarray(pad_f(times, np.inf, np.float32)),
         arr_ingress=jnp.asarray(pad_f(ingress, 0, np.int32)),
         arr_dr=jnp.asarray(pad_f(drs, 0.0, np.float32)),

@@ -260,7 +260,7 @@ class DeviceTraffic:
             self.cfg, means, self.active, self.next_active, self.horizon,
             self.capacity, self.n_sfcs, self.ttl_choices, self.eg_table,
             self.eg_count, k_flows)
-        return TrafficSchedule(
+        return TrafficSchedule.pack(
             arr_time=times, arr_ingress=ingress, arr_dr=drs,
             arr_duration=durs, arr_ttl=ttls, arr_sfc=sfcs, arr_egress=egs,
             ingress_active=self.active, node_cap=self.caps,

@@ -475,7 +475,7 @@ class ScenarioFactory:
             self.cfg, means, active, next_active, self.horizon,
             self.capacity, self.n_sfcs, self.ttl_choices,
             jnp.zeros((1,), jnp.int32), 0, k_flows)
-        return TrafficSchedule(
+        return TrafficSchedule.pack(
             arr_time=times, arr_ingress=ingress, arr_dr=drs,
             arr_duration=durs, arr_ttl=ttls, arr_sfc=sfcs, arr_egress=egs,
             ingress_active=active, node_cap=caps, edge_cap_t=edge_cap_t)

@@ -423,6 +423,34 @@ def test_release_rings_match_plain_statement(base, scenario, mode):
             check(jax.tree_util.tree_map(lambda x: x[b], states), k0 + k)
 
 
+INDEXED = ("scatter", "scatter-add", "dynamic_update_slice", "dynamic_slice",
+           "gather")
+
+
+def _walk(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _walk(sub)
+
+
+def _vmapped_primitives(fn, one, replicas, whole):
+    """Primitive names of ``jax.vmap(fn)`` over ``replicas`` copies of the
+    state ``one``; no indexed primitive may take an operand whose
+    per-replica shape is in ``whole``."""
+    states = jax.tree_util.tree_map(lambda x: jnp.stack([x] * replicas), one)
+    names = set()
+    for eqn in _walk(jax.make_jaxpr(jax.vmap(fn))(states).jaxpr):
+        names.add(eqn.primitive.name)
+        if eqn.primitive.name in INDEXED:
+            shapes = {tuple(v.aval.shape[1:]) for v in eqn.invars
+                      if hasattr(v.aval, "shape")}
+            assert not shapes & whole, (
+                f"{eqn.primitive.name} on {shapes & whole} at B = "
+                f"{replicas}: {eqn}")
+    return names
+
+
 def test_vmapped_substep_keeps_rings_whole(base):
     """Under ``jax.vmap`` the ring index is a per-replica vector; the
     substep must still touch a ring only through elementwise operations
@@ -435,34 +463,225 @@ def test_vmapped_substep_keeps_rings_whole(base):
     engine = SimEngine(service, cfg, limits)
     traffic = generate_traffic(cfg, service, topo, episode_steps=2, seed=0)
     rings = {(engine.H, N * limits.max_sfs), (engine.H, E)}
-    indexed = ("scatter", "scatter-add", "dynamic_update_slice",
-               "dynamic_slice", "gather")
 
-    def walk(jaxpr):
-        for eqn in jaxpr.eqns:
-            yield eqn
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                yield from walk(sub)
+    def substep(s):
+        return engine._substep(s, topo, traffic.window(s.cursor, 1),
+                               traffic.node_cap[0])
 
-    def primitives(replicas):
-        one = engine.init(jax.random.PRNGKey(0), topo)
-        states = jax.tree_util.tree_map(
-            lambda x: jnp.stack([x] * replicas), one)
-        jaxpr = jax.make_jaxpr(jax.vmap(
-            lambda s: engine._substep(s, topo, traffic,
-                                      traffic.node_cap[0])))(states)
-        names = set()
-        for eqn in walk(jaxpr.jaxpr):
-            names.add(eqn.primitive.name)
-            if eqn.primitive.name in indexed:
-                shapes = {tuple(v.aval.shape[1:]) for v in eqn.invars
-                          if hasattr(v.aval, "shape")}
-                assert not shapes & rings, (
-                    f"{eqn.primitive.name} on a release ring at B = "
-                    f"{replicas}: {eqn}")
-        return names
+    one = engine.init(jax.random.PRNGKey(0), topo)
+    assert (_vmapped_primitives(substep, one, 4, rings)
+            == _vmapped_primitives(substep, one, 16, rings))
 
-    assert primitives(4) == primitives(16)
+
+def test_vmapped_interval_reads_arrivals_without_an_index(base):
+    """Under ``jax.vmap`` the arrival cursor is a per-replica vector, and
+    an indexed read at it is serial on the TPU (one gather of 8 x B
+    scalars per field, or a loop over the replicas per slice): the whole
+    interval — taking the window, reading the run out of it — must reach
+    the arrival table, the window and the two rows of a run through masks
+    alone, and trace to the same primitives whatever the number of
+    replicas."""
+    service, limits = base
+    cfg = make_cfg()
+    topo = line_topo()
+    engine = SimEngine(service, cfg, limits)
+    traffic = generate_traffic(cfg, service, topo, episode_steps=20, seed=0,
+                               capacity=4000)
+    sched = schedule_all_to(limits, 1)
+    place = placement_at(limits, ALL_AT_1)
+    one = engine.init(jax.random.PRNGKey(0), topo)
+    fields, rows, lanes = traffic.arr.shape
+    window = traffic.window(one.cursor, (engine.substeps - 1) * 8 + 1
+                            )[0].shape
+    assert window[1] < rows         # the table is longer than a window
+    arrival_shapes = {(fields, rows, lanes), window, (fields, 2, lanes),
+                      (fields, rows * lanes), (rows * lanes,)}
+
+    def interval(s):
+        return engine.apply.__wrapped__(engine, s, topo, traffic, sched,
+                                        place)[0]
+
+    assert (_vmapped_primitives(interval, one, 4, arrival_shapes)
+            == _vmapped_primitives(interval, one, 16, arrival_shapes))
+
+
+# ---------------------------------------------------------- arrival cursor
+# Stage 3 against a plain statement.  With nothing placed, every flow of the
+# line scenario lives exactly ARRIVAL_HOLD substeps whatever its record
+# says: spawned at substep g, it takes link 0 (delay 3), reaches node 1 at
+# g + 3 and is dropped there (SF not placed), so its slot is free again
+# from g + 4 on.  That fixes the free slots of every substep, and with them
+# which record spawns when, into which slot, and every counter of the stage.
+ARRIVAL_HOLD = 4
+ARRIVAL_SUBSTEPS = 10       # a short interval: a checkpoint every 10 ms
+ARRIVAL_INTERVALS = 6
+
+
+def _records(rng, times, ingress=0):
+    """Seven per-record arrays for hand-made arrival ``times``: distinct
+    data rates, so a slot's ``dr`` names the record that landed in it."""
+    f = len(times)
+    dr = rng.uniform(0.5, 1.5, f).astype(np.float32)
+    return dict(
+        arr_time=np.asarray(times, np.float32),
+        arr_ingress=np.full(f, ingress, np.int32), arr_dr=dr,
+        arr_duration=(rng.uniform(1.0, 30.0, f)).astype(np.float32),
+        arr_ttl=np.full(f, 100.0, np.float32),
+        arr_sfc=np.zeros(f, np.int32), arr_egress=np.full(f, -1, np.int32))
+
+
+def _hand_table(cfg, topo, times, pad=0, seed=0):
+    """A schedule from hand-made arrival times, ``pad`` unused records
+    (time inf) behind them."""
+    from gsc_tpu.sim.state import TrafficSchedule
+
+    rec = _records(np.random.default_rng(seed), np.sort(times))
+    fill = dict(arr_time=np.inf, arr_egress=-1)
+    rec = {k: np.concatenate([v, np.full(pad, fill.get(k, 0), v.dtype)])
+           for k, v in rec.items()}
+    steps = ARRIVAL_INTERVALS + 4
+    return TrafficSchedule.pack(
+        ingress_active=jnp.ones((steps, N), bool),
+        node_cap=jnp.broadcast_to(topo.node_cap, (steps, N)), **rec)
+
+
+ARRIVAL_SCENARIOS = {
+    # one ingress, a flow every 10 ms (the benchmark cells' arrivals)
+    "deterministic": dict(cfg=dict(inter_arrival_mean=10.0)),
+    # exponential gaps of mean 0.7 ms: several records due in one substep
+    "poisson": dict(cfg=dict(inter_arrival_mean=0.7,
+                             deterministic_arrival=False)),
+    # 30 records due in substep 3, 20 more in substep 31: each spills over
+    # the 8 a substep admits, the later records counted as truncated
+    "burst_spills": dict(times=[3.0] * 30 + [31.0] * 20 + [45.0, 52.5],
+                         pad=40),
+    # two records a millisecond held 4 substeps each want 8 slots of 6:
+    # arrivals wait for a slot, the cursor stalls, and catches up
+    "slot_exhaustion": dict(times=np.arange(0, 40, 0.5), pad=30,
+                            cfg=dict(max_flows=6)),
+    # every record of the table is real: the cursor reaches its capacity
+    # in mid-interval and the run reads into the padding behind it
+    "table_end": dict(times=np.arange(0, 37, 0.25), pad=0),
+    # 100-substep intervals, ten records a millisecond: the cursor moves
+    # the 8 a substep allows, 800 an interval, across the whole window
+    "full_window": dict(times=np.arange(0, 250, 0.1), pad=60,
+                        cfg=dict(run_duration=100.0), intervals=3),
+    # 100-substep intervals over a table shorter than one interval's
+    # window: the window is the whole table
+    "short_table": dict(times=np.arange(0, 250, 2.5), pad=3,
+                        cfg=dict(run_duration=100.0), intervals=3),
+}
+
+
+def arrival_statement(traffic, max_flows, substeps, intervals):
+    """Stage 3 in plain numpy float32: after each interval the cursor, the
+    counters, the interval's ``run_requested_node``, the slots in use and
+    the last record that landed in each slot."""
+    f32, eps, dt = np.float32, np.float32(1e-4), np.float32(1.0)
+    time = np.asarray(traffic.arr_time)
+    ing = np.asarray(traffic.arr_ingress)
+    dr = np.asarray(traffic.arr_dr)
+    cursor = generated = truncated = 0
+    busy_until = np.full(max_flows, -1)
+    landed = np.full(max_flows, -1)
+    t = f32(0.0)
+    out = []
+    for g in range(substeps * intervals):
+        if g % substeps == 0:
+            req_node = np.zeros(N, f32)
+        free = [s for s in range(max_flows) if busy_until[s] < g]
+        for slot, r in zip(free, range(cursor, cursor + 8)):
+            if not (r < len(time) and np.isfinite(time[r])
+                    and time[r] < f32(t + dt) - eps):
+                break
+            truncated += bool(time[r] < t - eps)
+            req_node[ing[r]] += dr[r]
+            busy_until[slot], landed[slot] = g + ARRIVAL_HOLD - 1, r
+            cursor += 1
+            generated += 1
+        t = f32(t + dt)
+        if (g + 1) % substeps == 0:
+            out.append(dict(cursor=cursor, generated=generated,
+                            truncated=truncated, req_node=req_node.copy(),
+                            in_use=busy_until > g, landed=landed.copy()))
+    return out
+
+
+@pytest.mark.parametrize("mode", ["unbatched", "vmap_own_cursor"])
+@pytest.mark.parametrize("scenario", sorted(ARRIVAL_SCENARIOS))
+def test_arrival_cursor_matches_plain_statement(base, scenario, mode):
+    """Which record spawns at which substep into which slot, ``generated``,
+    ``truncated_arrivals``, ``run_requested_node`` and the cursor equal the
+    plain statement bit for bit after every interval — alone, and as the
+    rows of a ``jax.vmap`` batch whose replicas run whole intervals apart,
+    so that each reads the table at a cursor of its own."""
+    service, limits = base
+    spec = ARRIVAL_SCENARIOS[scenario]
+    cfg = make_cfg(**{"run_duration": float(ARRIVAL_SUBSTEPS),
+                      **spec.get("cfg", {})})
+    topo = line_topo(link_cap=1e6)
+    engine = SimEngine(service, cfg, limits)
+    intervals = spec.get("intervals", ARRIVAL_INTERVALS)
+    ahead = (0,) if mode == "unbatched" else (0, 1, 2, 3)
+    if "times" in spec:
+        traffic = _hand_table(cfg, topo, spec["times"], spec["pad"])
+    else:
+        traffic = generate_traffic(cfg, service, topo,
+                                   episode_steps=intervals + max(ahead),
+                                   seed=1)
+    want = arrival_statement(traffic, cfg.max_flows, engine.substeps,
+                             intervals + max(ahead))
+    assert want[-1]["generated"] > 0
+    if scenario in ("burst_spills", "slot_exhaustion", "full_window"):
+        assert want[-1]["truncated"] > 0
+    if scenario == "full_window":
+        assert want[0]["cursor"] == 8 * engine.substeps
+    if scenario == "table_end":
+        assert want[-1]["cursor"] == traffic.capacity
+    sched = schedule_all_to(limits, 1)
+    place = placement_at(limits, [])
+
+    def interval(state):
+        return engine.apply.__wrapped__(engine, state, topo, traffic, sched,
+                                        place)[0]
+
+    def check(state, done):
+        ref = want[done - 1]
+        note = f"after {done} intervals"
+        assert int(state.cursor) == ref["cursor"], note
+        assert int(state.metrics.generated) == ref["generated"], note
+        assert int(state.truncated_arrivals) == ref["truncated"], note
+        np.testing.assert_array_equal(
+            np.asarray(state.metrics.run_requested_node), ref["req_node"],
+            err_msg=note)
+        np.testing.assert_array_equal(
+            np.asarray(state.flows.phase) != 0, ref["in_use"], err_msg=note)
+        hit = ref["landed"] >= 0
+        for name in ("arr_dr", "arr_duration", "arr_sfc"):
+            got = np.asarray(getattr(state.flows, name[4:]))
+            rec = np.asarray(getattr(traffic, name))[ref["landed"][hit]]
+            np.testing.assert_array_equal(got[hit], rec,
+                                          err_msg=f"{name} {note}")
+            assert not got[~hit].any(), note
+
+    starts = []
+    for k in ahead:
+        state = engine.init(jax.random.PRNGKey(0), topo)
+        for _ in range(k):
+            state = engine.apply(state, topo, traffic, sched, place)[0]
+        starts.append(state)
+    if mode == "unbatched":
+        state = starts[0]
+        for k in range(1, intervals + 1):
+            state = engine.apply(state, topo, traffic, sched, place)[0]
+            check(state, k)
+        return
+    states = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *starts)
+    step = jax.jit(jax.vmap(interval))
+    for k in range(1, intervals + 1):
+        states = step(states)
+        for b, k0 in enumerate(ahead):
+            check(jax.tree_util.tree_map(lambda x: x[b], states), k0 + k)
 
 
 # ------------------------------------------------- the program shape the chip runs
@@ -681,14 +900,20 @@ def test_scan_unroll_bit_identical(base):
 # --------------------------------------------------------- fusion budget
 # Pinned compiled-HLO fusion count of the flagship-interval engine.apply
 # (abc service, Abilene limits 24/37, M=128, 100 substeps) on the CPU
-# backend, jaxlib 0.9.0: 276 (273 when re-measured and re-pinned in PR 21;
+# backend, jaxlib 0.9.0: 279 (273 when re-measured and re-pinned in PR 21;
 # the same program counted 191 under the previous jaxlib — the compiler's
 # fusion decisions moved, the engine did not).  PR 29 re-pinned 273 -> 276:
 # stage 1 of the substep reads and clears the release rings' due row
 # through a mask over the whole ring instead of by index, which the CPU
 # compiler counts as three more fusions of this unbatched program — while
 # the TPU's vmapped program loses four whole-ring layout copies and two
-# scatters per substep.  The budget adds NO headroom on purpose — a
+# scatters per substep.  PR 31 re-pinned 276 -> 279: stage 3 fetches its
+# eight candidate records through masks (the interval's window of the
+# arrival table once per interval, the run out of it per substep) in place
+# of seven per-field gathers and the rank gather, which this unbatched CPU
+# program counts as three more fusions — while the TPU's vmapped program
+# loses eight serial gathers per substep and half its operations
+# (`substep_device_ops` 612 -> 310).  The budget adds NO headroom on purpose — a
 # 281->294-style regression (the round-5 scatter-merge: bit-exact, yet
 # slower) is ~+13, so any slack would swallow exactly the class of change
 # this gate exists to catch.  If a toolchain upgrade moves the count,
@@ -698,7 +923,7 @@ def test_scan_unroll_bit_identical(base):
 # What the pin protects: the engine's op count as the CPU compiler sees it
 # — a proxy, not the chip's count (the TPU compiler fuses differently; the
 # benchmark's `substep_device_ops` is the chip's own).
-FUSION_BUDGET = 276
+FUSION_BUDGET = 279
 
 
 def _flagship_interval_compiled():

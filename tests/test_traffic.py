@@ -94,3 +94,92 @@ def test_select_best_agent(tmp_path):
     assert best.endswith("b")
     with pytest.raises(ValueError):
         select_best_agent([str(tmp_path / "missing")])
+
+
+# ------------------------------------------------- the packed arrival table
+def _host_records(native):
+    def build(monkeypatch):
+        if not native:
+            monkeypatch.setattr("gsc_tpu.native.generate_flows_native",
+                                lambda **kw: None)
+        cfg = SimConfig(ttl_choices=(100.0, 200.0),
+                        deterministic_arrival=False)
+        return generate_traffic(cfg, service(), topo(2), episode_steps=3,
+                                seed=5, capacity=300)
+    return build
+
+
+def _device_records(monkeypatch):
+    import jax
+
+    from gsc_tpu.sim.traffic_device import DeviceTraffic
+
+    cfg = SimConfig(ttl_choices=(100.0, 200.0), deterministic_arrival=False)
+    sampler = DeviceTraffic(cfg, service(), topo(2), 3, capacity=300)
+    return sampler.sample(jax.random.PRNGKey(5))
+
+
+def _factory_records(monkeypatch):
+    import jax
+    import jax.numpy as jnp
+
+    import __graft_entry__ as ge
+    from gsc_tpu.topology.factory import ScenarioFactory, parse_factory
+
+    env, _, _, _ = ge._flagship(max_nodes=8, max_edges=8, episode_steps=2,
+                                max_flows=32)
+    factory = ScenarioFactory(parse_factory("factory:star-line+shapes"),
+                              env.sim_cfg, env.service, 2, max_nodes=8,
+                              max_edges=8)
+    return factory.sample(jax.random.PRNGKey(5), jnp.full((2,), 0.5))[1]
+
+
+@pytest.mark.parametrize("producer", [
+    pytest.param(_host_records(native=True), id="host_native"),
+    pytest.param(_host_records(native=False), id="host_numpy"),
+    pytest.param(_device_records, id="device_sampler"),
+    pytest.param(_factory_records, id="scenario_factory"),
+])
+def test_producer_records_come_through_the_packed_table(producer,
+                                                        monkeypatch):
+    """Each of the four producers builds its schedule through
+    ``TrafficSchedule.pack``, and the per-field views give back the very
+    records it handed over: bit for bit, in their dtypes, at the capacity
+    asked for, sorted by time, with the table's padding behind them."""
+    from gsc_tpu.sim.state import (ARRIVAL_LANES, ARRIVAL_RUN,
+                                   TrafficSchedule)
+
+    handed = []
+    pack = TrafficSchedule.pack.__func__
+
+    def spy(cls, **kw):
+        handed.append({k: np.asarray(v) for k, v in kw.items()
+                       if k.startswith("arr_")})
+        return pack(cls, **kw)
+
+    monkeypatch.setattr(TrafficSchedule, "pack", classmethod(spy))
+    tr = producer(monkeypatch)
+    records = handed[-1]     # (building the factory's env packs one too)
+    assert sorted(records) == ["arr_dr", "arr_duration", "arr_egress",
+                               "arr_ingress", "arr_sfc", "arr_time",
+                               "arr_ttl"]
+    capacity = tr.capacity
+    rows = -(-(capacity + ARRIVAL_RUN) // ARRIVAL_LANES)
+    assert tr.arr.shape == (7, rows, ARRIVAL_LANES)
+    assert tr.arr.dtype == np.int32
+    times = np.asarray(tr.arr_time)
+    assert np.isfinite(times).sum() > 10
+    assert (np.diff(times[np.isfinite(times)]) >= 0).all()
+    assert not np.isfinite(times[np.isfinite(times).sum():]).any()
+    for name, raw in records.items():
+        view = np.asarray(getattr(tr, name))
+        want = np.float32 if name in ("arr_time", "arr_dr", "arr_duration",
+                                      "arr_ttl") else np.int32
+        assert view.dtype == want and view.shape == (capacity,), name
+        assert view.tobytes() == raw.astype(want).tobytes(), name
+    # behind the records: never due, no egress, zeros
+    table = np.asarray(tr.arr).reshape(7, -1)[:, capacity:]
+    fills = [np.float32(np.inf).view(np.int32), 0, 0, 0, 0, 0, -1]
+    np.testing.assert_array_equal(
+        table, np.broadcast_to(np.asarray(fills, np.int32)[:, None],
+                               table.shape))
