@@ -3,13 +3,15 @@
 What the timed path produced — the first episode, driven in set-up through
 the same compiled ``chunk_step`` programs and the same learner object that
 the window then drives, and the policy-driven actions of the window's own
-last episode — is compared with the plain references (``reference/ddpg.py``,
-``reference/flowsim.py``) once the window has closed and the program's
-device state is freed.  Each number compared has a limit of its own, kept in the
-cell's workload file; ``PERF.md`` gives the readings each was set from.  A
-number for which a cell's file gives no limit is not compared in that cell
-(it is printed on an earlier line); ``PERF.md`` names each such number with
-its readings and the reason.
+last episode — is compared with the plain references (the policy's and the
+learner's is the module the configuration names, ``reference/<name>.py``,
+handed in here as ``ref``; the simulator's is ``reference/flowsim.py``)
+once the window has closed and the program's device state is freed.  Each
+number compared has a limit of its own, kept in the cell's workload file;
+``PERF.md`` gives the readings each was set from.  A number for which a
+cell's file gives no limit is not compared in that cell (it is printed on
+an earlier line); ``PERF.md`` names each such number with its readings and
+the reason.
 
 Numbers (all "lower is closer"; a number whose inputs are missing reads
 ``inf`` and fails):
@@ -47,6 +49,10 @@ Numbers (all "lower is closer"; a number whose inputs are missing reads
   the burst, against the reference's norm of that leaf or of the median
   leaf, whichever is larger.  Leaves whose reference moment is under a
   thousandth of the median leaf's are left out of ``change_gap``.
+- ``moment_mid_gap``: the per-leaf gap of Adam's first moment, as in
+  ``moment_gap``, by the median leaf instead of the worst: what holds
+  where a single small leaf's noise swings the worst one from seed to
+  seed (PERF.md section 2 has both readings).
 - ``moment2_mid_gap``: the same per-leaf gap of Adam's second moment, by
   the median leaf.  After a long burst the worst leaf of the numbers
   above swings with the later steps' noise from seed to seed (PERF.md
@@ -74,8 +80,8 @@ import numpy as np
 
 NUMBERS = ("episodes_not_finite", "ring_rows_off", "return_gap",
            "action_gap", "obs_gap", "reward_gap", "features_gap",
-           "policy_action_gap", "td_gap", "moment_gap", "change_gap",
-           "moment2_mid_gap")
+           "policy_action_gap", "td_gap", "moment_gap", "moment_mid_gap",
+           "change_gap", "moment2_mid_gap")
 SIM_REPLICAS = 6
 NEAR_TIE = 1e-4
 REF_ROW_KEYS = ("obs/", "next_obs/", "action", "reward", "done")
@@ -86,15 +92,15 @@ def rel(a: float, b: float, floor: float = 1e-6) -> float:
 
 
 def leaf_gaps(prog: Dict[str, np.ndarray], ref: Dict[str, np.ndarray],
-              keep: Optional[Dict[str, bool]] = None) -> list:
+              keep: Optional[Dict[str, bool]] = None) -> Dict[str, float]:
     """Per leaf, the gap between the two norms against the larger of the
     reference's norm of that leaf and of its median leaf."""
     names = sorted(ref)
     rn = {k: float(np.linalg.norm(ref[k])) for k in names}
     med = float(np.median(list(rn.values())))
-    return [abs(float(np.linalg.norm(prog[k])) - rn[k])
+    return {k: abs(float(np.linalg.norm(prog[k])) - rn[k])
             / max(rn[k], med, 1e-30)
-            for k in names if keep is None or keep[k]]
+            for k in names if keep is None or keep[k]}
 
 
 def learner_numbers(prog: dict, ref: dict, weights: Dict[str, np.ndarray]
@@ -107,13 +113,15 @@ def learner_numbers(prog: dict, ref: dict, weights: Dict[str, np.ndarray]
             for k, v in ref["mu"].items()}
     change = lambda side: {k: np.asarray(side["params"][k]) - weights[k]
                            for k in weights}
-    moment = leaf_gaps(prog["mu"], ref["mu"])
-    moved = leaf_gaps(change(prog), change(ref), keep)
+    moment = list(leaf_gaps(prog["mu"], ref["mu"]).values())
+    moved = leaf_gaps(change(prog), change(ref), keep).values()
+    moment2 = list(leaf_gaps(prog["nu"], ref["nu"]).values())
     return {
         "td_gap": rel(prog["td_abs_mean"], ref["td_abs_mean"]),
-        "moment_gap": max(moment), "change_gap": max(moved),
-        "moment2_mid_gap": float(np.median(leaf_gaps(prog["nu"],
-                                                     ref["nu"]))),
+        "moment_gap": max(moment),
+        "moment_mid_gap": float(np.median(moment)),
+        "change_gap": max(moved),
+        "moment2_mid_gap": float(np.median(moment2)),
     }
 
 
@@ -140,13 +148,13 @@ def program_side(after: Dict[str, np.ndarray], events: list) -> dict:
             "td_abs_mean": float(sig.get("td_abs_mean") or nan)}
 
 
-def reference_side(cfg: dict, weights: Dict[str, np.ndarray], rng, rows,
-                   replicas: int, steps: int, matmul: str = "highest",
+def reference_side(ref, cfg: dict, weights: Dict[str, np.ndarray], rng,
+                   rows, replicas: int, steps: int, matmul: str = "highest",
                    half_batch: bool = False) -> dict:
-    """The reference's readings of the same burst (``rows`` already on the
-    device, ``rng`` the learner key as it stands after the rollouts)."""
+    """The reference's readings of the same burst (``ref`` the
+    configuration's reference module, ``rows`` already on the device,
+    ``rng`` the learner key as it stands after the rollouts)."""
     import jax.numpy as jnp
-    from benchmarks.reference import ddpg as ref
 
     spec = ref.spec_from_config(cfg)
     st, out = ref.learn_burst(
@@ -171,12 +179,11 @@ def sim_sample(seed: int, replicas: int, k: int = SIM_REPLICAS):
                              replace=False).tolist())
 
 
-def rollout_numbers(record: dict, rows, rng, node_mask, net_spec):
+def rollout_numbers(ref, record: dict, rows, rng, node_mask, net_spec):
     """``return_gap``, ``action_gap``, ``obs_gap``, ``reward_gap``,
     ``features_gap`` and the learner key after the first episode's
     rollouts."""
     import jax.numpy as jnp
-    from benchmarks.reference import ddpg as ref
 
     cfg = record["config"]
     spec = ref.spec_from_config(cfg)
@@ -224,14 +231,13 @@ def rollout_numbers(record: dict, rows, rng, node_mask, net_spec):
     return out, rng_after
 
 
-def policy_numbers(record: dict, policy: Optional[dict], rng,
+def policy_numbers(ref, record: dict, policy: Optional[dict], rng,
                    matmul: str = "highest") -> Dict[str, float]:
     """``policy_action_gap`` from what the driver kept of the window's
     last episode (``policy``: its index, the sampled replicas, their rows
     and the actor parameters that drove it), and how many destination
     rows were compared and left out as near-ties."""
     import jax.numpy as jnp
-    from benchmarks.reference import ddpg as ref
 
     if policy is None:
         return {"policy_action_gap": float("inf")}
@@ -277,10 +283,11 @@ def accounting(record: dict, final: Optional[dict]):
     return out, failed
 
 
-def decide(record: dict, limits: Dict[str, float], weights, rng, rows,
+def decide(record: dict, limits: Dict[str, float], ref, weights, rng, rows,
            after, final, node_mask, net_spec, policy=None,
            log: Callable = print) -> dict:
-    """``correct`` with every number compared beside its limit, every
+    """``correct`` (``ref``: the reference module the cell's configuration
+    names) with every number compared beside its limit, every
     number read (``values``), the learner key after the first episode's
     rollouts (``rng_after``), ``attempted`` and ``failed``."""
     values = {k: float("inf") for k in NUMBERS}
@@ -290,20 +297,21 @@ def decide(record: dict, limits: Dict[str, float], weights, rng, rows,
         failed += 1
     rng_after = None
     if rows is not None and after is not None:
-        roll, rng_after = rollout_numbers(record, rows, rng, node_mask,
+        roll, rng_after = rollout_numbers(ref, record, rows, rng, node_mask,
                                           net_spec)
         log("live_flows_peak", roll.pop("live_flows_peak"), "of",
             record["config"]["simulator"]["max_flows"], "slots")
         values.update(roll)
-        pol = policy_numbers(record, policy, rng)
+        pol = policy_numbers(ref, record, policy, rng)
         values["policy_action_gap"] = pol.pop("policy_action_gap")
         log("policy rows", pol)
         dev_rows = rows_to_device(rows)
-        ref = reference_side(record["config"], weights, rng_after, dev_rows,
-                             record["replicas"], record["episode_steps"])
+        want = reference_side(ref, record["config"], weights, rng_after,
+                              dev_rows, record["replicas"],
+                              record["episode_steps"])
         prog = program_side(after, record["events"])
-        values.update(learner_numbers(prog, ref, weights))
-        log("reference", {k: ref[k] for k in
+        values.update(learner_numbers(prog, want, weights))
+        log("reference", {k: want[k] for k in
                           ("critic_loss", "actor_loss", "td_abs_mean")})
         log("program", {k: prog[k] for k in
                         ("critic_loss", "actor_loss", "td_abs_mean")})

@@ -29,7 +29,6 @@ def compile_cell(name: str, topology: str = "v5e:2x2") -> dict:
     from jax.sharding import SingleDeviceSharding
 
     from benchmarks import harness
-    from benchmarks.drivers import train_parallel as drv
     from gsc_tpu.cli import _build
     from gsc_tpu.obs.learning import LearnLedgerSpec
     from gsc_tpu.parallel import ParallelDDPG
@@ -37,6 +36,7 @@ def compile_cell(name: str, topology: str = "v5e:2x2") -> dict:
 
     cell = harness.load_cell(name)
     cfg, wl = cell["config"], cell["cell"]
+    drv = harness.load_driver(cell)
     jax.config.update("jax_default_matmul_precision",
                       cfg["matmul_precision"])
     jax.config.update("jax_enable_compilation_cache", False)

@@ -19,7 +19,10 @@ reference put in the program's place,
   itself and needs no run.
 
 Prints one JSON line per seed: the program's numbers, the control's, the
-faults'.  The benchmark's own runs never run this.
+faults', and under ``moment_leaves`` the look behind the worst-leaf
+``moment_gap``: the four leaves the program reads farthest off, each with
+its norm against the median leaf's and every side's gap.  The benchmark's
+own runs never run this.
 
     python3 benchmarks/control.py --workload flagship-b256 --seeds 11,12,13
 """
@@ -39,43 +42,63 @@ from benchmarks import check, harness  # noqa: E402
 PARTS = ("learner", "policy", "answers", "sim")
 
 
-def probe(record, weights, rng, rows, after, final, node_mask, net_spec,
+def probe(record, ref, weights, rng, rows, after, final, node_mask, net_spec,
           policy=None, parts=PARTS):
+    """``ref`` is the reference module the cell's configuration names, as
+    the driver handed it to ``check.decide``."""
     import numpy as np
 
     out = {}
     if "learner" in parts:
         dev_rows = check.rows_to_device(rows)
-        args = (record["config"], weights, record["rng_after"], dev_rows,
-                record["replicas"], record["episode_steps"])
-        ref = check.reference_side(*args)
+        args = (ref, record["config"], weights, record["rng_after"],
+                dev_rows, record["replicas"], record["episode_steps"])
+        want = check.reference_side(*args)
+        sides = {"program": check.program_side(after, record["events"])}
         for name, kw in (("control_high", {"matmul": "high"}),
                          ("control_bfloat16", {"matmul": "bfloat16"}),
                          ("fault_half_batch", {"half_batch": True})):
-            out[name] = check.learner_numbers(
-                check.reference_side(*args, **kw), ref, weights)
+            sides[name] = check.reference_side(*args, **kw)
+            out[name] = check.learner_numbers(sides[name], want, weights)
+        out["moment_leaves"] = worst_leaves(sides, want)
         del dev_rows
     if "policy" in parts and policy is not None:
         # the policy branch: the reference's actor forward below float32
         # against the stored actions, and a stored action altered
         for name in ("control_high", "control_bfloat16"):
             out.setdefault(name, {}).update(check.policy_numbers(
-                record, policy, rng, matmul=name.split("_")[1]))
+                ref, record, policy, rng, matmul=name.split("_")[1]))
         moved = dict(policy, rows=dict(
             policy["rows"],
             action=policy["rows"]["action"] * np.float32(1.001)))
         out["fault_policy_action_altered"] = check.policy_numbers(
-            record, moved, rng)
+            ref, record, moved, rng)
     if "answers" in parts:
         altered = dict(rows)
         altered["action"] = rows["action"] * np.float32(1.001)
         altered["reward"] = rows["reward"] * np.float32(1.001)
         out["fault_answer_altered"], _ = check.rollout_numbers(
-            record, altered, rng, node_mask, net_spec)
+            ref, record, altered, rng, node_mask, net_spec)
     if "sim" in parts:
         out["fault_sim_half_rate"] = sim_fault(record, rows, rng, node_mask,
                                                net_spec)
     return out
+
+
+def worst_leaves(sides, want, k=4):
+    """The look behind a worst-leaf number: the ``k`` leaves whose first
+    moment the program reads farthest from the reference's, each with its
+    reference norm against the median leaf's and every side's gap."""
+    import numpy as np
+
+    gaps = {name: check.leaf_gaps(side["mu"], want["mu"])
+            for name, side in sides.items()}
+    norm = {leaf: float(np.linalg.norm(v)) for leaf, v in want["mu"].items()}
+    med = float(np.median(list(norm.values())))
+    worst = sorted(norm, key=lambda leaf: -gaps["program"][leaf])[:k]
+    return {leaf: {"norm_over_median": norm[leaf] / max(med, 1e-30),
+                   **{name: g[leaf] for name, g in gaps.items()}}
+            for leaf in worst}
 
 
 def sim_fault(record, rows, rng, node_mask, net_spec):
@@ -132,7 +155,7 @@ def main(argv=None) -> int:
     if a.program_precision:
         cell["config"]["precision"] = a.program_precision
     peaks = harness.load_peaks()
-    driver = harness.load_module("drivers", cell["cell"]["driver"])
+    driver = harness.load_driver(cell)
     driver.prepare(cell)
     device = harness.require_device(int(cell["cell"]["chips"]), peaks)
     for seed in (int(s) for s in a.seeds.split(",")):

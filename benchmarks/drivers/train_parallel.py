@@ -14,13 +14,19 @@ parameters carry the benchmark:
   path), so that the plain reference starts from the same weights without
   taking anything the program made;
 - ``ckpt_manager`` with ``ckpt_interval=1``: a recorder that, once, at the
-  end of the first episode, keeps a device copy of the learner state and a
-  host copy of that episode's replay rows for the output check; at every
-  call it keeps a host copy of the actor parameters alone (a megabyte, as
-  the loop's own finite check has just copied the whole learner state;
-  no device operation), so that the parameters which drove the window's
-  last episode are at hand when the window has closed.  Nothing is
-  written to disk.
+  end of the first episode (in set-up), keeps a host copy of the learner
+  state and of that episode's replay rows for the output check; at every
+  call it keeps a host copy of the actor parameters alone (the loop's own
+  finite check has just copied the whole learner state; no device
+  operation), so that the parameters which drove the window's last episode
+  are at hand when the window has closed.  What that copy costs is printed
+  per episode (``recorder_actor_s``).  Nothing stays on the device and
+  nothing is written to disk.
+
+What depends on the policy's architecture — the plain reference the output
+check follows, the model FLOPs ``step_mfu_pct`` divides and the weights
+made from the seed — comes from the module the configuration names
+(``"reference"``, ``harness.load_reference``), never from an import here.
 """
 from __future__ import annotations
 
@@ -30,7 +36,6 @@ import shutil
 import sys
 import tempfile
 import time
-import zlib
 from typing import Callable, Dict
 
 CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -40,7 +45,8 @@ if CHECKOUT not in sys.path:
 
 RESERVED = ("source", "deployment", "reduced", "published", "assumed",
             "network", "simulator", "service", "scheduler", "max_nodes",
-            "max_edges", "matmul_precision", "factored_head_threshold")
+            "max_edges", "matmul_precision", "factored_head_threshold",
+            "reference")
 EPISODES_CAP = 1_000_000   # the window stops the loop, never this count
 
 
@@ -108,33 +114,12 @@ def leaf_table(tree) -> Dict[str, object]:
             for p, l in jax.tree_util.tree_flatten_with_path(tree)[0]}
 
 
-def make_weights(seed: int, shapes: Dict[str, tuple]):
-    """Every network leaf from the seed in one jitted call on the device:
-    matrices Glorot-uniform from their own shape, vectors zero (the
-    initialisers of the program's modules, keyed here by leaf name so the
-    values do not depend on the program's own key schedule)."""
-    import jax
-    import jax.numpy as jnp
-
-    def make(key):
-        out = {}
-        for name, shape in sorted(shapes.items()):
-            if len(shape) < 2:
-                out[name] = jnp.zeros(shape, jnp.float32)
-                continue
-            k = jax.random.fold_in(key, zlib.crc32(name.encode()) & 0x7FFFFFFF)
-            lim = (6.0 / (shape[0] + shape[1])) ** 0.5
-            out[name] = jax.random.uniform(k, shape, jnp.float32, -lim, lim)
-        return out
-
-    return jax.jit(make)(jax.random.PRNGKey(seed))
-
-
-def make_init_state(seed: int, state_shape):
+def make_init_state(seed: int, state_shape, init_weights: Callable):
     """A ``DDPGState`` of the program's layout filled from the seed:
-    online and target networks from :func:`make_weights`, optimiser
-    moments and counts zero, the learner key folded from the seed.
-    Returns (state, weights by reference name, learner key)."""
+    online and target networks from the reference's ``init_weights(seed,
+    shapes)``, optimiser moments and counts zero, the learner key folded
+    from the seed.  Returns (state, weights by reference name, learner
+    key)."""
     import jax
     import jax.numpy as jnp
 
@@ -147,7 +132,7 @@ def make_init_state(seed: int, state_shape):
         head, _, rest = name.partition("/")
         if head in ("actor_params", "critic_params"):
             shapes[f"{nets[head]}/{rest}"] = tuple(leaf.shape)
-    weights = make_weights(seed, shapes)
+    weights = init_weights(seed, shapes)
     rng = jax.random.fold_in(jax.random.PRNGKey(seed), 7)
     leaves = []
     for name, (_, leaf) in zip(names, flat):
@@ -199,11 +184,13 @@ def ring_rows(buffers, index, prefixes=("",)):
 
 class Recorder:
     """Stands where ``cli train`` puts its ``CheckpointManager``.  At the
-    end of episode 0 it keeps what the output check follows: a device copy
-    of the learner state and a host copy of the episode's replay rows.  At
-    every call it keeps a host copy of the actor parameters (the last two
-    calls' only; a device copy would put operations after the program's
-    last into the traced slice), and stamps its entry: the loop's finite check of the
+    end of episode 0, which lies in set-up, it keeps what the output check
+    follows as host copies: the learner state and the episode's replay
+    rows (a device copy of the state would hold every trained parameter
+    twice for the whole run).  At every call it keeps a host copy of the
+    actor parameters (the last two calls' only; a device copy would put
+    operations after the program's last into the traced slice) with the
+    seconds it took, and stamps its entry: the loop's finite check of the
     learner state, which the checkpoint cadence brings, lies between the
     episode's event and that stamp.  Nothing is written to disk."""
 
@@ -213,19 +200,20 @@ class Recorder:
         self.rows = None
         self.seconds = 0.0
         self.actor = {}          # save number -> host copy, last two
+        self.actor_s = {}        # save number -> seconds that copy took
         self.entered = {}        # save number -> host clock at entry
 
     def save(self, state, buffers, episode: int, **_):
         import jax
-        import jax.numpy as jnp
 
         self.entered[episode] = time.time()
         self.actor[episode] = jax.device_get(state.actor_params)
         self.actor.pop(episode - 2, None)
+        self.actor_s[episode] = time.time() - self.entered[episode]
         if episode != 1 or self.state is not None:
             return None
         t0 = time.time()
-        self.state = jax.tree_util.tree_map(jnp.copy, state)
+        self.state = jax.device_get(state)
         self.rows = ring_rows(buffers, (slice(None), slice(self.steps)))
         self.seconds = time.time() - t0
         return None
@@ -308,18 +296,21 @@ def run(cell: dict, seed: int, seconds: float, traced: bool,
     ``phases``, ``harness_episode``, ``learn_signal``, ``compile``,
     ``recovery``); ``memory_peak_bytes``; ``trace`` (``trace.reduce``'s
     result, traced runs only); ``correct compared values rng_after
-    attempted failed error`` (the output check).  ``probe`` (``control.py``) is handed the
-    check's inputs afterwards; a benchmark run passes none."""
+    attempted failed error`` (the output check).  ``probe``
+    (``control.py``) is handed the check's inputs afterwards, the
+    configuration's reference module among them; a benchmark run passes
+    none."""
     import jax
     import numpy as np
 
-    from benchmarks import check, flops
-    from benchmarks.harness import Window, memory_peak_bytes
+    from benchmarks import check
+    from benchmarks.harness import Window, load_reference, memory_peak_bytes
     from gsc_tpu.agents.trainer import Trainer
     from gsc_tpu.cli import _build
     from gsc_tpu.obs import RunObserver
 
     cfg, wl = cell["config"], cell["cell"]
+    ref = load_reference(cell)
     replicas, chunk = int(wl["replicas"]), int(wl["chunk"])
     steps = int(cfg["episode_steps"])
     warm = -(-int(cfg["nb_steps_warmup_critic"]) // steps)
@@ -333,7 +324,7 @@ def run(cell: dict, seed: int, seconds: float, traced: bool,
               "chunk": chunk, "episode_steps": steps,
               "warm_episodes": warm, "t_start": t_start, "config": cfg,
               "peaks": peaks, "trace": None,
-              "flops": flops.model_flops(cfg)}
+              "flops": ref.model_flops(cfg)}
     obs = None
     error = None
     try:
@@ -357,7 +348,8 @@ def run(cell: dict, seed: int, seconds: float, traced: bool,
                                       topo0, traffic0)
         state_shape = jax.eval_shape(trainer.ddpg.init,
                                      jax.random.PRNGKey(0), obs_shape)
-        init_state, weights, rng0 = make_init_state(seed, state_shape)
+        init_state, weights, rng0 = make_init_state(seed, state_shape,
+                                                    ref.init_weights)
         weights_host = {k: np.asarray(v) for k, v in weights.items()}
         rng0_host = np.asarray(rng0)
         node_mask = np.asarray(topo0.node_mask)
@@ -406,7 +398,6 @@ def run(cell: dict, seed: int, seconds: float, traced: bool,
             after = {k: np.asarray(v)
                      for k, v in leaf_table(recorder.state).items()}
         # free the program's device state before the reference runs
-        recorder.state = None
         del state, buffers, init_state, weights, trainer
         obs.close(status="preempted" if error is None else "error")
         obs = None
@@ -424,6 +415,8 @@ def run(cell: dict, seed: int, seconds: float, traced: bool,
         log("ckpt_check_s", json.dumps(
             {k: round(recorder.entered[k + 1] - ended[k], 3)
              for k in sorted(ended) if k + 1 in recorder.entered}))
+        log("recorder_actor_s", json.dumps(
+            {k - 1: round(v, 4) for k, v in sorted(recorder.actor_s.items())}))
         if traced and tracer.started and tracer.stopped:
             from benchmarks import trace as trace_mod
             t0 = time.time()
@@ -435,7 +428,7 @@ def run(cell: dict, seed: int, seconds: float, traced: bool,
                 "events": record["trace"]["n_events"],
                 "last_loops": record["trace"]["top_level_loops"][-4:]}))
         t_check = time.time()
-        inputs = dict(weights=weights_host, rng=rng0_host,
+        inputs = dict(ref=ref, weights=weights_host, rng=rng0_host,
                       rows=recorder.rows, after=after, final=final,
                       node_mask=node_mask, net_spec=paths["spec"],
                       policy=policy)

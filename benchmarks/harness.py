@@ -6,8 +6,10 @@ Driven by data: a cell is ``workloads/<cell>.json`` (its configuration,
 its driver, its traffic parameters and the limits of its output check), a
 configuration is ``configs/<name>.json``, a metric is
 ``metrics/<name>.py`` with one ``read(record)`` function, a driver is
-``drivers/<name>.py`` with one ``run(...)`` function.  Nothing here
-switches on a cell's or a configuration's name.
+``drivers/<name>.py`` with one ``run(...)`` function, a plain reference
+is ``reference/<name>.py``, named by the configuration's ``reference``
+key and exporting ``CONTRACT``.  Nothing here switches on a cell's, a
+configuration's or a reference's name.
 """
 from __future__ import annotations
 
@@ -21,13 +23,17 @@ from typing import Callable, Dict, List, Optional
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
 CHECKOUT = os.path.dirname(ROOT)
+# what a policy's plain reference module exports (README: the contract)
+CONTRACT = ("spec_from_config", "warmup_actions", "advance_key",
+            "policy_actions", "learn_burst", "expected_static_columns",
+            "model_flops", "init_weights")
 
 
 # ------------------------------------------------------------------ files
 def load_json(kind: str, name: str, root: str = ROOT) -> dict:
     path = os.path.join(root, kind, f"{name}.json")
     if not os.path.isfile(path):
-        raise SystemExit(f"benchmark: no {kind[:-1]} file {path}")
+        raise SystemExit(f"benchmark: no {kind.rstrip('s')} file {path}")
     with open(path) as f:
         return json.load(f)
 
@@ -35,7 +41,7 @@ def load_json(kind: str, name: str, root: str = ROOT) -> dict:
 def load_module(kind: str, name: str, root: str = ROOT):
     path = os.path.join(root, kind, f"{name}.py")
     if not os.path.isfile(path):
-        raise SystemExit(f"benchmark: no {kind[:-1]} module {path}")
+        raise SystemExit(f"benchmark: no {kind.rstrip('s')} module {path}")
     spec = importlib.util.spec_from_file_location(
         f"benchmarks_{kind}_{name.replace('.', '_').replace('-', '_')}",
         path)
@@ -52,10 +58,33 @@ def list_names(kind: str, ext: str, root: str = ROOT) -> List[str]:
 
 def load_cell(name: str, root: str = ROOT) -> Dict:
     """A cell with its configuration resolved: ``{"name", "cell",
-    "config_name", "config"}``."""
+    "config_name", "config", "root"}``.  A configuration that names no
+    plain reference is refused: there is no default."""
     cell = load_json("workloads", name, root)
+    config = load_json("configs", cell["config"], root)
+    if not config.get("reference"):
+        path = os.path.join(root, "configs", f"{cell['config']}.json")
+        raise SystemExit(f"benchmark: configuration file {path} names no "
+                         f"`reference` (reference/<name>.py)")
     return {"name": name, "cell": cell, "config_name": cell["config"],
-            "config": load_json("configs", cell["config"], root)}
+            "config": config, "root": root}
+
+
+def load_driver(cell: Dict):
+    """The cell's driver module, from the tree the cell was loaded from."""
+    return load_module("drivers", cell["cell"]["driver"], cell["root"])
+
+
+def load_reference(cell: Dict):
+    """The plain reference the cell's configuration names, from the tree
+    the cell was loaded from; a module that lacks part of ``CONTRACT`` is
+    refused with what it lacks."""
+    ref = load_module("reference", cell["config"]["reference"], cell["root"])
+    lacks = [f for f in CONTRACT if not callable(getattr(ref, f, None))]
+    if lacks:
+        raise SystemExit(f"benchmark: reference module {ref.__file__} "
+                         f"lacks {', '.join(lacks)}")
+    return ref
 
 
 def load_peaks(root: str = ROOT) -> dict:
@@ -236,7 +265,7 @@ def main(argv=None, t_start: Optional[float] = None) -> int:
     bench = manifest()
     cell = load_cell(args.workload)
     peaks = load_peaks()
-    driver = load_module("drivers", cell["cell"]["driver"])
+    driver = load_driver(cell)
     driver.prepare(cell)       # cache directory, precision: before JAX starts
     device = require_device(int(cell["cell"]["chips"]), peaks)
     record = driver.run(cell, seed=args.seed, seconds=args.seconds,

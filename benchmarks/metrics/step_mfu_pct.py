@@ -1,6 +1,7 @@
 """The whole training step's share of the chip's peak: model FLOPs from
-shapes (``benchmarks/flops.py``: the policy forward per environment step,
-the learner's forward and backward per gradient step) times the rates this
+shapes (``model_flops`` of the configuration's reference module: the policy
+forward per environment step, the learner's forward and backward per
+gradient step; the run record's ``flops``) times the rates this
 run measured, over the peaks table's bf16 peak."""
 from benchmarks.metrics._common import env_steps, window_seconds
 

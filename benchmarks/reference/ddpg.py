@@ -19,14 +19,21 @@ weights, scales or tables from the program.
 ``matmul`` selects the precision of every contraction: ``"highest"`` is
 the reference, ``"high"`` (three bf16 passes) and ``"bfloat16"`` are the
 controls one step below what a configuration states.
+
+A configuration file names this module (``"reference": "ddpg"``) and the
+harness loads it by that name; what a reference module exports is listed
+in ``benchmarks/README.md`` (``CONTRACT`` in ``harness.py``).
 """
 from __future__ import annotations
 
+import zlib
 from typing import Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from benchmarks.flops import model_flops  # noqa: F401  (the contract's)
 
 NEG_INF = -1e30
 LEAKY = 0.2
@@ -66,6 +73,27 @@ def spec_from_config(cfg: dict) -> Spec:
         gamma=float(cfg["gamma"]), tau=float(cfg["target_model_update"]),
         lr=float(cfg["learning_rate"]), batch_size=int(cfg["batch_size"]),
         threshold=float(cfg["schedule_threshold"]))
+
+
+# ---------------------------------------------------------------- weights
+def init_weights(seed: int, shapes: Dict[str, tuple]):
+    """Every network leaf from the seed in one jitted call on the device:
+    matrices Glorot-uniform from their own shape, vectors zero (the
+    initialisers of the program's modules, keyed here by leaf name so the
+    values do not depend on the program's own key schedule)."""
+
+    def make(key):
+        out = {}
+        for name, shape in sorted(shapes.items()):
+            if len(shape) < 2:
+                out[name] = jnp.zeros(shape, jnp.float32)
+                continue
+            k = jax.random.fold_in(key, zlib.crc32(name.encode()) & 0x7FFFFFFF)
+            lim = (6.0 / (shape[0] + shape[1])) ** 0.5
+            out[name] = jax.random.uniform(k, shape, jnp.float32, -lim, lim)
+        return out
+
+    return jax.jit(make)(jax.random.PRNGKey(seed))
 
 
 # ------------------------------------------------------------ contractions

@@ -20,13 +20,22 @@ from benchmarks import harness  # noqa: E402
 
 TINY = {"replicas": 4, "chunk": 5, "episode_steps": 10,
         "nb_steps_warmup_critic": 10, "mem_limit": 160, "batch_size": 8}
+# limits for the tiny CPU cell (float32 on one backend on both sides):
+# counts exact, everything else two decades above what sound runs read here
+LIMITS = {"episodes_not_finite": 0, "ring_rows_off": 0, "return_gap": 1e-4,
+          "action_gap": 1e-6, "obs_gap": 1e-6, "reward_gap": 1e-5,
+          "features_gap": 1e-5, "policy_action_gap": 1e-5, "td_gap": 1e-4,
+          "moment_gap": 1e-3, "moment_mid_gap": 1e-4, "change_gap": 1e-3,
+          "moment2_mid_gap": 1e-5}
 FAKE_PEAKS = {"bf16_flops": 1e12, "hbm_bytes": 1e9, "hbm_bytes_per_s": 1e11}
 
 
-def tiny_cell(base: str = "flagship-b256", **overrides) -> dict:
-    """A committed cell cut to rehearsal size: same files, same driver,
-    fewer replicas, shorter episodes, a ring of 40 rows per replica."""
-    cell = copy.deepcopy(harness.load_cell(base))
+def tiny_cell(base: str = "flagship-b256", root: str = harness.ROOT,
+              **overrides) -> dict:
+    """A committed cell (or one of another tree, ``root``) cut to
+    rehearsal size: same files, same driver, same reference, fewer
+    replicas, shorter episodes, a ring of 40 rows per replica."""
+    cell = copy.deepcopy(harness.load_cell(base, root))
     sizes = {**TINY, **overrides}
     for k in ("replicas", "chunk"):
         cell["cell"][k] = sizes.pop(k)
@@ -40,7 +49,7 @@ def run_once(cell: dict, seed: int = 3, seconds: float = 0.5,
     with the run record under ``record``."""
     if limits is not None:
         cell["cell"]["limits"] = limits
-    driver = harness.load_module("drivers", cell["cell"]["driver"])
+    driver = harness.load_driver(cell)
     driver.prepare(cell)
     record = driver.run(cell, seed=seed, seconds=seconds, traced=traced,
                         t_start=time.time(), peaks=FAKE_PEAKS,

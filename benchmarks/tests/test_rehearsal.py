@@ -9,12 +9,7 @@ import pytest
 
 from benchmarks import check, control, rehearse
 
-# limits for the tiny CPU cell (float32 on one backend on both sides):
-# counts exact, everything else two decades above what sound runs read here
-LIMITS = {"episodes_not_finite": 0, "ring_rows_off": 0, "return_gap": 1e-4,
-          "action_gap": 1e-6, "obs_gap": 1e-6, "reward_gap": 1e-5,
-          "features_gap": 1e-5, "policy_action_gap": 1e-5, "td_gap": 1e-4,
-          "moment_gap": 1e-3, "change_gap": 1e-3, "moment2_mid_gap": 1e-5}
+LIMITS = rehearse.LIMITS
 KEYS = ["correct", "attempted", "failed", "metrics", "device"]
 
 
@@ -23,7 +18,7 @@ def tiny_run(traced=False, probe=None, seed=3):
     cell["cell"]["limits"] = dict(LIMITS)
     if probe is None:
         return rehearse.run_once(cell, seed=seed, seconds=0.5, traced=traced)
-    driver = rehearse.harness.load_module("drivers", cell["cell"]["driver"])
+    driver = rehearse.harness.load_driver(cell)
     driver.prepare(cell)
     import time
     return driver.run(cell, seed=seed, seconds=0.5, traced=False,
@@ -135,15 +130,32 @@ def _policy_action_altered(monkeypatch):
 
 
 def _half_of_the_replicas_left_out(monkeypatch):
+    # the one replay write per control step keeps the old rows of the
+    # upper half of the replicas: their transitions never reach the ring
     from gsc_tpu.parallel import dp
-    orig = dp.buffer_add
+    orig = dp.buffer_write_lockstep
 
-    def add(buf, item):
-        new = orig(buf, item)
-        keep = item["obs"].node_mask.sum() < 0      # never: rows dropped
-        return jax.tree_util.tree_map(
-            lambda a, b: jax.numpy.where(keep, a, b), new, buf)
-    monkeypatch.setattr(dp, "buffer_add", add)
+    def write(data, items, cursor):
+        def keep_half(new, old):
+            upper = jax.numpy.arange(new.shape[0]) >= new.shape[0] // 2
+            return jax.numpy.where(
+                upper.reshape((-1,) + (1,) * (new.ndim - 1)), old, new)
+        return jax.tree_util.tree_map(keep_half, orig(data, items, cursor),
+                                      data)
+    monkeypatch.setattr(dp, "buffer_write_lockstep", write)
+
+
+def _ring_cursors_not_advanced(monkeypatch):
+    # the rings' size and cursor stand still for the upper half
+    from gsc_tpu.parallel import dp
+    orig = dp.buffer_advance
+
+    def advance(buf, data, n):
+        new = orig(buf, data, n)
+        upper = jax.numpy.arange(new.pos.shape[0]) >= new.pos.shape[0] // 2
+        return new.replace(pos=jax.numpy.where(upper, buf.pos, new.pos),
+                           size=jax.numpy.where(upper, buf.size, new.size))
+    monkeypatch.setattr(dp, "buffer_advance", advance)
 
 
 def _half_the_substeps(monkeypatch):
@@ -169,7 +181,8 @@ def _reward_altered(monkeypatch):
     (_half_batch, "td_gap"),
     (_answer_altered, "action_gap"),
     (_policy_action_altered, "policy_action_gap"),
-    (_half_of_the_replicas_left_out, "ring_rows_off"),
+    (_half_of_the_replicas_left_out, "return_gap"),
+    (_ring_cursors_not_advanced, "ring_rows_off"),
 ])
 def test_broken_timed_path_is_not_correct(monkeypatch, plant, caught_by):
     plant(monkeypatch)
