@@ -48,6 +48,7 @@ from flax import struct
 from ..config.schema import AgentConfig
 from ..env.env import ServiceCoordEnv
 from ..models.nets import Actor, QNetwork, scale_action, unscale_action
+from ..models.torso import exit_entropy, exit_pass, take_pass
 from ..obs.learning import (accumulate_signal, learn_signal, replay_stats,
                             zero_learn_signal)
 from ..resilience.guard import all_finite
@@ -323,13 +324,18 @@ class DDPG:
         return state, buffer, env_state, obs, stats, metrics
 
     # ------------------------------------------------------------ learning
-    def _critic_loss(self, critic_params, state: DDPGState, batch):
+    def _td_target(self, state: DDPGState, batch):
         next_a = jnp.clip(
             self.actor.apply(state.target_actor_params, batch["next_obs"]),
             -1.0, 1.0)  # clamp(-1,1), simple_ddpg.py:208
         q_next = self.critic.apply(state.target_critic_params,
                                    batch["next_obs"], next_a)[..., 0]
-        target = batch["reward"] + (1.0 - batch["done"]) * self.agent.gamma * q_next
+        return batch["reward"] + (1.0 - batch["done"]) * self.agent.gamma * q_next
+
+    def _critic_loss(self, critic_params, state: DDPGState, batch):
+        if self.agent.torso is not None:
+            return self._critic_exit_loss(critic_params, state, batch)
+        target = self._td_target(state, batch)
         q = self.critic.apply(critic_params, batch["obs"], batch["action"])[..., 0]
         # the residual IS the loss argument — naming it changes no op.
         # With the learn ledger the aux also carries it, so the burst can
@@ -342,6 +348,32 @@ class DDPG:
     def _actor_loss(self, actor_params, critic_params, batch):
         a = self.actor.apply(actor_params, batch["obs"])
         return -jnp.mean(self.critic.apply(critic_params, batch["obs"], a))
+
+    # the exit objective (arXiv 2510.25741) with DDPG's as the task loss:
+    # a network with a looped torso answers once per pass, and its loss
+    # is the exit distribution's expectation of the per-pass task loss
+    # less beta times that distribution's entropy.  Targets and the Q
+    # inside the actor's loss come from the pass the exit threshold picks
+    # (the networks' default answer).
+    def _critic_exit_loss(self, critic_params, state: DDPGState, batch):
+        torso = self.agent.torso
+        target = jax.lax.stop_gradient(self._td_target(state, batch))
+        q, p = self.critic.apply(critic_params, batch["obs"],
+                                 batch["action"], passes=True)
+        td = q[..., 0] - target                             # [T, batch]
+        loss = jnp.mean(jnp.sum(p * td ** 2, axis=0)) \
+            - torso.exit_entropy_beta * jnp.mean(exit_entropy(p))
+        idx = exit_pass(p, torso.early_exit_threshold)
+        return loss, (take_pass(q[..., 0], idx), take_pass(td, idx), p)
+
+    def _actor_exit_loss(self, actor_params, critic_params, batch):
+        a, p = self.actor.apply(actor_params, batch["obs"], passes=True)
+        # the T candidate actions share one pass of the critic's torso:
+        # the action enters after it
+        q = self.critic.apply(critic_params, batch["obs"], a)[..., 0]
+        loss = jnp.mean(jnp.sum(p * -q, axis=0)) \
+            - self.agent.torso.exit_entropy_beta * jnp.mean(exit_entropy(p))
+        return loss, p
 
     def gradient_step(self, state: DDPGState, buffer: ReplayBuffer, key
                       ) -> Tuple[DDPGState, Dict[str, jnp.ndarray]]:
@@ -356,13 +388,23 @@ class DDPG:
             (critic_loss, aux), cgrad = jax.value_and_grad(
                 self._critic_loss, has_aux=True)(state.critic_params, state,
                                                  batch)
-            q_vals, td = aux if self.learn_ledger is not None else (aux, None)
+            exits = None
+            if self.agent.torso is not None:
+                q_vals, td, exits = aux[0], aux[1], {"critic": aux[2]}
+            else:
+                q_vals, td = aux if self.learn_ledger is not None \
+                    else (aux, None)
             cupd, critic_opt = self.opt.update(cgrad, state.critic_opt)
             critic_params = optax.apply_updates(state.critic_params, cupd)
 
         with jax.named_scope("actor_update"):
-            actor_loss, agrad = jax.value_and_grad(self._actor_loss)(
-                state.actor_params, critic_params, batch)
+            if exits is not None:
+                (actor_loss, exits["actor"]), agrad = jax.value_and_grad(
+                    self._actor_exit_loss, has_aux=True)(
+                        state.actor_params, critic_params, batch)
+            else:
+                actor_loss, agrad = jax.value_and_grad(self._actor_loss)(
+                    state.actor_params, critic_params, batch)
             aupd, actor_opt = self.opt.update(agrad, state.actor_opt)
             actor_params = optax.apply_updates(state.actor_params, aupd)
 
@@ -391,7 +433,7 @@ class DDPG:
             metrics["learn_signal"] = learn_signal(
                 self.learn_ledger, batch["topo_idx"], td, q_vals,
                 params={"actor": actor_params, "critic": critic_params},
-                grads={"actor": agrad, "critic": cgrad})
+                grads={"actor": agrad, "critic": cgrad}, exits=exits)
         return state, metrics
 
     def _learn_burst(self, state: DDPGState, sample_fn, constrain=None,
@@ -454,8 +496,9 @@ class DDPG:
                 "critic_grad_norm": jnp.zeros(()),
                 "actor_grad_norm": jnp.zeros(())}
         if self.learn_ledger is not None:
-            zero["learn_signal"] = zero_learn_signal(self.learn_ledger,
-                                                     state)
+            zero["learn_signal"] = zero_learn_signal(
+                self.learn_ledger, state,
+                exits=self.agent.torso is not None)
         # `steps` is a STATIC jit arg (dp.py marks it static_argnums) —
         # int() here normalizes a Python int, never syncs a tracer
         n_steps = (int(steps) if steps is not None  # gsc-lint: disable=R1

@@ -35,7 +35,7 @@ from ..env.driver import EpisodeDriver
 from ..env.env import ServiceCoordEnv
 from ..obs.trace import emit_episode_spans, episode_span, phase_span
 from ..resilience.faults import FaultInjected
-from ..resilience.guard import RollbackGuard, poison_tree
+from ..resilience.guard import RollbackGuard, all_finite, poison_tree
 from ..resilience.retry import (RetryPolicy, TransientDispatchError,
                                 call_with_retry)
 from ..utils.debug import check_invariants
@@ -44,6 +44,9 @@ from .buffer import buffer_nbytes, lockstep_cursor
 from .ddpg import DDPG, DDPGState
 
 log = logging.getLogger("gsc_tpu.agents.trainer")
+
+# the replica path's boundary gates (Trainer._finite_device)
+_all_finite_jit = jax.jit(all_finite)
 
 
 class RewardsWriter:
@@ -330,14 +333,24 @@ class Trainer:
     @staticmethod
     def _finite_host(tree) -> bool:
         """Host-side all-finite scan over a (host-layout) pytree's
-        inexact leaves — the replica path's stand-in for the rollback
-        guard's on-device verdict.  ONE definition shared by the
-        periodic-checkpoint and hot-swap-publish gates in
-        ``train_parallel``, so the two paths can never diverge on what
-        counts as a poisoned state."""
+        inexact leaves: the async path's checkpoint gate, whose state is
+        already gathered to the host for the save."""
         return all(np.isfinite(np.asarray(leaf)).all()
                    for leaf in jax.tree_util.tree_leaves(tree)
                    if np.issubdtype(np.asarray(leaf).dtype, np.inexact))
+
+    @staticmethod
+    def _finite_device(tree) -> bool:
+        """The same verdict made where the state lives — the replica
+        path's stand-in for the rollback guard: one jitted all-finite
+        over the tree's inexact leaves (``resilience.guard.all_finite``)
+        and ONE boolean read back, so no leaf crosses to the host for
+        the check (a learner state of gigabytes would idle the chip for
+        seconds at every boundary).  ONE definition shared by the chaos
+        verify, the periodic-checkpoint gate and the hot-swap-publish
+        gate in ``train_parallel``, so they can never diverge on what
+        counts as a poisoned state."""
+        return bool(_all_finite_jit(tree) > 0)
 
     # -------------------------------------------------------- cost ledger
     @staticmethod
@@ -1207,7 +1220,12 @@ class Trainer:
                         es_s, obs_s = pcls.reset_all.eval_shape(
                             pddpg, jax.random.PRNGKey(0), topo, traffic)
                         c_fn, c_pre = self._ledger_fn(pddpg, "chunk_step")
-                        l_fn, l_pre = self._ledger_fn(pddpg, "learn_burst")
+                        # (no capture of `learn_burst` alone: this path
+                        # never dispatches it — the burst runs inside
+                        # chunk_step(learn=True), whose capture carries it
+                        # under the `learn_burst` scope — and compiling the
+                        # learner's whole program a second time is set-up
+                        # nobody reads: 44 s with a 411 M-parameter torso)
                         self._capture_costs({
                             "chunk_step": (
                                 c_fn,
@@ -1215,8 +1233,6 @@ class Trainer:
                                  topo, traffic,
                                  np.int32(ep * steps_per_ep)),
                                 {"num_steps": chunk, "learn": True}),
-                            "learn_burst": (
-                                l_fn, (*l_pre, state, buffers), {}),
                         })
                         if factory is not None:
                             # the factory-inclusive program: the jitted
@@ -1284,7 +1300,7 @@ class Trainer:
                     # episode, NEVER on the production path): the replica
                     # harness drains synchronously, so the carries here
                     # are exactly the state after episode ep
-                    if self._finite_host(jax.device_get(state)):
+                    if self._finite_device(state):
                         if guard is not None:
                             guard.promote(ep, state, buffers,
                                           pending_empty=True)
@@ -1352,13 +1368,13 @@ class Trainer:
                         # per-leaf move the plan's gather fns perform;
                         # pulling the whole state would move ~5x the bytes,
                         # and critic/targets/moments never serve).  With no
-                        # rollback guard here, finite-verify before
-                        # anything reaches the fleet.  Host gather at
-                        # publish cadence only, never per episode.
-                        params = jax.device_get(state.actor_params)
-                        if self._finite_host(params):
-                            publisher.publish(params, meta={"episode": ep + 1},
-                                              verified=True)
+                        # rollback guard here, finite-verify (on the
+                        # device) before anything reaches the fleet.  Host
+                        # gather at publish cadence only, never per episode.
+                        if self._finite_device(state.actor_params):
+                            publisher.publish(
+                                jax.device_get(state.actor_params),
+                                meta={"episode": ep + 1}, verified=True)
                         else:
                             self._recover(
                                 ep, site="learner_state", action="detected",
@@ -1375,11 +1391,12 @@ class Trainer:
                         # with no rollback guard on this path the state must
                         # be verified HERE, or a NaN-poisoned run would
                         # checksum garbage into the last-good resume target.
-                        # One host-side scan at checkpoint cadence (the save
-                        # needs these leaves on host anyway — under a plan
+                        # The verdict is made on the device and one boolean
+                        # comes back; only a finite state is then put in
+                        # the layout the manager is handed (under a plan
                         # the gather IS the mesh-agnostic checkpoint layout).
-                        h_state, h_buffers = to_host(state, buffers)
-                        if self._finite_host(h_state):
+                        if self._finite_device(state):
+                            h_state, h_buffers = to_host(state, buffers)
                             ckpt_manager.save(h_state, h_buffers,
                                               episode=ep + 1)
                         else:

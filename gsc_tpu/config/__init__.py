@@ -8,6 +8,7 @@ from .schema import (
     ServiceConfig,
     ServiceFunction,
     SimConfig,
+    TorsoConfig,
     SUPPORTED_OBJECTIVES,
     SUPPORTED_OBSERVATIONS,
     DROP_REASONS,
@@ -19,7 +20,7 @@ from .registry import get_resource_function, register_resource_function
 __all__ = [
     "AgentConfig", "EnvLimits", "MMPPState", "PrecisionPolicy",
     "PRECISION_POLICIES", "precision_policy", "SchedulerConfig",
-    "ServiceConfig", "ServiceFunction", "SimConfig",
+    "ServiceConfig", "ServiceFunction", "SimConfig", "TorsoConfig",
     "SUPPORTED_OBJECTIVES", "SUPPORTED_OBSERVATIONS", "DROP_REASONS",
     "load_agent", "load_scheduler", "load_service", "load_sim",
     "get_resource_function", "register_resource_function",

@@ -301,6 +301,62 @@ class SimConfig:
 
 
 @dataclass(frozen=True)
+class TorsoConfig:
+    """A looped decoder stack between the GNN embedder and the heads of
+    actor and critic (``AgentConfig.torso``; models/torso.py): ``num_hidden_layers``
+    sandwich-normed attention + gated-MLP layers applied
+    ``total_ut_steps`` times on the same weights, with one exit gate per
+    graph.  Key names are the published ``config.json``'s (Ouro,
+    arXiv 2510.25741); ``exit_entropy_beta`` weighs the entropy term of
+    the exit objective and is not in that file.  Frozen and hashable: it
+    rides on ``AgentConfig``, a static argument of every jitted entry
+    point."""
+
+    hidden_size: int
+    num_attention_heads: int
+    num_key_value_heads: int
+    head_dim: int
+    intermediate_size: int
+    num_hidden_layers: int
+    total_ut_steps: int
+    early_exit_threshold: float = 1.0
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 1e6
+    hidden_act: str = "silu"
+    exit_entropy_beta: float = 0.05
+
+    def __post_init__(self):
+        for key in ("hidden_size", "num_attention_heads",
+                    "num_key_value_heads", "head_dim", "intermediate_size",
+                    "num_hidden_layers", "total_ut_steps"):
+            if int(getattr(self, key)) < 1:
+                raise ValueError(f"torso.{key} must be >= 1")
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError("torso.num_attention_heads must be a multiple "
+                             "of torso.num_key_value_heads")
+        if self.head_dim % 2:
+            raise ValueError("torso.head_dim must be even (rotary pairs)")
+        if self.hidden_act != "silu":
+            raise ValueError(f"unsupported torso.hidden_act "
+                             f"{self.hidden_act!r} (only silu)")
+        if not 0.0 < self.early_exit_threshold <= 1.0:
+            raise ValueError("torso.early_exit_threshold must be in (0, 1]")
+
+    @classmethod
+    def from_mapping(cls, doc: Mapping) -> "TorsoConfig":
+        """From a config file's nested ``torso`` mapping; a key this
+        class lacks is refused (a misspelt width would otherwise run as
+        its default)."""
+        fields = cls.__dataclass_fields__
+        unknown = sorted(set(doc) - set(fields))
+        if unknown:
+            raise ValueError(f"unknown torso key(s) {unknown} (known: "
+                             f"{sorted(fields)})")
+        kinds = {"int": int, "float": float, "str": str}   # annotations
+        return cls(**{k: kinds[fields[k].type](v) for k, v in doc.items()})
+
+
+@dataclass(frozen=True)
 class AgentConfig:
     """Agent/learning configuration
     (reference: configs/config/agent/sample_agent.yaml, validated in
@@ -373,7 +429,18 @@ class AgentConfig:
     # the reference is implicitly f32 end to end.
     precision: str = "f32"
 
+    # Optional looped decoder stack between the embedder and the heads of
+    # actor and critic (TorsoConfig; a mapping is parsed on construction).
+    # None = the networks as they were.  New key.
+    torso: Optional[TorsoConfig] = None
+
     def __post_init__(self):
+        if self.torso is not None and not isinstance(self.torso, TorsoConfig):
+            object.__setattr__(self, "torso",
+                               TorsoConfig.from_mapping(self.torso))
+        if self.torso is not None and not self.graph_mode:
+            raise ValueError("torso needs graph_mode (its tokens are the "
+                             "network's nodes)")
         # the reference's agent_type dispatch (main.py:374-381) is broken
         # upstream (SAC_Agent is never defined); here unknown types fail fast
         if self.agent_type != "DDPG":

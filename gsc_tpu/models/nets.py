@@ -32,6 +32,7 @@ from flax import linen as nn
 from ..config.schema import AgentConfig
 from ..env.observations import GraphObs
 from .gnn import GNNEmbedder, masked_mean_pool
+from .torso import LoopedTorso, exit_pass, take_pass
 
 
 def _accum_f32_dot_general(lhs, rhs, dimension_numbers, precision=None,
@@ -75,6 +76,20 @@ def _embedder(agent: AgentConfig, impl: str) -> GNNEmbedder:
                        mean_aggr=agent.gnn_aggr == "mean",
                        impl=impl,
                        compute_dtype=agent.precision_policy.gnn_dtype)
+
+
+def _torso_features(agent: AgentConfig, impl: str, obs, passes: bool):
+    """The embedder's per-node output through the configured torso:
+    per-node states, their masked mean and the exit distribution.  With
+    ``passes`` every pass keeps its leading ``[T]`` axis; without, the
+    pass the exit threshold picks is taken and ``p`` is dropped."""
+    x = _node_embedder(agent, impl)(obs.nodes, obs.edge_index,
+                                    obs.edge_mask, obs.node_mask)
+    h, z, p = LoopedTorso(agent.torso)(x, obs.node_mask)
+    if passes:
+        return h, z, p
+    idx = exit_pass(p, agent.torso.early_exit_threshold)
+    return take_pass(h, idx), take_pass(z, idx), None
 
 
 def _node_embedder(agent: AgentConfig, impl: str) -> GNNEmbedder:
@@ -136,19 +151,30 @@ class Actor(nn.Module):
     sched_shape: Tuple[int, int, int, int] = None
 
     @nn.compact
-    def __call__(self, obs):
+    def __call__(self, obs, passes: bool = False):
+        """``passes`` (torso only): return ``(answers [T, ..., A],
+        p [T, ...])``, one answer per pass with the exit distribution,
+        where the default returns the answer of the pass the exit
+        threshold picks."""
         mdt = self.agent.precision_policy.mlp_dtype
         if not self.agent.graph_mode:
             out = MLP(tuple(self.agent.actor_hidden_layer_nodes)
                       + (self.action_dim,), dtype=mdt)(obs)
             return out.astype(jnp.float32)
         assert isinstance(obs, GraphObs)
+        torso, p = self.agent.torso is not None, None
+        if torso:
+            # the heads below read the torso's per-node states and their
+            # masked mean where they read the embedder's
+            feats, pooled, p = _torso_features(self.agent, self.gnn_impl,
+                                               obs, passes)
         if use_factored_head(self.agent, self.action_dim):
             n, c, s, n2 = _check_sched_shape(self.sched_shape,
                                              self.action_dim)
-            feats = _node_embedder(self.agent, self.gnn_impl)(
-                obs.nodes, obs.edge_index, obs.edge_mask, obs.node_mask)
-            pooled = masked_mean_pool(feats, obs.node_mask)
+            if not torso:
+                feats = _node_embedder(self.agent, self.gnn_impl)(
+                    obs.nodes, obs.edge_index, obs.edge_mask, obs.node_mask)
+                pooled = masked_mean_pool(feats, obs.node_mask)
             # per-src hidden through the configured actor stack (global
             # context broadcast onto every node)
             h = jnp.concatenate(
@@ -171,14 +197,19 @@ class Actor(nn.Module):
                                  preferred_element_type=jnp.float32)
             out = out.reshape(out.shape[:-4] + (self.action_dim,))
         else:
-            emb = _embedder(self.agent, self.gnn_impl)(
+            emb = pooled if torso else _embedder(self.agent, self.gnn_impl)(
                 obs.nodes, obs.edge_index, obs.edge_mask, obs.node_mask)
-            h = jnp.concatenate([emb, obs.mask.astype(emb.dtype)], axis=-1)
+            mask = obs.mask
+            if torso:      # one mask per pass
+                mask = jnp.broadcast_to(mask,
+                                        emb.shape[:-1] + mask.shape[-1:])
+            h = jnp.concatenate([emb, mask.astype(emb.dtype)], axis=-1)
             out = MLP(tuple(self.agent.actor_hidden_layer_nodes)
                       + (self.action_dim,), dtype=mdt)(h)
         # actions leave the network in f32 regardless of compute dtype:
         # noise, clipping and replay post-processing stay full precision
-        return (out * obs.mask).astype(jnp.float32)
+        out = (out * obs.mask).astype(jnp.float32)
+        return (out, p) if passes and torso else out
 
 
 class QNetwork(nn.Module):
@@ -205,7 +236,13 @@ class QNetwork(nn.Module):
     sched_shape: Tuple[int, int, int, int] = None
 
     @nn.compact
-    def __call__(self, obs, action):
+    def __call__(self, obs, action, passes: bool = False):
+        """With a torso the action enters after it, so ``action`` may
+        carry leading axes the observation lacks (several candidate
+        actions for one state share one pass of the torso); ``passes``
+        returns ``(q [T, ..., 1], p [T, ...])``, one answer per pass with
+        the exit distribution, where the default answers from the pass
+        the exit threshold picks."""
         mdt = self.agent.precision_policy.mlp_dtype
         if not self.agent.graph_mode:
             out = MLP(tuple(self.agent.critic_hidden_layer_nodes) + (1,),
@@ -213,12 +250,27 @@ class QNetwork(nn.Module):
                 jnp.concatenate([obs, action.astype(obs.dtype)], axis=-1))
             return out.astype(jnp.float32)
         assert isinstance(obs, GraphObs)
+        torso, p = self.agent.torso is not None, None
+        node_mask = obs.node_mask
+        if torso:
+            assert not passes or action.ndim == obs.mask.ndim, \
+                "one action per state when every pass answers"
+            feats, pooled, p = _torso_features(self.agent, self.gnn_impl,
+                                               obs, passes)
+            # states and action meet on their common leading axes
+            lead = jnp.broadcast_shapes(pooled.shape[:-1], action.shape[:-1])
+            feats = jnp.broadcast_to(feats, lead + feats.shape[-2:])
+            pooled = jnp.broadcast_to(pooled, lead + pooled.shape[-1:])
+            action = jnp.broadcast_to(action, lead + action.shape[-1:])
+            node_mask = jnp.broadcast_to(node_mask,
+                                         lead + node_mask.shape[-1:])
         if use_factored_head(self.agent, action.shape[-1]):
             n, c, s, n2 = _check_sched_shape(self.sched_shape,
                                              action.shape[-1])
-            feats = _node_embedder(self.agent, self.gnn_impl)(
-                obs.nodes, obs.edge_index, obs.edge_mask, obs.node_mask)
-            pooled = masked_mean_pool(feats, obs.node_mask)
+            if not torso:
+                feats = _node_embedder(self.agent, self.gnn_impl)(
+                    obs.nodes, obs.edge_index, obs.edge_mask, obs.node_mask)
+                pooled = masked_mean_pool(feats, obs.node_mask)
             g = self.agent.factored_key_dim
             a4 = action.reshape(action.shape[:-1] + (n, c, s, n2))
             k = nn.Dense(g, name="key", **_dense_kw(mdt))(feats)  # [.., N', G]
@@ -234,16 +286,21 @@ class QNetwork(nn.Module):
                 axis=-1)
             z = nn.relu(nn.Dense(self.agent.gnn_features, name="src",
                                  **_dense_kw(mdt))(z))
-            z = masked_mean_pool(z, obs.node_mask)
+            z = masked_mean_pool(z, node_mask)
             h = jnp.concatenate([pooled, z], axis=-1)
         else:
-            emb = _embedder(self.agent, self.gnn_impl)(
+            emb = pooled if torso else _embedder(self.agent, self.gnn_impl)(
                 obs.nodes, obs.edge_index, obs.edge_mask, obs.node_mask)
-            h = jnp.concatenate([emb, obs.mask.astype(emb.dtype),
+            mask = obs.mask
+            if torso:      # one mask per pass and candidate action
+                mask = jnp.broadcast_to(mask,
+                                        emb.shape[:-1] + mask.shape[-1:])
+            h = jnp.concatenate([emb, mask.astype(emb.dtype),
                                  action.astype(emb.dtype)], axis=-1)
         # Q-values leave in f32: TD targets and losses stay full precision
-        return MLP(tuple(self.agent.critic_hidden_layer_nodes) + (1,),
-                   dtype=mdt)(h).astype(jnp.float32)
+        q = MLP(tuple(self.agent.critic_hidden_layer_nodes) + (1,),
+                dtype=mdt)(h).astype(jnp.float32)
+        return (q, p) if passes and torso else q
 
 
 def scale_action(action: jnp.ndarray, low: float = 0.0,

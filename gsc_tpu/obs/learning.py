@@ -100,14 +100,28 @@ def layer_norms(tree) -> Dict[str, "object"]:
             for name, leaves in _layer_groups(tree).items()}
 
 
-def learn_signal(spec: LearnLedgerSpec, topo_idx, td, q, params, grads
-                 ) -> Dict:
+def exit_stats(p) -> Dict:
+    """Where an exit distribution ``p`` [T, batch] (a looped torso's, see
+    models/torso.py) puts its mass: the expected exit pass, counted from
+    one, and the distribution's entropy, each the batch's mean."""
+    import jax.numpy as jnp
+
+    from ..models.torso import exit_entropy
+
+    steps = jnp.arange(1, p.shape[0] + 1, dtype=p.dtype)
+    return {"exit_step_mean": jnp.mean(jnp.tensordot(steps, p, axes=1)),
+            "exit_entropy": jnp.mean(exit_entropy(p))}
+
+
+def learn_signal(spec: LearnLedgerSpec, topo_idx, td, q, params, grads,
+                 exits: Optional[Dict] = None) -> Dict:
     """One gradient step's learning signal (traced inside the learn
     burst).  ``td`` is the critic residual ``q - stop_grad(target)`` the
     loss already computes; ``params``/``grads`` are the post-update trees
     — everything here CONSUMES tensors the update path materialized, so
     the update math is untouched and ledger-on runs stay bit-identical
-    to ledger-off runs."""
+    to ledger-off runs.  ``exits`` ({"actor": p, "critic": p}, networks
+    with a looped torso only) adds each network's :func:`exit_stats`."""
     import jax
     import jax.numpy as jnp
 
@@ -116,7 +130,10 @@ def learn_signal(spec: LearnLedgerSpec, topo_idx, td, q, params, grads
     k = max(spec.num_topos, 1)
     seg = jnp.clip(jnp.asarray(topo_idx).astype(jnp.int32), 0, k - 1)
     td_abs = jnp.abs(td)
+    extra = {} if exits is None else {
+        "exits": {net: exit_stats(p) for net, p in sorted(exits.items())}}
     return {
+        **extra,
         # accumulated across the burst by _learn_burst's carry
         "td_abs_sum": jax.ops.segment_sum(td_abs, seg, num_segments=k),
         "td_count": jax.ops.segment_sum(jnp.ones_like(td_abs), seg,
@@ -130,16 +147,22 @@ def learn_signal(spec: LearnLedgerSpec, topo_idx, td, q, params, grads
     }
 
 
-def zero_learn_signal(spec: LearnLedgerSpec, state) -> Dict:
+def zero_learn_signal(spec: LearnLedgerSpec, state,
+                      exits: bool = False) -> Dict:
     """The fori-loop carry template matching :func:`learn_signal`'s
     structure (layer names derive from the state's static tree, so the
-    two always agree)."""
+    two always agree; ``exits`` as the networks have a looped torso)."""
     import jax.numpy as jnp
 
     k = max(spec.num_topos, 1)
     trees = {"actor": state.actor_params, "critic": state.critic_params}
     zeros = {name: jnp.zeros(()) for name in _layer_groups(trees)}
+    extra = {} if not exits else {
+        "exits": {net: {"exit_step_mean": jnp.zeros(()),
+                        "exit_entropy": jnp.zeros(())}
+                  for net in ("actor", "critic")}}
     return {
+        **extra,
         "td_abs_sum": jnp.zeros((k,)), "td_count": jnp.zeros((k,)),
         "q_mean": jnp.zeros(()), "q_std": jnp.zeros(()),
         "q_min": jnp.zeros(()), "q_max": jnp.zeros(()),
@@ -219,6 +242,12 @@ def emit_learn_signal(hub, episode: int, signal: Optional[Dict] = None,
                        for k, v in (signal.get("param_norms") or {}).items()}
         fields.update(td_abs_mean=td_mean, per_topology_td=per_topo, **q,
                       grad_norms=grad_norms, param_norms=param_norms)
+        for net, stats in (signal.get("exits") or {}).items():
+            # a looped torso's exit distribution, the burst's last step
+            for k, v in stats.items():
+                fields[f"{k}_{net}"] = _scalar(v)
+                if fields[f"{k}_{net}"] is not None:
+                    hub.gauge(k, fields[f"{k}_{net}"], network=net)
         if td_mean is not None:
             hub.gauge("td_abs_mean", td_mean)
         for name, v in per_topo.items():

@@ -70,11 +70,15 @@ SPAN_NAMES = (
 # Every ``jax.named_scope`` name in the package, stable API: the layer
 # boundaries of the device program.  The innermost of these on an HLO
 # instruction's ``op_name`` path is its scope (``analysis.hlo.scope_stats``).
+# The last four stand only in a program whose networks have a looped
+# torso (``AgentConfig.torso``, models/torso.py): one pass of the stack,
+# its layers' two halves, and the exit gate.
+TORSO_SCOPES = ("torso_pass", "torso_attention", "torso_mlp", "exit_gate")
 DEVICE_SCOPES = (
     "rollout_step", "sim_substep", "traffic_arrivals", "policy_forward",
     "env_observe", "replay_write", "learn_burst", "replay_sample",
     "critic_update", "actor_update", "target_update", "gat_layer",
-    "finite_guard")
+    "finite_guard") + TORSO_SCOPES
 
 class _OpenSpans(threading.local):
     """Per-thread stack of the open ``phase_span`` names."""

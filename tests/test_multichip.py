@@ -76,6 +76,29 @@ def test_match_partition_rules_paths_scalars_and_default():
                               {"other": jnp.zeros((2, 2))})
 
 
+@pytest.mark.parametrize("book", ["sharded", "tp"])
+def test_a_looped_torsos_leaves_stay_replicated(book):
+    """The books' rules name 2-D leaves (``kernel``, ``w_l``, ``w_r``): on a
+    stacked ``[layers, in, out]`` leaf ``P(None, 'mp')`` would split the
+    contraction axis, so the torso's leaf names match none of them."""
+    from gsc_tpu.config import TorsoConfig
+    from gsc_tpu.models.torso import LoopedTorso
+    from gsc_tpu.parallel.partition import tp_rules
+
+    torso = LoopedTorso(TorsoConfig(
+        hidden_size=64, num_attention_heads=4, num_key_value_heads=4,
+        head_dim=16, intermediate_size=176, num_hidden_layers=2,
+        total_ut_steps=4))
+    tree = jax.eval_shape(torso.init, jax.random.PRNGKey(0),
+                          jnp.zeros((8, 22)), jnp.ones(8, bool))
+    tree = {"actor_params": {"params": {"LoopedTorso_0": tree["params"]}}}
+    rules = sharded_rules() if book == "sharded" else tp_rules()
+    specs = match_partition_rules(rules, tree)
+    leaves = jax.tree_util.tree_leaves(
+        specs, is_leaf=lambda x: isinstance(x, P))
+    assert len(leaves) == 15 and all(spec == P() for spec in leaves)
+
+
 def test_clamp_specs_to_mesh_indivisible_widths():
     mesh = make_train_mesh(4, 2)
     tree = {"wide": {"kernel": jnp.zeros((4, 8))},    # 8 % 2 == 0: stays
