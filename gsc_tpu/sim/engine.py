@@ -380,7 +380,6 @@ class SimEngine:
         t = state.t
         g = jnp.round(t / dt).astype(jnp.int32)       # global substep index
         ridx = jnp.mod(g, self.H)                      # ring-buffer index
-        slots = jnp.arange(self.M)
         rng, k_proc = jax.random.split(state.rng)
 
         # --- 1. capacity releases ------------------------------------------
@@ -462,38 +461,34 @@ class SimEngine:
             n_free = free.sum()
             arr_rank = jnp.cumsum(due.astype(jnp.int32)) - 1
             spawn = due & (arr_rank < n_free)
-            # tgt[a] = slot of the arr_rank[a]-th free slot, as a masked sum
-            # over the [A, M] match (one non-zero integer term, or none
-            # when the rank has no free slot: such a record does not
-            # spawn) — a gather at the per-replica ranks would again be
-            # serial under vmap
-            match = free[None, :] & (free_rank[None, :] == arr_rank[:, None])
-            tgt = jnp.where(match, slots[None, :], 0).sum(-1)
+            # land[a, m]: record a lands in slot m, the arr_rank[a]-th free
+            # one (a record whose rank has no free slot does not spawn).
+            # Rows are disjoint with at most one true slot each and a free
+            # slot holds no live flow, so the write is a select per field and
+            # record in the [M] layout every later stage reads.  A scatter
+            # at the per-replica slot indices would be serial under vmap and
+            # keep layout copies of the slot fields, packed for it, around
+            # it on the TPU — every substep, a record due or none.
+            land = (spawn[:, None] & free[None, :]
+                    & (free_rank[None, :] == arr_rank[:, None]))
+            hit = land.any(0)
 
-            # one packed scatter per dtype instead of 11 per-field scatters —
-            # scatters end XLA fusions, so per-substep op count (the TPU cost
-            # driver) tracks the number of scatters, not the bytes moved
-            arr_idx = jnp.where(spawn, tgt, self.M)
-            a_i32 = jnp.zeros(_ARRIVALS_PER_SUBSTEP, jnp.int32)
-            int_cur = jnp.stack(
-                [phase, node, position, F.sfc, F.egress, F.dest],
-                axis=-1)                                       # [M, 6]
-            int_new = jnp.stack(
-                [a_i32 + PH_DECIDE, a_ingress, a_i32, a_sfc, a_egress,
-                 a_i32 - 1], axis=-1)                          # [A, 6]
-            int_cur = int_cur.at[arr_idx].set(int_new, mode="drop")
-            phase, node, position, sfc, egress, dest = (
-                int_cur[:, 0], int_cur[:, 1], int_cur[:, 2], int_cur[:, 3],
-                int_cur[:, 4], int_cur[:, 5])
-            a_f32 = jnp.zeros(_ARRIVALS_PER_SUBSTEP, jnp.float32)
-            flt_cur = jnp.stack([F.dr, F.duration, ttl, e2e, F.pend_path],
-                                axis=-1)                           # [M, 5]
-            flt_new = jnp.stack([a_dr, a_duration, a_ttl, a_f32, a_f32],
-                                axis=-1)                           # [A, 5]
-            flt_cur = flt_cur.at[arr_idx].set(flt_new, mode="drop")
-            dr, duration, ttl, e2e, pend_path = (
-                flt_cur[:, 0], flt_cur[:, 1], flt_cur[:, 2], flt_cur[:, 3],
-                flt_cur[:, 4])
+            def landed(cur, new):
+                for a in range(_ARRIVALS_PER_SUBSTEP):
+                    cur = jnp.where(land[a], new[a], cur)
+                return cur
+
+            phase = jnp.where(hit, PH_DECIDE, phase)
+            node = landed(node, a_ingress)
+            position = jnp.where(hit, 0, position)
+            sfc = landed(F.sfc, a_sfc)
+            egress = landed(F.egress, a_egress)
+            dest = jnp.where(hit, -1, F.dest)
+            dr = landed(F.dr, a_dr)
+            duration = landed(F.duration, a_duration)
+            ttl = landed(ttl, a_ttl)
+            e2e = jnp.where(hit, 0.0, e2e)
+            pend_path = jnp.where(hit, 0.0, F.pend_path)
             hop_next = F.hop_next
             n_spawn = spawn.sum()
             cursor = state.cursor + n_spawn
@@ -502,13 +497,18 @@ class SimEngine:
             # each once
             late = spawn & (a_time < t - _EPS)
             truncated = state.truncated_arrivals + late.sum()
+            # per ingress node, a left fold in record order: the float sum
+            # a scatter-add makes, with its order fixed
+            req_node = m.run_requested_node
+            for a in range(_ARRIVALS_PER_SUBSTEP):
+                req_node = req_node + jnp.where(
+                    spawn[a] & (jnp.arange(self.N) == a_ingress[a]),
+                    a_dr[a], 0.0)
             m = m.replace(
                 generated=m.generated + n_spawn,
                 run_generated=m.run_generated + n_spawn,
                 active=m.active + n_spawn,
-                run_requested_node=m.run_requested_node.at[
-                    jnp.where(spawn, a_ingress, self.N)
-                ].add(jnp.where(spawn, a_dr, 0.0), mode="drop"),
+                run_requested_node=req_node,
             )
 
         # recompute flags after arrivals.  The UN-clipped one-hot zero-rows

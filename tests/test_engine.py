@@ -451,26 +451,52 @@ def _vmapped_primitives(fn, one, replicas, whole):
     return names
 
 
+def _substep_primitives_agree(base, whole, ext_decisions=None):
+    """The vmapped substep traces to the same primitives at B = 4 and
+    B = 16, none of them indexed on an operand of a shape in
+    ``whole(engine)``."""
+    service, limits = base
+    cfg = make_cfg()
+    topo = line_topo()
+    engine = SimEngine(service, cfg, limits)
+    traffic = generate_traffic(cfg, service, topo, episode_steps=2, seed=0)
+    if ext_decisions is not None:
+        ext_decisions = jnp.full(engine.M, ext_decisions, jnp.int32)
+
+    def substep(s):
+        return engine._substep(s, topo, traffic.window(s.cursor, 1),
+                               traffic.node_cap[0],
+                               ext_decisions=ext_decisions)
+
+    one = engine.init(jax.random.PRNGKey(0), topo)
+    shapes = whole(engine)
+    assert (_vmapped_primitives(substep, one, 4, shapes)
+            == _vmapped_primitives(substep, one, 16, shapes))
+
+
 def test_vmapped_substep_keeps_rings_whole(base):
     """Under ``jax.vmap`` the ring index is a per-replica vector; the
     substep must still touch a ring only through elementwise operations
     and contractions over the whole array (a row-indexed read or write
     forces a second, row-contiguous layout of it on the TPU), and trace
     to the same primitives whatever the number of replicas."""
-    service, limits = base
-    cfg = make_cfg()
-    topo = line_topo()
-    engine = SimEngine(service, cfg, limits)
-    traffic = generate_traffic(cfg, service, topo, episode_steps=2, seed=0)
-    rings = {(engine.H, N * limits.max_sfs), (engine.H, E)}
+    _substep_primitives_agree(
+        base, lambda e: {(e.H, N * base[1].max_sfs), (e.H, E)})
 
-    def substep(s):
-        return engine._substep(s, topo, traffic.window(s.cursor, 1),
-                               traffic.node_cap[0])
 
-    one = engine.init(jax.random.PRNGKey(0), topo)
-    assert (_vmapped_primitives(substep, one, 4, rings)
-            == _vmapped_primitives(substep, one, 16, rings))
+@pytest.mark.parametrize("ext_decisions", [None, 1],
+                         ids=["wrr", "ext_decisions"])
+def test_vmapped_substep_writes_arrivals_without_a_scatter(base,
+                                                           ext_decisions):
+    """Under ``jax.vmap`` the slot a record lands in is a per-replica
+    index, and a scatter at it is serial on the TPU and keeps layout
+    copies of the packed slot blocks around it: stage 3 must write the
+    arrivals (and the per-node requested rate) through masks alone — no
+    indexed primitive on a packed ``[M, 6]`` / ``[M, 5]`` block, a slot
+    field or the ``[N]`` node counter — and trace to the same primitives
+    whatever the number of replicas."""
+    _substep_primitives_agree(
+        base, lambda e: {(e.M, 6), (e.M, 5), (e.M,), (N,)}, ext_decisions)
 
 
 def test_vmapped_interval_reads_arrivals_without_an_index(base):
@@ -900,7 +926,7 @@ def test_scan_unroll_bit_identical(base):
 # --------------------------------------------------------- fusion budget
 # Pinned compiled-HLO fusion count of the flagship-interval engine.apply
 # (abc service, Abilene limits 24/37, M=128, 100 substeps) on the CPU
-# backend, jaxlib 0.9.0: 279 (273 when re-measured and re-pinned in PR 21;
+# backend, jaxlib 0.9.0: 273 (273 too when re-measured and re-pinned in PR 21;
 # the same program counted 191 under the previous jaxlib — the compiler's
 # fusion decisions moved, the engine did not).  PR 29 re-pinned 273 -> 276:
 # stage 1 of the substep reads and clears the release rings' due row
@@ -913,7 +939,12 @@ def test_scan_unroll_bit_identical(base):
 # of seven per-field gathers and the rank gather, which this unbatched CPU
 # program counts as three more fusions — while the TPU's vmapped program
 # loses eight serial gathers per substep and half its operations
-# (`substep_device_ops` 612 -> 310).  The budget adds NO headroom on purpose — a
+# (`substep_device_ops` 612 -> 310).  PR 36 re-pinned 279 -> 273: stage 3
+# writes the arrivals into their slots as selects through the match mask
+# and folds the per-node requested rate, in place of two packed scatters
+# with their stack / unstack and a scatter-add — six fusions fewer here,
+# 38 operations fewer in the TPU's vmapped program (310 -> 272, sandbox
+# compile for a described v5e).  The budget adds NO headroom on purpose — a
 # 281->294-style regression (the round-5 scatter-merge: bit-exact, yet
 # slower) is ~+13, so any slack would swallow exactly the class of change
 # this gate exists to catch.  If a toolchain upgrade moves the count,
@@ -923,7 +954,7 @@ def test_scan_unroll_bit_identical(base):
 # What the pin protects: the engine's op count as the CPU compiler sees it
 # — a proxy, not the chip's count (the TPU compiler fuses differently; the
 # benchmark's `substep_device_ops` is the chip's own).
-FUSION_BUDGET = 279
+FUSION_BUDGET = 273
 
 
 def _flagship_interval_compiled():
