@@ -1,7 +1,10 @@
 """Compile a cell's ``chunk_step`` (both variants) at its real size for a
 described, not attached, TPU v5e and print ``memory_analysis()`` with the
-bytes the cell's state holds — rehearsal 3 of the on-chip-measurement
-guide.  Nothing runs; a compile that passes is not a chip run.
+bytes the cell's state holds, and each program's ``device_total_gb``
+(arguments + temporaries + outputs not aliased to an argument) with its
+share of the chip (``chip_share_pct``) — rehearsal 3 of the
+on-chip-measurement guide.  Nothing runs; a compile that passes is not a
+chip run.
 
     JAX_PLATFORMS=cpu python3 benchmarks/compile_v5e.py --workload flagship-b256
 
@@ -18,6 +21,9 @@ import tempfile
 import time
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
+# what one v5e chip lets a process hold: ``memory_stats()["bytes_limit"]``
+# on the chip (my chip run, PR 37), below the 16 GiB of HBM in peaks.json
+CHIP_BYTES = 16.9e9
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
@@ -89,6 +95,10 @@ def compile_cell(name: str, topology: str = "v5e:2x2") -> dict:
         t0 = time.time()
         compiled = jitted.lower(pddpg, *args, start, chunk, learn).compile()
         mem = compiled.memory_analysis()
+        # the program's whole footprint: its arguments, its temporaries
+        # and the outputs that do not reuse a donated argument's buffer
+        total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+                 + mem.output_size_in_bytes - mem.alias_size_in_bytes)
         out["programs"][f"chunk_step(learn={learn})"] = {
             "compile_s": round(time.time() - t0, 1),
             "argument_bytes": int(mem.argument_size_in_bytes),
@@ -96,6 +106,8 @@ def compile_cell(name: str, topology: str = "v5e:2x2") -> dict:
             "alias_bytes": int(mem.alias_size_in_bytes),
             "temp_bytes": int(mem.temp_size_in_bytes),
             "generated_code_bytes": int(mem.generated_code_size_in_bytes),
+            "device_total_gb": total / 1e9,
+            "chip_share_pct": 100 * total / CHIP_BYTES,
         }
     return out
 

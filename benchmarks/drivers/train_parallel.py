@@ -12,7 +12,9 @@ parameters carry the benchmark:
   ``triggered`` the loop reads at every episode boundary;
 - ``init_state``: the learner state made here from the seed (the resume
   path), so that the plain reference starts from the same weights without
-  taking anything the program made;
+  taking anything the program made.  It is handed over as host arrays,
+  which the trainer copies to the device, so the trainer's copy is the
+  only one there;
 - ``ckpt_manager`` with ``ckpt_interval=1``: a recorder that, once, at the
   end of the first episode (in set-up), keeps a host copy of the learner
   state and of that episode's replay rows for the output check; at every
@@ -22,6 +24,24 @@ parameters carry the benchmark:
   are at hand when the window has closed.  What that copy costs is printed
   per episode (``recorder_actor_s``).  Nothing stays on the device and
   nothing is written to disk.
+
+What the driver holds, by phase.  A configuration's device bytes are the
+trainer's alone: its learner state (online and target networks, two Adam
+moments: 16 B a trained parameter), replay ring, environment and traffic.
+
+- set-up: the reference's weights are made on the device in one jitted
+  call and copied to the host at once (``host_init_state``); then the
+  host keeps them (4 B a trained parameter) and the learner key for the
+  check, and from the end of episode 0 the recorder's copies;
+- window: nothing of the driver's on the device; on the host the same,
+  and the actor's parameters of the last two boundaries;
+- check: the program's device state is freed before the reference runs;
+  of the final carries only the ring's ``size``, ``pos`` and ``capacity``
+  and the rows of the window's last episode that the policy check reads
+  are kept, on the host.
+
+``device_live_gb`` (each save) and ``memory_stats`` (run end) print what
+is resident, for sizing a cell.
 
 What depends on the policy's architecture — the plain reference the output
 check follows, the model FLOPs ``step_mfu_pct`` divides and the weights
@@ -114,36 +134,80 @@ def leaf_table(tree) -> Dict[str, object]:
             for p, l in jax.tree_util.tree_flatten_with_path(tree)[0]}
 
 
-def make_init_state(seed: int, state_shape, init_weights: Callable):
-    """A ``DDPGState`` of the program's layout filled from the seed:
-    online and target networks from the reference's ``init_weights(seed,
-    shapes)``, optimiser moments and counts zero, the learner key folded
-    from the seed.  Returns (state, weights by reference name, learner
-    key)."""
+def _net_shapes(state_shape):
+    """Leaf name -> shape of the online networks, named as the reference
+    names them (``actor/...``, ``critic/...``)."""
     import jax
-    import jax.numpy as jnp
+
+    shapes = {}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(state_shape)[0]:
+        head, _, rest = leaf_name(path).partition("/")
+        if head in ("actor_params", "critic_params"):
+            shapes[f"{head.removesuffix('_params')}/{rest}"] = \
+                tuple(leaf.shape)
+    return shapes
+
+
+def _fill(state_shape, weights, rng, zeros):
+    """The state's tree with online and target networks from ``weights``
+    (one array per network leaf, shared by online and target), the
+    learner key ``rng`` and every other leaf ``zeros(shape, dtype)``."""
+    import jax
 
     flat, treedef = jax.tree_util.tree_flatten_with_path(state_shape)
-    names = [leaf_name(p) for p, _ in flat]
     nets = {"actor_params": "actor", "target_actor_params": "actor",
             "critic_params": "critic", "target_critic_params": "critic"}
-    shapes = {}
-    for name, (_, leaf) in zip(names, flat):
-        head, _, rest = name.partition("/")
-        if head in ("actor_params", "critic_params"):
-            shapes[f"{nets[head]}/{rest}"] = tuple(leaf.shape)
-    weights = init_weights(seed, shapes)
-    rng = jax.random.fold_in(jax.random.PRNGKey(seed), 7)
     leaves = []
-    for name, (_, leaf) in zip(names, flat):
-        head, _, rest = name.partition("/")
+    for path, leaf in flat:
+        head, _, rest = leaf_name(path).partition("/")
         if head in nets:
             leaves.append(weights[f"{nets[head]}/{rest}"])
         elif head == "rng":
             leaves.append(rng)
         else:
-            leaves.append(jnp.zeros(leaf.shape, leaf.dtype))
-    return jax.tree_util.tree_unflatten(treedef, leaves), weights, rng
+            leaves.append(zeros(leaf.shape, leaf.dtype))
+    return jax.tree_util.tree_unflatten(treedef, leaves)
+
+
+def make_init_state(seed: int, state_shape, init_weights: Callable):
+    """A ``DDPGState`` of the program's layout filled from the seed, as
+    device arrays: online and target networks from the reference's
+    ``init_weights(seed, shapes)``, optimiser moments and counts zero, the
+    learner key folded from the seed.  Returns (state, weights by
+    reference name, learner key)."""
+    import jax
+    import jax.numpy as jnp
+
+    weights = init_weights(seed, _net_shapes(state_shape))
+    rng = jax.random.fold_in(jax.random.PRNGKey(seed), 7)
+    return _fill(state_shape, weights, rng, jnp.zeros), weights, rng
+
+
+def host_init_state(seed: int, state_shape, init_weights: Callable):
+    """``make_init_state``'s state, weights and key as host arrays, the
+    same bits (float32 survives the copy exactly): the weights are made
+    on the device in the reference's one jitted call and copied to the
+    host, the zero leaves are made on the host.  No device array of
+    them outlives the call, so the trainer's copy of the state
+    (``train_parallel`` puts what it is given on the device) is the only
+    one on the device."""
+    import jax
+    import numpy as np
+
+    # np.array copies: on the CPU np.asarray's view would keep the
+    # device array alive
+    weights = {k: np.array(v) for k, v in
+               init_weights(seed, _net_shapes(state_shape)).items()}
+    rng = np.array(jax.random.fold_in(jax.random.PRNGKey(seed), 7))
+    return _fill(state_shape, weights, rng, np.zeros), weights, rng
+
+
+def device_live_bytes() -> int:
+    """Bytes of every live device array, read from their shapes on the
+    host: no device operation."""
+    import jax
+
+    return sum(a.nbytes for a in jax.live_arrays())
 
 
 # --------------------------------------------------------------- recorder
@@ -190,9 +254,11 @@ class Recorder:
     twice for the whole run).  At every call it keeps a host copy of the
     actor parameters (the last two calls' only; a device copy would put
     operations after the program's last into the traced slice) with the
-    seconds it took, and stamps its entry: the loop's finite check of the
-    learner state, which the checkpoint cadence brings, lies between the
-    episode's event and that stamp.  Nothing is written to disk."""
+    seconds it took and the bytes of every live device array
+    (``device_live_bytes``), and stamps its entry: the loop's finite
+    check of the learner state, which the checkpoint cadence brings, lies
+    between the episode's event and that stamp.  Nothing is written to
+    disk."""
 
     def __init__(self, episode_steps: int):
         self.steps = int(episode_steps)
@@ -202,11 +268,15 @@ class Recorder:
         self.actor = {}          # save number -> host copy, last two
         self.actor_s = {}        # save number -> seconds that copy took
         self.entered = {}        # save number -> host clock at entry
+        self.live = {}           # save number -> live device bytes
 
     def save(self, state, buffers, episode: int, **_):
         import jax
 
         self.entered[episode] = time.time()
+        # before the copy: on the TPU ``device_get`` leaves arrays among
+        # the live ones that the chip does not hold (the actor's bytes)
+        self.live[episode] = device_live_bytes()
         self.actor[episode] = jax.device_get(state.actor_params)
         self.actor.pop(episode - 2, None)
         self.actor_s[episode] = time.time() - self.entered[episode]
@@ -348,16 +418,15 @@ def run(cell: dict, seed: int, seconds: float, traced: bool,
                                       topo0, traffic0)
         state_shape = jax.eval_shape(trainer.ddpg.init,
                                      jax.random.PRNGKey(0), obs_shape)
-        init_state, weights, rng0 = make_init_state(seed, state_shape,
-                                                    ref.init_weights)
-        weights_host = {k: np.asarray(v) for k, v in weights.items()}
-        rng0_host = np.asarray(rng0)
+        seeded, weights_host, rng0_host = host_init_state(
+            seed, state_shape, ref.init_weights)
         node_mask = np.asarray(topo0.node_mask)
         recorder = Recorder(steps)
         try:
+            # host arrays: the trainer's device copy is the only one
             state, buffers = trainer.train_parallel(
                 EPISODES_CAP, num_replicas=replicas, chunk=chunk,
-                verbose=False, init_state=init_state,
+                verbose=False, init_state=seeded,
                 ckpt_manager=recorder, ckpt_interval=1, preempt=window)
             jax.block_until_ready(state)
         except Exception as e:  # the run failed: no metric, not correct
@@ -368,14 +437,19 @@ def run(cell: dict, seed: int, seconds: float, traced: bool,
         if tracer is not None:
             tracer.finish()
         record["memory_peak_bytes"] = memory_peak_bytes()
+        for d in jax.local_devices():
+            stats = d.memory_stats()
+            if stats:
+                log("memory_stats", json.dumps({"device": d.id, **{
+                    k: stats.get(k) for k in ("bytes_in_use",
+                                              "peak_bytes_in_use",
+                                              "bytes_limit")}}))
         final = None
-        if state is not None:
+        if state is not None:   # the ring's accounting, all the check reads
             final = {"size": np.asarray(buffers.size),
                      "pos": np.asarray(buffers.pos),
                      "capacity": int(jax.tree_util.tree_leaves(
-                         buffers.data)[0].shape[1]),
-                     "state": {k: np.asarray(v)
-                               for k, v in leaf_table(state).items()}}
+                         buffers.data)[0].shape[1])}
         policy = None
         last = warm + window.episodes - 1
         if state is not None and window.episodes and last in recorder.actor:
@@ -398,7 +472,7 @@ def run(cell: dict, seed: int, seconds: float, traced: bool,
             after = {k: np.asarray(v)
                      for k, v in leaf_table(recorder.state).items()}
         # free the program's device state before the reference runs
-        del state, buffers, init_state, weights, trainer
+        del state, buffers, seeded, trainer
         obs.close(status="preempted" if error is None else "error")
         obs = None
         record.update(
@@ -417,6 +491,8 @@ def run(cell: dict, seed: int, seconds: float, traced: bool,
              for k in sorted(ended) if k + 1 in recorder.entered}))
         log("recorder_actor_s", json.dumps(
             {k - 1: round(v, 4) for k, v in sorted(recorder.actor_s.items())}))
+        log("device_live_gb", json.dumps(
+            {k - 1: v / 1e9 for k, v in sorted(recorder.live.items())}))
         if traced and tracer.started and tracer.stopped:
             from benchmarks import trace as trace_mod
             t0 = time.time()
