@@ -26,7 +26,14 @@ for t < T and ``p_T`` the remainder.
 Every layer's weights are ONE stacked leaf ``[L, ...]`` run by a
 ``lax.scan`` over layers inside a ``lax.scan`` over passes, so the program
 text and its compile time do not grow with L x T, and reverse mode sums a
-shared leaf's gradient over the passes by construction.  Leaf names avoid
+shared leaf's gradient over the passes by construction.  The backward
+pass keeps each layer's seven projection outputs (q, k, v, o, gate, up,
+down: the contractions without batch axes) and recomputes only the
+norms, rotary, SiLU, softmax and the two ``[N, N]`` attention products
+(0.2 % of a layer's operations at Ouro's widths and N = 24): no
+projection runs twice, and a gradient step's temporaries are about two
+thirds of what keeping every intermediate takes (which also ran slower
+on a TPU v5e).  Leaf names avoid
 the partition rulebooks' ``kernel`` / ``w_l`` / ``w_r`` patterns on
 purpose: those rules were written for 2-D leaves and would put ``mp`` on
 the contraction axis of a stacked ``[L, in, out]`` leaf, so the torso's
@@ -188,9 +195,12 @@ class LoopedTorso(nn.Module):
                                  w["norm_mlp_out"], eps)
             return h, None
 
+        keep = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+
         def one_pass(h, _):
             with jax.named_scope("torso_pass"):
-                h, _ = jax.lax.scan(jax.checkpoint(layer), h, layers)
+                h, _ = jax.lax.scan(jax.checkpoint(layer, policy=keep), h,
+                                    layers)
                 h = rms_norm(h, final_norm, eps)
             return h, h
 

@@ -177,6 +177,44 @@ def test_passes_share_weights_and_a_shared_leafs_gradient_is_their_sum():
         assert float(jnp.abs(each[0][name] - want).max()) > 1e-3 * scale
 
 
+def contractions(jaxpr) -> int:
+    """``dot_general`` equations in ``jaxpr`` and every jaxpr nested in its
+    equations' parameters (``scan`` bodies, ``checkpoint``, ``pjit``), each
+    counted once per occurrence in the text."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == "dot_general"
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)        # a ClosedJaxpr's
+                if hasattr(sub, "eqns"):
+                    n += contractions(sub)
+    return n
+
+
+def test_the_gradient_runs_no_second_forward_s_contractions():
+    torso = LoopedTorso(TorsoConfig(**{**TORSO, "total_ut_steps": 2}))
+    nm = jnp.arange(N) < REAL
+    x = jax.random.normal(jax.random.PRNGKey(0), (3, N, 22)) * nm[:, None]
+    mask = jnp.broadcast_to(nm, (3, N))
+    params = torso.init(jax.random.PRNGKey(1), x, mask)
+
+    def loss(p):
+        _, z, exits = torso.apply(p, x, mask)
+        return jnp.sum(jnp.sin(z)) + jnp.sum(exits * jnp.arange(2.0)[:, None])
+
+    forward = contractions(jax.make_jaxpr(loss)(params).jaxpr)
+    grad = contractions(jax.make_jaxpr(jax.grad(loss))(params).jaxpr)
+    # the layer's seven projections, its two attention products, the input
+    # projection and the exit gate, once in the program text
+    assert forward == 7 + 2 + 1 + 1
+    # the forward once, the backward two contractions for each of it (one
+    # where an operand is the input, which takes no gradient), and the two
+    # attention products the layer recomputes; a wholly rematerialised
+    # layer would run all nine a second time
+    assert grad <= 3 * forward + 2, (grad, forward)
+
+
 # ------------------------------------------------------------- exit gate
 def test_exit_distribution_sums_to_one_and_the_last_takes_the_rest():
     lam = jax.random.uniform(jax.random.PRNGKey(0), (4, 6), minval=0.05,
