@@ -10,9 +10,11 @@ enough: the rejected round-5 scatter-merge was bit-exact yet REGRESSED
 rejected it before the chip ever saw it.
 
 Consumers: the cost ledger (``obs/perf.py``: ``fusions`` and, per
-``jax.named_scope`` layer, ``scopes``) and the tier-1 fusion-budget
-regression test (``tests/test_engine.py``), which pins the compiled
-flagship-interval ``engine.apply`` count on the CPU backend.
+``jax.named_scope`` layer, ``scopes`` and the operation-to-scope map
+``op_map`` that ``obs.trace`` joins a device trace through) and the
+tier-1 fusion-budget regression test (``tests/test_engine.py``), which
+pins the compiled flagship-interval ``engine.apply`` count on the CPU
+backend.
 
 Stdlib-only on purpose (the gsc-lint convention for analysis/): the
 argument is an already-compiled jax ``Compiled`` object (or its
@@ -22,10 +24,12 @@ from __future__ import annotations
 
 import collections
 import re
+import zlib
 from typing import List, NamedTuple
 
 __all__ = ["collective_stats", "count_fusions", "count_ops", "hlo_text",
-           "op_histogram", "op_scopes", "scope_stats"]
+           "instruction_head", "module_name", "op_histogram", "op_scopes",
+           "scope_map", "scope_stats"]
 
 
 def hlo_text(compiled_or_text) -> str:
@@ -161,11 +165,15 @@ _OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
 # `to_apply=` are part of their instruction, not operations of their own)
 _RUNS_RE = re.compile(r"(?:body|condition|true_computation|"
                       r"false_computation)=%?([\w.\-]+)")
+_BODY_RE = re.compile(r"body=%?([\w.\-]+)")
 _BRANCHES_RE = re.compile(r"branch_computations=\{([^}]*)\}")
 _TO_APPLY_RE = re.compile(r"to_apply=%?([\w.\-]+)")
 _WORD_RE = re.compile(r"[A-Za-z_][A-Za-z0-9_]*")
 # `jit(name)` on a path is a function's name, never a scope
 _JIT_NAME_RE = re.compile(r"p?jit\([^()]*\)")
+_SCALAR_RE = re.compile(r"[a-z0-9]+\[\]")
+#: operations kept per scope path to count its executions by
+ANCHORS = 4
 _INSTR_NAME_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = ")
 _OPERAND_RE = re.compile(r"%([\w.\-]+)")
 
@@ -178,6 +186,7 @@ class _Instr(NamedTuple):
     operands: List[str]
     named: bool            # carries an op_name at all
     runs: List[str]        # computations a control-flow instruction runs
+    body: List[str]        # a ``while``'s body
 
 
 def _split_instruction(line: str):
@@ -203,9 +212,13 @@ def _split_instruction(line: str):
 
 def _walk_ops(text: str, scopes):
     """Every device operation of an HLO module text as ``(instruction
-    name, opcode, result type text, scope path, own)``: ``path`` the
-    names of ``scopes`` the operation lies under, outermost first, and
-    ``own`` whether its own ``op_name`` gave them (else inherited)."""
+    name, opcode, result type text, scope path, own, computation, loop
+    body)``: ``path`` the names of ``scopes`` the operation lies under,
+    outermost first, ``own`` whether its own ``op_name`` gave them (else
+    inherited), the computation it stands in and whether that is a
+    ``while`` body (each of its operations runs once per iteration); in
+    the text's order within each computation, the schedule's order in a
+    scheduled module."""
     known = frozenset(scopes)
     instructions = {}          # computation -> [_Instr]
     entry = current = None
@@ -236,7 +249,9 @@ def _walk_ops(text: str, scopes):
         instructions[current].append(_Instr(
             _INSTR_NAME_RE.match(line).group(1), opcode, type_text, path,
             _OPERAND_RE.findall(rest[:rest.find(")") + 1]), bool(found),
-            runs))
+            runs, _BODY_RE.findall(rest) if opcode == "while" else []))
+    bodies = {b for body in instructions.values() for i in body
+              if i.opcode == "while" for b in i.body}
     seen, todo = set(), [(entry, [])] if entry else []
     while todo:
         comp, caller_path = todo.pop()
@@ -258,7 +273,8 @@ def _walk_ops(text: str, scopes):
                 or ([] if i.named else rest)
             todo += [(c, path) for c in i.runs]
             if i.opcode not in _NOT_AN_OP:
-                yield i.name, i.opcode, i.type_text, path, bool(i.path)
+                yield (i.name, i.opcode, i.type_text, path, bool(i.path),
+                       comp, comp in bodies)
 
 
 def scope_stats(compiled_or_text, scopes) -> dict:
@@ -294,31 +310,98 @@ def scope_stats(compiled_or_text, scopes) -> dict:
     bytes, and ``ops_incl`` the operations whose path passes through the
     scope at any depth, so a layer can be read with what is nested in
     it."""
+    return scope_map(compiled_or_text, scopes)["stats"]
+
+
+def scope_map(compiled_or_text, scopes) -> dict:
+    """:func:`scope_stats` and the map from each operation to its scope
+    path, from one walk of the compiled text (the rules of
+    :func:`scope_stats`, ``inherited`` operations where it puts them).
+
+    Returns ``{"stats", "paths", "anchors", "signatures"}``: ``stats`` is
+    :func:`scope_stats`'s result; ``paths`` maps each scope path (the
+    names outermost first, joined by ``/``; ``unscoped`` for none) to its
+    operations' instruction names, the join key of a device trace's
+    events; ``anchors`` maps each path to the operations whose
+    occurrences count its executions: the path's own operations in the
+    ``while`` body that holds most of them (else in the computation that
+    does), up to :data:`ANCHORS` of them in the text's order and none with
+    a scalar result (a chip's scalar unit runs those, and its trace does
+    not record them), so each runs once per iteration of the loop that
+    carries the layer — a control step for ``rollout_step``, a substep
+    for ``sim_substep``, a gradient step for ``learn_burst``'s own
+    operations; ``signatures`` maps each operation
+    to :func:`instruction_head`'s check of its result type and opcode,
+    which a trace's event name shows beside the instruction's name."""
     scopes = tuple(scopes)
-    out = {name: {"ops": 0, "fusions": 0, "copies": 0, "out_bytes": 0,
-                  "inherited": 0, "ops_incl": 0}
-           for name in scopes + ("unscoped",)}
-    for _, opcode, type_text, path, own in _walk_ops(
+    stats = {name: {"ops": 0, "fusions": 0, "copies": 0, "out_bytes": 0,
+                    "inherited": 0, "ops_incl": 0}
+             for name in scopes + ("unscoped",)}
+    paths: dict = {}
+    signatures: dict = {}
+    homes: dict = {}       # path -> computation -> [loop body, count, ops]
+    for name, opcode, type_text, path, own, comp, body in _walk_ops(
             hlo_text(compiled_or_text), scopes):
-        rec = out[path[-1] if path else "unscoped"]
+        rec = stats[path[-1] if path else "unscoped"]
         rec["ops"] += 1
         rec["fusions"] += opcode == "fusion"
         rec["copies"] += opcode in _COPY_OPS
         rec["out_bytes"] += _shape_bytes(type_text)
         rec["inherited"] += bool(path) and not own
         for scope in set(path) or ("unscoped",):
-            out[scope]["ops_incl"] += 1
-    return out
+            stats[scope]["ops_incl"] += 1
+        key = "/".join(path) or "unscoped"
+        paths.setdefault(key, []).append(name)
+        signatures[name] = _signature(type_text, opcode)
+        if path:
+            home = homes.setdefault(key, {}).setdefault(comp, [body, 0, []])
+            home[1] += 1
+            if len(home[2]) < ANCHORS and not _SCALAR_RE.match(type_text):
+                home[2].append(name)
+    anchors = {}
+    for key, by_comp in homes.items():
+        ops = max(by_comp.values(), key=lambda h: h[:2])[2]
+        if ops:
+            anchors[key] = ops
+    return {"stats": stats, "paths": paths, "anchors": anchors,
+            "signatures": signatures}
+
+
+def _signature(type_text: str, opcode: str) -> int:
+    return zlib.crc32(f"{type_text} {opcode}".encode())
+
+
+def instruction_head(line: str):
+    """``(instruction name, signature)`` of an HLO instruction line or of
+    a device trace's event, which is named by the instruction's text
+    (``%fusion.8 = f32[8]{0} fusion(...)``); the signature is a CRC-32 of
+    the result type and the opcode.  None for a line that is no
+    instruction (a CPU trace's events carry the bare name)."""
+    name = _INSTR_NAME_RE.match(line)
+    parts = _split_instruction(line) if name else None
+    if parts is None:
+        return None
+    return name.group(1), _signature(parts[0], parts[1])
 
 
 def op_scopes(compiled_or_text, scopes) -> dict:
     """``{instruction name: innermost scope}`` (``unscoped`` where none)
-    for every device operation, by :func:`scope_stats`'s rules: the join
-    from a device trace's events, which carry the instruction's name, to
-    the program's layers."""
-    return {name: (path[-1] if path else "unscoped")
-            for name, _, _, path, _ in _walk_ops(
-                hlo_text(compiled_or_text), tuple(scopes))}
+    for every device operation: a view of :func:`scope_map`'s ``paths``,
+    the join from a device trace's events to the program's layers."""
+    return {name: key.rsplit("/", 1)[-1]
+            for key, names in scope_map(compiled_or_text,
+                                        scopes)["paths"].items()
+            for name in names}
+
+
+def module_name(compiled_or_text) -> str:
+    """The module's name as its text's first line states it
+    (``HloModule jit_chunk_step, ...`` -> ``jit_chunk_step``), the name a
+    device trace gives the program's executions; ``""`` without one."""
+    head = hlo_text(compiled_or_text).lstrip().split("\n", 1)[0]
+    if not head.startswith("HloModule "):
+        return ""
+    return head[len("HloModule "):].split(",", 1)[0].strip()
 
 
 def _inherit_paths(body) -> dict:

@@ -14,7 +14,11 @@ makes that evidence a per-run artifact: every watched jitted entry point
   op-count perf proxy — plus a small op histogram
   (while/dot/scatter/gather), the device operations per
   ``jax.named_scope`` layer (``scopes``: ops, fusions, copies, result
-  bytes for each of ``obs.trace.DEVICE_SCOPES``) and the collective-op stats
+  bytes for each of ``obs.trace.DEVICE_SCOPES``), the map from each
+  operation's instruction name to its scope path, with the module name
+  and each operation's signature that identify the program in a device
+  trace (``op_map``: what :func:`gsc_tpu.obs.trace.layer_times` joins a
+  trace's events through) and the collective-op stats
   (all-reduce/all-gather/reduce-scatter count + payload bytes) that
   make the ``tp``-vs-``sharded`` interconnect comparison machine-read
   (on a sharded dispatch the trainer additionally captures the
@@ -52,8 +56,8 @@ import logging
 import time
 from typing import Dict, Optional
 
-from ..analysis.hlo import (collective_stats, count_fusions, op_histogram,
-                            scope_stats)
+from ..analysis.hlo import (collective_stats, count_fusions, module_name,
+                            op_histogram, scope_map)
 from .trace import DEVICE_SCOPES
 
 log = logging.getLogger("gsc_tpu.obs.perf")
@@ -128,14 +132,29 @@ def resolve_lowerable(owner, name: str):
 OWN_CACHE_KEY = {"xla_detailed_logging": False}
 
 
+def scope_ledger(hlo: str):
+    """``(operations by named scope, op_map)`` of a compiled program's
+    text, from one walk (:func:`gsc_tpu.analysis.hlo.scope_map`).
+    ``op_map`` is ``{"module", "paths", "anchors", "signatures"}``: the
+    module's name, as a device trace names its executions, each scope
+    path's operations, the operations that count its executions, and
+    each operation's result-type-and-opcode check, which tells programs
+    of one name apart in a trace (``obs.trace.join_scopes``)."""
+    walked = scope_map(hlo, DEVICE_SCOPES)
+    return walked["stats"], {
+        "module": module_name(hlo), "paths": walked["paths"],
+        "anchors": walked["anchors"], "signatures": walked["signatures"]}
+
+
 def _mine_scopes(compiled):
-    """``(hlo text, operations by named scope)`` of a compiled program;
-    ``("", {})`` on a backend without HLO text access."""
+    """``(hlo text, operations by named scope, op_map)`` of a compiled
+    program (:func:`scope_ledger`); ``("", {}, None)`` on a backend
+    without HLO text access."""
     try:
         hlo = compiled.as_text()
     except Exception:
-        return "", {}
-    return hlo, (scope_stats(hlo, DEVICE_SCOPES) if hlo else {})
+        hlo = ""
+    return (hlo, *scope_ledger(hlo)) if hlo else ("", {}, None)
 
 
 def _cost_dict(compiled) -> Dict[str, float]:
@@ -201,7 +220,8 @@ class CostLedger:
             fn, args, kwargs = _unwrap_partial(fn, args, kwargs)
             lowered = fn.lower(*args, **kwargs)
             compiled = lowered.compile()
-            hlo, scopes = _mine_scopes(compiled)
+            mined = _mine_scopes(compiled)
+            scopes = mined[1]
             if scopes and not any(rec["ops"] for scope, rec in scopes.items()
                                   if scope != "unscoped"):
                 # no scope name at all: a cache hit compiled from a source
@@ -210,8 +230,8 @@ class CostLedger:
                          "carries no scope names, compiling the lowering "
                          "under a cache key of its own", name)
                 compiled = lowered.compile(compiler_options=OWN_CACHE_KEY)
-                hlo, scopes = _mine_scopes(compiled)
-            entry = self.capture_compiled(name, compiled, hlo, scopes)
+                mined = _mine_scopes(compiled)
+            entry = self.capture_compiled(name, compiled, mined)
             entry["capture_s"] = round(time.perf_counter() - t0, 3)
             return entry
         except Exception as e:  # noqa: BLE001 - observability must not kill
@@ -221,14 +241,12 @@ class CostLedger:
                                    "error": f"{type(e).__name__}: {e}"}
             return self._entries[name]
 
-    def capture_compiled(self, name: str, compiled, hlo=None,
-                         scopes=None) -> Dict:
+    def capture_compiled(self, name: str, compiled, mined=None) -> Dict:
         """Record an already-compiled ``jax.stages.Compiled`` (the serve
-        path holds one per bucket after warmup).  ``hlo``/``scopes`` are
+        path holds one per bucket after warmup).  ``mined`` is
         :func:`_mine_scopes`'s result where the caller already has it."""
         cost = _cost_dict(compiled)
-        if hlo is None:
-            hlo, scopes = _mine_scopes(compiled)
+        hlo, scopes, op_map = mined or _mine_scopes(compiled)
         entry: Dict = {
             "available": True,
             "flops": float(cost.get("flops", 0.0)),
@@ -244,6 +262,9 @@ class CostLedger:
             # device operations by named scope (obs.trace.DEVICE_SCOPES
             # plus `unscoped`): the program's op count put down to layers
             "scopes": scopes,
+            # every operation's scope path, and what identifies the
+            # program in a device trace: the join from events to layers
+            "op_map": op_map,
         }
         if entry["flops"] and entry["bytes_accessed"]:
             entry["arithmetic_intensity"] = round(
@@ -265,7 +286,8 @@ class CostLedger:
                            fusions=entry["fusions"],
                            ops=entry["ops"],
                            collectives=entry["collectives"],
-                           scopes=entry["scopes"])
+                           scopes=entry["scopes"],
+                           op_map=entry["op_map"])
             if entry["fusions"] is not None:
                 self.hub.gauge("compile_fusions", entry["fusions"], fn=name)
         return entry

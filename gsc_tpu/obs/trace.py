@@ -1,6 +1,7 @@
-"""Profiler annotations + the events.jsonl -> Perfetto trace exporter.
+"""Profiler annotations, device time by layer from a profiler trace, and
+the events.jsonl -> Perfetto trace exporter.
 
-Two halves, one module (both are "how a run becomes a timeline"):
+Three parts, one module (each is "how a run becomes a timeline"):
 
 **Live annotations** — ``--profile`` traces of the pipelined trainer used
 to be one opaque blob: the fused rollout+learn program, the prefetch
@@ -17,6 +18,16 @@ event per episode.  The device program's layers carry
 ``jax.named_scope`` names from :data:`DEVICE_SCOPES`; they reach the
 compiled HLO as ``op_name`` metadata, where
 :func:`gsc_tpu.analysis.hlo.scope_stats` counts operations by them.
+
+**Device time by layer** — a profiler trace names each device operation
+by its instruction and each program execution by its module;
+:func:`layer_times` joins those events to the map from instruction to
+scope path that the cost ledger keeps for every program it captured
+(``obs.perf``: ``op_map``), and each device-idle gap to the innermost
+host span open through it: device seconds per layer, whole executions of
+each layer, each program's join coverage and own idle share
+(``tools/obs_report.py`` renders them for a ``--profile`` run).  Only
+:func:`load_profile` needs JAX; the join takes plain lists.
 
 **Post-hoc export** — a run's ``events.jsonl`` already carries everything
 a timeline needs (episode boundaries, cumulative PhaseTimer totals,
@@ -47,11 +58,13 @@ inside each episode's span and clamped to it.
 (monotone ts per track, matched B/E pairs, pid/tid present) that CI and
 the exporter gate on; ``tools/trace_export.py`` is the CLI.
 
-The export half is deliberately jax-free (stdlib + the sibling sinks
-reader) — it must run anywhere the events stream can be copied to.
+The join and the export are deliberately jax-free (stdlib + the sibling
+sinks reader) — they must run anywhere a trace or an events stream can
+be copied to.
 """
 from __future__ import annotations
 
+import heapq
 import json
 import os
 import threading
@@ -133,6 +146,401 @@ def episode_span(step: int, name: str = "episode_step"):
 
     with jax.profiler.StepTraceAnnotation(name, step_num=int(step)):
         yield
+
+
+# ---------------------------------------------------- device time by layer
+# A ``jax.profiler`` trace of a TPU has one plane per chip; on it the
+# ``XLA Ops`` line holds one event per operation executed, named by its
+# instruction's text, and the ``XLA Modules`` line one per program
+# execution, named ``<module>(<program id>)``.  The functions below join
+# those events to the map the cost ledger keeps for each program it
+# captured (``obs.perf``: ``op_map``), and the device's idle gaps to the
+# host's ``phase_span`` names, which the profiler records on its own
+# clock.  Everything but :func:`load_profile` takes plain lists.
+OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
+NO_MODULE = "(no module)"
+# a program whose map covers less of its busy time is read as unmapped:
+# a layer's seconds are never read off a partial join
+MIN_COVERAGE = 0.99
+
+
+def find_profile(trace_dir: str) -> str:
+    """The newest ``.xplane.pb`` a profiler trace wrote under
+    ``trace_dir`` (``plugins/profile/<time>/``)."""
+    import glob
+
+    found = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
+    if not found:
+        raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
+    return found[-1]
+
+
+def _is_device_plane(name: str) -> bool:
+    """``/device:TPU:0``: one plane per chip (a chip's extra planes carry
+    a suffix after the number)."""
+    return name.startswith("/device:") and \
+        name.rsplit(":", 1)[-1].strip().isdigit()
+
+
+def load_profile(path: str, span_names=SPAN_NAMES) -> Dict:
+    """A profiler trace as plain lists: ``{"devices": {plane: {"ops",
+    "modules"}}, "spans"}``, each a list of ``(name, start_ns,
+    duration_ns)`` — each chip's ``XLA Ops`` and ``XLA Modules`` events,
+    and the host's events named in ``span_names``.  A CPU run's trace has
+    no chip plane, so ``devices`` is empty there."""
+    from jax.profiler import ProfileData
+
+    lines = {OPS_LINE: "ops", MODULES_LINE: "modules"}
+    wanted = frozenset(span_names)
+    devices: Dict[str, Dict] = {}
+    spans: List[tuple] = []
+    for plane in ProfileData.from_file(path).planes:
+        if _is_device_plane(plane.name):
+            dev = devices.setdefault(plane.name, {"ops": [], "modules": []})
+            for line in plane.lines:
+                if line.name in lines:
+                    dev[lines[line.name]] = [
+                        (e.name, e.start_ns, e.duration_ns)
+                        for e in line.events]
+        elif plane.name.startswith("/host:"):
+            spans += [(e.name, e.start_ns, e.duration_ns)
+                      for line in plane.lines for e in line.events
+                      if e.name in wanted]
+    return {"devices": devices, "spans": spans}
+
+
+def _module(event_name: str):
+    """``jit_chunk_step(123)`` -> ``("jit_chunk_step", "123")``."""
+    base, sep, rest = event_name.rpartition("(")
+    if sep and rest.endswith(")"):
+        return base, rest[:-1]
+    return event_name, None
+
+
+def _leaf_ops(ops) -> List[tuple]:
+    """The operations that enclose no other recorded operation (a
+    ``while`` spans its body's), sorted by start: the device is busy
+    during these alone."""
+    ordered = sorted(ops, key=lambda o: (o[1], -o[2]))
+    parent = [False] * len(ordered)
+    stack: List[tuple] = []           # (index, end)
+    for i, (_, start, dur) in enumerate(ordered):
+        while stack and stack[-1][1] <= start:
+            stack.pop()
+        if stack:
+            parent[stack[-1][0]] = True
+        stack.append((i, start + dur))
+    return [o for o, p in zip(ordered, parent) if not p]
+
+
+def _choose_map(heads, candidates):
+    """The one map among ``candidates`` (maps of the program's module
+    name) under which every instruction ``heads`` shows — ``{name:
+    signature}``, the program's distinct events — has that signature;
+    None where no map, or more than one, passes."""
+    passing = []
+    for op_map in candidates:
+        sigs = op_map.get("signatures") or {}
+        seen = [sigs.get(name) for name in heads]
+        if any(got is not None for got in seen) and all(
+                got == sig for got, sig in zip(seen, heads.values())
+                if got is not None):
+            passing.append(op_map)
+    return passing[0] if len(passing) == 1 else None
+
+
+def join_scopes(device: Dict, maps) -> Dict:
+    """One device's leaf operations joined to the programs' layers.
+
+    ``device`` is one plane of :func:`load_profile`; ``maps`` the
+    ``op_map`` of each captured program (a ``compile_cost`` event's, a
+    ``perf.json`` entry's).  Each leaf operation goes to the module
+    execution that holds its start.  A device trace names a program
+    ``<module>(<id>)``, where the id is the runtime's own fingerprint,
+    which the compiled object does not report; so a program's map is the
+    one of its module name under which every operation the trace shows
+    of the program has the same result type and opcode
+    (:func:`gsc_tpu.analysis.hlo.instruction_head`) — two programs of one
+    name, as ``chunk_step`` with and without its learn burst, share
+    instruction names but not their types — and where no map, or more
+    than one, passes that check the program stays unmapped.  Returns
+    ``{"leaves": [(start_ns, end_ns, execution, instruction)],
+    "executions": [(module name, start_ns, end_ns, map or None,
+    {instruction: scope path} or None)]}``; an operation outside every
+    recorded execution has execution -1.  Each distinct event name is
+    parsed once."""
+    from ..analysis.hlo import instruction_head
+
+    order = sorted(device.get("modules") or [], key=lambda e: e[1])
+    parsed: Dict[str, tuple] = {}
+    heads: Dict[str, Dict[str, int]] = {}      # program -> {op: signature}
+    leaves = []
+    k = 0
+    for name, start, dur in _leaf_ops(device.get("ops") or []):
+        head = parsed.get(name)
+        if head is None:
+            head = parsed[name] = instruction_head(name) or (name, None)
+        while k < len(order) and order[k][1] + order[k][2] <= start:
+            k += 1
+        inside = k < len(order) and order[k][1] <= start
+        if inside and head[1] is not None:
+            heads.setdefault(order[k][0], {})[head[0]] = head[1]
+        leaves.append((start, start + dur, k if inside else -1, head[0]))
+    maps = [m for m in maps if m]
+    chosen = {}
+    for program, seen in heads.items():
+        op_map = _choose_map(seen, [m for m in maps if m.get("module")
+                                    == _module(program)[0]])
+        if op_map is not None:
+            chosen[program] = (op_map, {
+                op: path for path, ops in op_map["paths"].items()
+                for op in ops})
+    executions = [(_module(name)[0], start, start + dur,
+                   *chosen.get(name, (None, None)))
+                  for name, start, dur in order]
+    return {"leaves": leaves, "executions": executions}
+
+
+def scope_seconds(joined: Dict) -> Dict:
+    """Device seconds of the leaf operations by layer: ``innermost``
+    (each operation under the last scope of its path), ``inclusive``
+    (under every scope of its path, as ``scope_stats``' ``ops_incl``
+    counts), ``unscoped`` (operations of a mapped program under no
+    scope), ``unmatched`` (by module: operations of a mapped program
+    that its map lacks) and ``unmapped`` (by module: operations of a
+    program with no map, and ``(no module)`` for those outside every
+    recorded execution) — never guessed into a scope."""
+    inner: Dict[str, float] = {}
+    incl: Dict[str, float] = {}
+    unmatched: Dict[str, float] = {}
+    unmapped: Dict[str, float] = {}
+    unscoped = 0.0
+    execs = joined["executions"]
+    for start, end, k, instr in joined["leaves"]:
+        s = (end - start) * 1e-9
+        op_map = execs[k][3] if k >= 0 else None
+        if op_map is None:
+            label = execs[k][0] if k >= 0 else NO_MODULE
+            unmapped[label] = unmapped.get(label, 0.0) + s
+            continue
+        path = execs[k][4].get(instr)
+        if path is None:
+            unmatched[execs[k][0]] = unmatched.get(execs[k][0], 0.0) + s
+        elif path == "unscoped":
+            unscoped += s
+        else:
+            names = path.split("/")
+            inner[names[-1]] = inner.get(names[-1], 0.0) + s
+            for name in set(names):
+                incl[name] = incl.get(name, 0.0) + s
+    return {"innermost": inner, "inclusive": incl, "unscoped": unscoped,
+            "unmatched": unmatched, "unmapped": unmapped}
+
+
+def join_coverage(joined: Dict) -> Dict[str, float]:
+    """By module name: the share of a mapped program's busy time (its
+    leaf operations' seconds) whose instruction its map holds."""
+    found: Dict[str, List[float]] = {}
+    execs = joined["executions"]
+    for start, end, k, instr in joined["leaves"]:
+        if k < 0 or execs[k][3] is None:
+            continue
+        rec = found.setdefault(execs[k][0], [0.0, 0.0])
+        rec[1] += end - start
+        if instr in execs[k][4]:
+            rec[0] += end - start
+    return {m: (a / b if b else 0.0) for m, (a, b) in found.items()}
+
+
+def _prefixes(path: str) -> List[str]:
+    """``a/b/c`` -> ``["a", "a/b", "a/b/c"]``: the paths it lies under."""
+    names = path.split("/")
+    return ["/".join(names[:i]) for i in range(1, len(names) + 1)]
+
+
+def scope_executions(joined: Dict, prefixes=None) -> Dict[str, Dict]:
+    """Whole executions of scope paths in the trace, and the device
+    seconds of the leaf operations under each (itself and every path
+    nested in it) per execution: ``{path: {"executions", "seconds",
+    "per_execution_s"}}`` for each of ``prefixes`` (every path a map
+    anchors, by default) that some program shows twice.
+
+    The rule: executions are counted between consecutive occurrences of
+    the path's anchor — the first of its map's ``anchors`` (operations
+    that run once per iteration of the loop that carries the layer) that
+    the trace shows.  From the anchor's first occurrence to its last lie
+    exactly k - 1 whole executions for k occurrences, and the seconds
+    under the path that start in that interval are theirs: the execution
+    a trace cuts at its start or at its end is never counted.
+    Occurrences are counted per program (map) and summed over them."""
+    execs = joined["executions"]
+    wanted: Dict[int, Dict[str, List[str]]] = {}    # map -> op -> paths
+    for e in execs:
+        if e[3] is not None and id(e[3]) not in wanted:
+            ops = wanted[id(e[3])] = {}
+            for path, anchors in e[3].get("anchors", {}).items():
+                if prefixes is None or path in prefixes:
+                    for op in anchors:
+                        ops.setdefault(op, []).append(path)
+    marks: Dict[tuple, Dict[str, List[float]]] = {}  # (map, path) -> starts
+    for start, _, k, instr in joined["leaves"]:
+        if k >= 0 and execs[k][3] is not None:
+            for path in wanted[id(execs[k][3])].get(instr, ()):
+                marks.setdefault((id(execs[k][3]), path), {}).setdefault(
+                    instr, []).append(start)
+    windows: Dict[tuple, tuple] = {}
+    for e in execs:
+        for path, anchors in (e[3] or {}).get("anchors", {}).items():
+            seen = marks.get((id(e[3]), path))
+            if seen and (id(e[3]), path) not in windows:
+                starts = seen[next(op for op in anchors if op in seen)]
+                if len(starts) >= 2:
+                    windows[(id(e[3]), path)] = (starts[0], starts[-1],
+                                                 len(starts) - 1)
+    # per map and operation, once: the windows of the paths it lies under
+    plans: Dict[int, Dict[str, List[tuple]]] = {}
+    seconds: Dict[str, float] = {}
+    for start, end, k, instr in joined["leaves"]:
+        if k < 0 or execs[k][3] is None:
+            continue
+        plan = plans.setdefault(id(execs[k][3]), {})
+        todo = plan.get(instr)
+        if todo is None:
+            path = execs[k][4].get(instr)
+            todo = plan[instr] = [
+                (prefix, *windows[(id(execs[k][3]), prefix)][:2])
+                for prefix in (_prefixes(path) if path and path != "unscoped"
+                               else ())
+                if (id(execs[k][3]), prefix) in windows]
+        for prefix, w0, w1 in todo:
+            if w0 <= start < w1:
+                seconds[prefix] = seconds.get(prefix, 0.0) + \
+                    (end - start) * 1e-9
+    out: Dict[str, Dict] = {}
+    for (_, path), (_, _, n) in windows.items():
+        rec = out.setdefault(path, {"executions": 0, "seconds": 0.0})
+        rec["executions"] += n
+    for path, rec in out.items():
+        rec["seconds"] = seconds.get(path, 0.0)
+        rec["per_execution_s"] = rec["seconds"] / rec["executions"]
+    return out
+
+
+def _union(intervals) -> List[tuple]:
+    merged: List[list] = []
+    for start, end in sorted(intervals):
+        if merged and start <= merged[-1][1]:
+            merged[-1][1] = max(merged[-1][1], end)
+        else:
+            merged.append([start, end])
+    return [(a, b) for a, b in merged]
+
+
+def program_busy(joined: Dict) -> Dict[str, Dict]:
+    """By module name: the recorded executions, the seconds they span
+    (each clipped to the trace's first and last leaf operation, so an
+    execution the trace cuts counts for its traced part) and the busy
+    seconds of the leaf operations inside them: ``1 - busy_s / span_s``
+    is the idle share inside the program, without the host's gaps
+    between its executions."""
+    leaves = joined["leaves"]
+    if not leaves:
+        return {}
+    busy: Dict[int, float] = {}
+    reach: Dict[int, float] = {}       # execution -> end of its busy run
+    for start, end, k, _ in leaves:    # sorted by start
+        if k < 0:
+            continue
+        last = reach.get(k, start)
+        if end > last:
+            busy[k] = busy.get(k, 0.0) + end - (start if start > last
+                                                else last)
+            reach[k] = end
+    lo = leaves[0][0]
+    hi = max(end for _, end, _, _ in leaves)
+    out: Dict[str, Dict] = {}
+    for k, (module, start, end, _, _) in enumerate(joined["executions"]):
+        span = min(end, hi) - max(start, lo)
+        if span <= 0:
+            continue
+        rec = out.setdefault(module, {"executions": 0, "span_s": 0.0,
+                                      "busy_s": 0.0})
+        rec["executions"] += 1
+        rec["span_s"] += span * 1e-9
+        rec["busy_s"] += busy.get(k, 0.0) * 1e-9
+    return out
+
+
+def idle_spans(leaves, spans, top: int = 5) -> List[List]:
+    """The ``top`` longest device-idle gaps between leaf operations
+    (``(start_ns, end_ns, ...)`` sorted by start, as :func:`join_scopes`
+    gives them), each ``[span, seconds]`` under the host span that held
+    most of it.  At each instant of a gap the host is in the innermost of
+    the ``spans`` (``(name, start_ns, duration_ns)`` on the device's
+    clock) open then — nesting decided by containment, so the shortest
+    of those that cover the instant — or in ``none``; the gap goes to the
+    name that held the most of its time."""
+    found = []
+    reach = None
+    for start, end, *_ in leaves:      # sorted by start
+        if reach is not None and start > reach:
+            found.append((start - reach, reach, start))
+        if reach is None or end > reach:
+            reach = end
+    out = []
+    for length, g0, g1 in heapq.nlargest(top, found):
+        open_ = [(max(start, g0), min(start + dur, g1), dur, name)
+                 for name, start, dur in spans
+                 if start < g1 and start + dur > g0]
+        cuts = sorted({g0, g1} | {t for a, b, _, _ in open_ for t in (a, b)})
+        held: Dict[str, float] = {}
+        for a, b in zip(cuts[:-1], cuts[1:]):
+            inner = min(((dur, name) for lo, hi, dur, name in open_
+                         if lo <= a and b <= hi), default=(0, "none"))[1]
+            held[inner] = held.get(inner, 0.0) + b - a
+        out.append([max(held, key=held.get), length * 1e-9])
+    return out
+
+
+def _add(into: Dict, more: Dict) -> None:
+    """Sum ``more``'s numbers into ``into``, nested dicts key by key."""
+    for key, val in more.items():
+        if isinstance(val, dict):
+            _add(into.setdefault(key, {}), val)
+        else:
+            into[key] = into.get(key, 0) + val
+
+
+def layer_times(loaded: Dict, maps, top: int = 5) -> Dict:
+    """:func:`load_profile`'s trace read through the captured ``maps``,
+    summed over the chips: device seconds by scope, whole executions of
+    every anchored scope path, each mapped program's join coverage, the
+    programs' own busy and span seconds and the longest idle gaps under
+    the host's spans.  A program whose coverage is under
+    :data:`MIN_COVERAGE` on a chip is read there as unmapped."""
+    maps = [m for m in maps if m]
+    out = {"scopes": {}, "executions": {}, "coverage": {}, "programs": {},
+           "idle_spans": []}
+    for device in loaded["devices"].values():
+        joined = join_scopes(device, maps)
+        coverage = join_coverage(joined)
+        joined["executions"] = [
+            e if e[3] is None or coverage[e[0]] >= MIN_COVERAGE
+            else (*e[:3], None, None) for e in joined["executions"]]
+        _add(out["scopes"], scope_seconds(joined))
+        _add(out["executions"], scope_executions(joined))
+        for module, share in coverage.items():
+            out["coverage"][module] = min(share, out["coverage"].get(
+                module, 1.0))
+        _add(out["programs"], program_busy(joined))
+        out["idle_spans"] += idle_spans(joined["leaves"], loaded["spans"],
+                                        top)
+    for rec in out["executions"].values():
+        rec["per_execution_s"] = rec["seconds"] / rec["executions"]
+    out["idle_spans"] = sorted(out["idle_spans"], key=lambda g: -g[1])[:top]
+    return out
 
 
 # --------------------------------------------------------------- exporter
@@ -655,15 +1063,3 @@ def validate_trace(trace: Dict) -> List[str]:
         if n:
             errors.append(f"flow start without finish (id {fid!r})")
     return errors
-
-
-def export_trace(src: str, out_path: Optional[str] = None):
-    """events.jsonl (or run dir) -> validated trace dict; optionally
-    written to ``out_path``.  Returns ``(trace, errors)`` — the caller
-    decides whether a non-empty error list is fatal."""
-    trace = build_trace(read_events(src))
-    errors = validate_trace(trace)
-    if out_path is not None:
-        with open(out_path, "w") as f:
-            json.dump(trace, f)
-    return trace, errors

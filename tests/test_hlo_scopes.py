@@ -1,9 +1,10 @@
 """Device operations by ``jax.named_scope`` from compiled HLO text
-(``analysis.hlo.scope_stats`` / ``op_scopes``) and their place in the cost
-ledger (``obs.perf``): the counting rule on hand-written text and on a toy
-``jit(vmap(scan))``, every layer of the tiny ``chunk_step(learn=True)``,
-and the capture's second compile when a cached executable carries the
-names of an older source."""
+(``analysis.hlo.scope_stats`` / ``scope_map`` / ``op_scopes``) and their
+place in the cost ledger (``obs.perf``): the counting rule on hand-written
+text and on a toy ``jit(vmap(scan))``, every layer of the tiny
+``chunk_step(learn=True)``, the map from each operation to its scope path
+that the same walk keeps, and the capture's second compile when a cached
+executable carries the names of an older source."""
 import json
 
 import jax
@@ -12,7 +13,8 @@ import numpy as np
 import pytest
 from jax.experimental.compilation_cache import compilation_cache
 
-from gsc_tpu.analysis.hlo import op_scopes, scope_stats
+from gsc_tpu.analysis.hlo import (instruction_head, op_scopes, scope_map,
+                                  scope_stats)
 from gsc_tpu.obs import ListSink, MetricsHub
 from gsc_tpu.obs import perf as perf_mod
 from gsc_tpu.obs.trace import DEVICE_SCOPES, TORSO_SCOPES
@@ -167,6 +169,61 @@ def test_toy_vmap_scan_with_three_scopes():
     assert len(op_scopes(compiled, SCOPES)) == total
 
 
+# ------------------------------------------------ the map from one walk
+@pytest.mark.parametrize("source", ["hand_written", "tiny_chunk_step"])
+def test_map_from_the_one_walk_counts_as_scope_stats(source, request):
+    if source == "hand_written":
+        walked, scopes = scope_map(TEXT, SCOPES), SCOPES
+        assert walked["stats"] == scope_stats(TEXT, SCOPES)
+        # op_scopes is the map's view: each operation's innermost scope
+        assert op_scopes(TEXT, SCOPES) == {
+            n: path.rsplit("/", 1)[-1]
+            for path, ops in walked["paths"].items() for n in ops}
+    else:
+        walked, scopes = request.getfixturevalue("tiny_walk"), DEVICE_SCOPES
+    stats, paths = walked["stats"], walked["paths"]
+    # every operation once, under one path
+    names = [n for ops in paths.values() for n in ops]
+    assert len(names) == len(set(names)) == sum(
+        v["ops"] for v in stats.values())
+    # the inclusive count of each scope from the map is scope_stats'
+    for scope in scopes:
+        assert sum(len(ops) for path, ops in paths.items()
+                   if scope in path.split("/")) == stats[scope]["ops_incl"]
+    assert len(paths.get("unscoped", [])) == stats["unscoped"]["ops_incl"]
+    # a signature for every operation, anchors only from the map's paths
+    assert set(walked["signatures"]) == set(names)
+    assert set(walked["anchors"]) <= set(paths)
+    for path, anchors in walked["anchors"].items():
+        assert anchors and set(anchors) <= set(paths[path])
+
+
+def test_anchors_run_once_per_iteration_of_the_layers_loop():
+    walked = scope_map(TEXT, SCOPES)
+    # layer_b/layer_a: its three operations in the loop body %body;
+    # layer_b: of its own, the loop body holds two (the reduce, whose
+    # result is a scalar the chip's trace never shows, and %loose), the
+    # entry one (%staged); layer_c: the loop the compiler made, whose
+    # scalar counter and test do not count
+    assert walked["anchors"] == {"layer_b/layer_a": ["start", "done", "work"],
+                                 "layer_b": ["loose"], "layer_c": ["move"]}
+
+
+def test_signatures_are_what_a_trace_event_shows():
+    walked = scope_map(TEXT, SCOPES)
+    # a chip's trace names an operation by its instruction's text, with
+    # the operands' types and without the metadata
+    event = ("%work = f32[8]{0} fusion(f32[8]{0} %done), kind=kLoop, "
+             "calls=%fused")
+    assert instruction_head(event) == ("work", walked["signatures"]["work"])
+    assert instruction_head("%start = (f32[8]{0}, f32[8]{0}, u32[]) "
+                            "copy-start(f32[8]{0} %c1)") == \
+        ("start", walked["signatures"]["start"])
+    assert walked["signatures"]["work"] != walked["signatures"]["done"]
+    # a CPU trace's bare instruction name carries no signature
+    assert instruction_head("work") is None
+
+
 # ------------------------------------------------- the tiny chunk_step
 @pytest.fixture(scope="module")
 def tiny():
@@ -185,7 +242,7 @@ def tiny():
 
 
 @pytest.fixture(scope="module")
-def tiny_stats(tiny):
+def tiny_walk(tiny):
     fn, args, kwargs = tiny
     # past the persistent cache: it keys a program on its operations, not
     # its names, so filled by an older source it would hand back that
@@ -199,7 +256,12 @@ def tiny_stats(tiny):
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
         compilation_cache.reset_cache()
-    return scope_stats(compiled, DEVICE_SCOPES)
+    return scope_map(compiled, DEVICE_SCOPES)
+
+
+@pytest.fixture(scope="module")
+def tiny_stats(tiny_walk):
+    return tiny_walk["stats"]
 
 
 # (a looped torso's scopes stand in its own program: tests/test_torso.py)
@@ -255,9 +317,17 @@ def test_capture_puts_scopes_in_entry_event_and_document(tiny):
         <= entry["fusions"]
     event = sink.of_kind("compile_cost")[0]
     assert event["fn"] == "chunk_step" and event["scopes"] == entry["scopes"]
-    assert len(json.dumps(event)) < 4096
+    # the event's counts stay compact; the map beside them is the join's
+    assert len(json.dumps({k: v for k, v in event.items()
+                           if k != "op_map"})) < 4096
+    op_map = entry["op_map"]
+    assert event["op_map"] == op_map and op_map["module"] == "jit_chunk_step"
+    ops = sum(v["ops"] for v in entry["scopes"].values())
+    assert sum(len(v) for v in op_map["paths"].values()) == ops
+    assert len(json.dumps(op_map)) < 64 * ops
     doc = ledger.summary()
     assert doc["entries"]["chunk_step"]["scopes"] == entry["scopes"]
+    assert doc["entries"]["chunk_step"]["op_map"] == op_map
 
 
 class Lowered:

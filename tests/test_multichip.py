@@ -271,7 +271,7 @@ def test_lockstep_ring_write_partitions_with_no_collective_in_the_scan():
     text = compiled.as_text()
     kinds = COLLECTIVE_OPS + tuple(f"{op}-start" for op in COLLECTIVE_OPS)
     collectives = [(name, op, type_text, path)
-                   for name, op, type_text, path, _ in _walk_ops(
+                   for name, op, type_text, path, *_ in _walk_ops(
                        text, DEVICE_SCOPES) if op in kinds]
     assert collectives                               # it IS partitioned
     in_scan = [c for c in collectives if "rollout_step" in c[3]

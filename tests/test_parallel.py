@@ -418,7 +418,8 @@ def test_replay_write_has_no_loop_over_replicas():
                 pddpg, state, buffers, env_states, obs, topo, traffic,
                 np.int32(0), num_steps=2, learn=True).compile()
             text = compiled.as_text()
-            ops = [op for _, op, _, path, _ in _walk_ops(text, DEVICE_SCOPES)
+            ops = [op for _, op, _, path, *_ in _walk_ops(text,
+                                                          DEVICE_SCOPES)
                    if "replay_write" in path]
             assert ops and "scatter" not in ops
             # a loop the compiler makes of a scatter is run by a `while`

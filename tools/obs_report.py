@@ -44,6 +44,15 @@ does by default), prints:
   adoptions), the learner's policy-lag percentiles and wall
   decomposition (ingest vs learn-burst vs idle), and the weight
   adoption timeline (publish -> per-actor adopt latency per version);
+- a "device time by layer" section for a run made with ``cli train
+  --profile``: the newest trace under ``<result_dir>/profile`` read
+  through the operation-to-scope map each ``perf.json`` entry keeps
+  (``op_map``; ``gsc_tpu.obs.trace.layer_times``): device seconds per
+  ``jax.named_scope`` layer, whole executions of each layer and their
+  device time, each program's join coverage and own idle share, the
+  programs with no map, and the longest device-idle gaps under the host
+  span open through them.  Reading the trace needs JAX; everything else
+  here does not;
 - a serving section for ``cli serve`` runs, from the ``serve_start`` /
   ``serve_stats`` events (gsc_tpu.serve.PolicyServer): tier, requests/s,
   p50/p99 latency overall and per batch bucket, bucket occupancy,
@@ -57,7 +66,9 @@ does by default), prints:
 ``--selftest`` synthesizes a stream (including a stall and a leak),
 renders it, and asserts both are flagged — the CI smoke target.
 
-Stdlib only: this must run on a login node with no JAX installed.
+Stdlib only: this must run on a login node with no JAX installed (the
+device-time section alone imports the package, and JAX, to read a
+trace; without them it says so).
 """
 from __future__ import annotations
 
@@ -139,6 +150,37 @@ def load_perf(path: str) -> Optional[Dict]:
             return json.load(f)
     except (OSError, json.JSONDecodeError):
         return None
+
+
+def layer_summary(path: str, perf: Optional[Dict]) -> Optional[Dict]:
+    """Device time by layer of a run made with ``cli train --profile``:
+    the newest trace under ``<result_dir>/profile`` joined to the
+    ``op_map`` of every ``perf.json`` entry.  None without a trace or
+    without a ledger; ``{"error": ...}`` where the trace cannot be read
+    (JAX reads it) or holds no chip's operations."""
+    run_dir = path if os.path.isdir(path) else \
+        os.path.dirname(os.path.abspath(path))
+    profile = os.path.join(run_dir, "profile")
+    maps = [e.get("op_map") for e in
+            ((perf or {}).get("entries") or {}).values() if e]
+    if not os.path.isdir(profile) or not any(maps):
+        return None
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    try:
+        from gsc_tpu.obs.trace import find_profile, layer_times, \
+            load_profile
+        xplane = find_profile(profile)
+        loaded = load_profile(xplane)
+    except FileNotFoundError:
+        return None
+    except ImportError as e:
+        return {"error": f"{type(e).__name__}: {e}"}
+    if not loaded["devices"]:
+        return {"error": "the trace holds no chip plane (a CPU run)"}
+    return {"trace": os.path.relpath(xplane, run_dir),
+            **layer_times(loaded, maps)}
 
 
 def phase_deltas(episodes: List[Dict]) -> List[Dict[str, float]]:
@@ -666,6 +708,45 @@ def _fmt(v, width) -> str:
     return s.rjust(width)
 
 
+def _render_layers(layers: Dict, w) -> None:
+    """The "device time by layer" section (:func:`layer_summary`)."""
+    w("\ndevice time by layer")
+    if layers.get("error"):
+        w(f": trace not read ({layers['error']})\n")
+        return
+    w(f" ({layers.get('trace')}):\n")
+    scopes = layers.get("scopes") or {}
+    inner = scopes.get("innermost") or {}
+    incl = scopes.get("inclusive") or {}
+    w(f"  {'scope':<18} {'self_ms':>10} {'with_nested_ms':>15}\n")
+    for name in sorted(incl, key=lambda n: -incl[n]):
+        w(f"  {name:<18} {1e3 * inner.get(name, 0.0):>10.3f} "
+          f"{1e3 * incl[name]:>15.3f}\n")
+    unscoped = 1e3 * (scopes.get("unscoped") or 0.0)
+    w(f"  {'unscoped':<18} {unscoped:>10.3f}\n")
+    for kind in ("unmatched", "unmapped"):
+        for module, sec in sorted((scopes.get(kind) or {}).items()):
+            w(f"  {kind} {module}: {1e3 * sec:.3f} ms\n")
+    execs = layers.get("executions") or {}
+    if execs:
+        w(f"  {'whole executions':<40} {'count':>7} {'ms_each':>10}\n")
+        for path, rec in sorted(execs.items()):
+            w(f"  {path:<40} {rec['executions']:>7} "
+              f"{1e3 * rec['per_execution_s']:>10.4f}\n")
+    for module, share in sorted((layers.get("coverage") or {}).items()):
+        prog = (layers.get("programs") or {}).get(module) or {}
+        idle = (100 * (1 - prog["busy_s"] / prog["span_s"])
+                if prog.get("span_s") else None)
+        w(f"  {module}: join coverage {100 * share:.2f}%, "
+          f"{prog.get('executions', 0)} executions, idle inside "
+          f"{'-' if idle is None else f'{idle:.2f}%'}\n")
+    gaps = layers.get("idle_spans") or []
+    if gaps:
+        w("  longest device-idle gaps (host span open): "
+          + ", ".join(f"{name} {1e3 * sec:.3f} ms" for name, sec in gaps)
+          + "\n")
+
+
 def render_text(summary: Dict, out=sys.stdout):
     w = out.write
     w(f"run: {summary['run']}  episodes: {summary['episodes']}  "
@@ -893,6 +974,9 @@ def render_text(summary: Dict, out=sys.stdout):
         dvh = perf.get("device_vs_host") or {}
         w(f"  device-vs-host wall: dispatch {dvh.get('dispatch_s')}s / "
           f"host {dvh.get('host_s')}s\n")
+    layers = summary.get("layers")
+    if layers:
+        _render_layers(layers, w)
     w("\nper-phase host wall (cumulative):\n")
     for name, info in summary["phase_summary"].items():
         w(f"  {name:<18} total {info['total_s']:>9}s   "
@@ -1470,10 +1554,12 @@ def main(argv: Optional[List[str]] = None) -> int:
         return selftest()
     if not args.path:
         ap.error("path required (or --selftest)")
+    perf = load_perf(args.path)
     summary = summarize(load_events(args.path),
                         mem_growth_threshold=args.mem_growth_threshold,
                         retrace_threshold=args.retrace_threshold,
-                        perf=load_perf(args.path))
+                        perf=perf)
+    summary["layers"] = layer_summary(args.path, perf)
     if args.json:
         json.dump(summary, sys.stdout, indent=1)
         sys.stdout.write("\n")
