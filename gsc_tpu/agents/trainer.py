@@ -40,7 +40,7 @@ from ..resilience.retry import (RetryPolicy, TransientDispatchError,
                                 call_with_retry)
 from ..utils.debug import check_invariants
 from ..utils.telemetry import PhaseTimer
-from .buffer import buffer_nbytes, lockstep_cursor
+from .buffer import buffer_nbytes, lockstep_cursor, pieced_leaves
 from .ddpg import DDPG, DDPGState
 
 log = logging.getLogger("gsc_tpu.agents.trainer")
@@ -353,6 +353,16 @@ class Trainer:
         return bool(_all_finite_jit(tree) > 0)
 
     # -------------------------------------------------------- cost ledger
+    @staticmethod
+    def _gauge_row_pieces(hub, buf) -> None:
+        """How the learn burst fetches the ring's rows, for the program
+        being built: ``replay_leaves_in_pieces`` ring leaves (and
+        ``replay_row_pieces`` pieces in all) are too wide to gather in
+        place and are fetched in column pieces (``buffer.take_rows``)."""
+        pieces = pieced_leaves(buf)
+        hub.gauge("replay_leaves_in_pieces", len(pieces))
+        hub.gauge("replay_row_pieces", sum(pieces))
+
     @staticmethod
     def _ledger_fn(owner, name: str):
         """The dispatched-executable resolver (obs.perf.resolve_lowerable)
@@ -673,6 +683,8 @@ class Trainer:
             # replay residency is static across the run (ring buffer):
             # computed once from shapes, streamed in every episode event
             replay_bytes = buffer_nbytes(buffer)
+            if hub is not None:
+                self._gauge_row_pieces(hub, buffer)
             if verbose:
                 log.info(
                     "replay buffer: %.1f MiB resident%s",
@@ -1110,6 +1122,8 @@ class Trainer:
 
         self.phase_timer = timer = PhaseTimer()
         hub = self.obs.hub if self.obs else None
+        if hub is not None:
+            self._gauge_row_pieces(hub, buffers)
         self.preempted = False
         self._last_drained = start_episode - 1
         if self.obs:
@@ -1613,6 +1627,8 @@ class Trainer:
 
         self.phase_timer = timer = PhaseTimer()
         hub = self.obs.hub if self.obs else None
+        if hub is not None:
+            self._gauge_row_pieces(hub, buffers)
         self.preempted = False
         self._last_drained = start_episode - 1
         if self.obs:

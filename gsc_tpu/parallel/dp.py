@@ -29,7 +29,7 @@ import jax.numpy as jnp
 
 from ..agents.buffer import (ReplayBuffer, buffer_advance,
                              buffer_write_lockstep, flatten_transition,
-                             restore_batch, transition_shapes)
+                             restore_batch, take_rows, transition_shapes)
 from ..agents.ddpg import DDPG, DDPGState, donated_jit
 from ..resilience.guard import all_finite
 from ..config.schema import AgentConfig
@@ -588,7 +588,8 @@ class ParallelDDPG:
         bidx = jax.random.randint(kb, (self.agent.batch_size,), 0, self.B)
         sidx = jax.random.randint(ks, (self.agent.batch_size,), 0,
                                   jnp.maximum(buffers.size[bidx], 1))
-        raw = jax.tree_util.tree_map(lambda d: d[bidx, sidx], buffers.data)
+        raw = jax.tree_util.tree_map(lambda d: take_rows(d, bidx, sidx),
+                                     buffers.data)
         return restore_batch(buffers.shapes, raw)
 
     def _sample_local(self, buffers: ReplayBuffer, key):
@@ -603,7 +604,7 @@ class ParallelDDPG:
 
         def pick(shard, size, k):
             idx = jax.random.randint(k, (b_per,), 0, jnp.maximum(size, 1))
-            return jax.tree_util.tree_map(lambda d: d[idx], shard)
+            return jax.tree_util.tree_map(lambda d: take_rows(d, idx), shard)
 
         batch = jax.vmap(pick)(buffers.data, buffers.size, keys)
         raw = jax.tree_util.tree_map(
